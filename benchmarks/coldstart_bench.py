@@ -2,8 +2,10 @@
 bench (BENCH_coldstart.json).
 
 Every number that matters here is a COLD-PROCESS number, so each leg runs in
-a subprocess with its own interpreter, its own forced device topology, and a
-shared on-disk cache directory:
+a subprocess with its own interpreter, its own forced host-device topology
+(``JAX_PLATFORMS=cpu``: the children never ask for an accelerator), and a
+shared on-disk cache directory — the fixed ``coldstart`` subdirectory of the
+compile cache directory, emptied before the cold legs:
 
 * **cold-process / cold-cache** — fresh interpreter, empty cache dir: the
   full XLA compile bill every process used to pay;
@@ -34,9 +36,9 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 from benchmarks.common import Row
@@ -50,7 +52,7 @@ TRAINER_STEPS = 6
 _ENGINE_CHILD = f"""
 import json, os, time
 from repro.core import compilecache
-compilecache.configure(os.environ["COLDSTART_CACHE"])
+compilecache.configure()
 from repro.core.simulate import engine_cache_stats
 from repro.experiments.runner import _run_training_scenarios, sweep_matrix_45
 
@@ -69,7 +71,7 @@ print("RESULT " + json.dumps({{
 _TRAINER_CHILD = f"""
 import json, os, time
 from repro.core import compilecache
-compilecache.configure(os.environ["COLDSTART_CACHE"])
+compilecache.configure()
 from repro.experiments.trainer_substrate import run_trainer_sweep, trainer_matrix_16
 from repro.train.steps import bundle_cache_stats
 
@@ -90,7 +92,7 @@ print("RESULT " + json.dumps({{
 _CALIBRATE_CHILD = f"""
 import json, os
 from repro.core import calibrate, compilecache
-compilecache.configure(os.environ["COLDSTART_CACHE"])
+compilecache.configure()
 from repro.experiments.scenario import Scenario
 from repro.experiments.trainer_substrate import run_trainer_sweep, trainer_matrix_16
 
@@ -130,8 +132,8 @@ print("RESULT " + json.dumps({{
 
 def _run_child(code: str, cache_dir: str, *, ndev: int, timeout: int = 900) -> dict:
     env = dict(os.environ)
-    env["COLDSTART_CACHE"] = cache_dir
-    env.pop("REPRO_CACHE_DIR", None)  # the child configures explicitly
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    env["JAX_PLATFORMS"] = "cpu"
     if ndev > 1:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     else:
@@ -148,34 +150,37 @@ def _run_child(code: str, cache_dir: str, *, ndev: int, timeout: int = 900) -> d
 
 
 def run() -> list[Row]:
+    from repro.core import compilecache
+
     t_all = time.perf_counter()
-    with tempfile.TemporaryDirectory(prefix="coldstart-cache-") as cache_dir:
-        eng_cold = _run_child(_ENGINE_CHILD, cache_dir, ndev=1)
-        eng_warm = _run_child(_ENGINE_CHILD, cache_dir, ndev=1)
-        tr_cold = _run_child(_TRAINER_CHILD, cache_dir, ndev=4)
-        tr_warm = _run_child(_TRAINER_CHILD, cache_dir, ndev=4)
-        cal = _run_child(_CALIBRATE_CHILD, cache_dir, ndev=4)
+    cache_dir = os.path.join(compilecache.cache_dir(), "coldstart")
+    shutil.rmtree(cache_dir, ignore_errors=True)  # the cold legs start empty
+    eng_cold = _run_child(_ENGINE_CHILD, cache_dir, ndev=1)
+    eng_warm = _run_child(_ENGINE_CHILD, cache_dir, ndev=1)
+    tr_cold = _run_child(_TRAINER_CHILD, cache_dir, ndev=4)
+    tr_warm = _run_child(_TRAINER_CHILD, cache_dir, ndev=4)
+    cal = _run_child(_CALIBRATE_CHILD, cache_dir, ndev=4)
 
-        # manifest accounting: a cold cache misses every build, a warm cache
-        # misses NONE (0 fresh XLA compiles on the second process)
-        assert eng_cold["persistent"]["misses"] == eng_cold["compiles"], eng_cold
-        assert eng_warm["persistent"]["misses"] == 0, eng_warm
-        assert eng_warm["persistent"]["hits"] == eng_warm["compiles"], eng_warm
-        assert tr_cold["persistent"]["misses"] == tr_cold["builds"], tr_cold
-        assert tr_warm["persistent"]["misses"] == 0, tr_warm
-        assert tr_warm["persistent"]["hits"] == tr_warm["builds"], tr_warm
+    # manifest accounting: a cold cache misses every build, a warm cache
+    # misses NONE (0 fresh XLA compiles on the second process)
+    assert eng_cold["persistent"]["misses"] == eng_cold["compiles"], eng_cold
+    assert eng_warm["persistent"]["misses"] == 0, eng_warm
+    assert eng_warm["persistent"]["hits"] == eng_warm["compiles"], eng_warm
+    assert tr_cold["persistent"]["misses"] == tr_cold["builds"], tr_cold
+    assert tr_warm["persistent"]["misses"] == 0, tr_warm
+    assert tr_warm["persistent"]["hits"] == tr_warm["builds"], tr_warm
 
-        trainer_disk_speedup = tr_cold["first_s"] / tr_warm["first_s"]
-        engine_disk_speedup = eng_cold["first_s"] / eng_warm["first_s"]
-        # the acceptance criterion: warm-disk-cache cold-process trainer
-        # sweep >= 3x faster than cold-cache
-        assert trainer_disk_speedup >= 3.0, (trainer_disk_speedup, tr_cold, tr_warm)
+    trainer_disk_speedup = tr_cold["first_s"] / tr_warm["first_s"]
+    engine_disk_speedup = eng_cold["first_s"] / eng_warm["first_s"]
+    # the acceptance criterion: warm-disk-cache cold-process trainer
+    # sweep >= 3x faster than cold-cache
+    assert trainer_disk_speedup >= 3.0, (trainer_disk_speedup, tr_cold, tr_warm)
 
-        # calibration strictly improves the step-time prediction; the
-        # overlap-saving leg is recorded without an assert (host-device noise)
-        rel_before = cal["before"]["step_time"]
-        rel_after = cal["after"]["step_time"]
-        assert rel_after < rel_before, cal
+    # calibration strictly improves the step-time prediction; the
+    # overlap-saving leg is recorded without an assert (host-device noise)
+    rel_before = cal["before"]["step_time"]
+    rel_after = cal["after"]["step_time"]
+    assert rel_after < rel_before, cal
 
     record = {
         "engine": {

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size as compat_axis_size
 
 from repro.core import collectives, comms, feedback, integrity
 from repro.core.compression.base import (
@@ -351,7 +350,7 @@ def _aggregate_one(
     view); it computes the identical predicate a receiver would."""
     n_workers = 1
     for axn in axes:
-        n_workers *= compat_axis_size(axn)
+        n_workers *= jax.lax.axis_size(axn)
     denom = n_workers if n_eff is None else n_eff
 
     wire_fmt = getattr(comm, "wire_format", "dense")
@@ -503,20 +502,20 @@ def aggregate_buckets(
     ``alive_prev`` update; the rejoin reset still applies here."""
     n_workers = 1
     for axn in axes:
-        n_workers *= compat_axis_size(axn)
+        n_workers *= jax.lax.axis_size(axn)
 
     # distinct stochastic-compression keys per worker
     key0 = key
     widx = jnp.zeros((), jnp.int32)
     for axn in axes:
-        widx = widx * compat_axis_size(axn) + jax.lax.axis_index(axn)
+        widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
     key = jax.random.fold_in(key, widx)
     if mask_axes is None or tuple(mask_axes) == tuple(axes):
         mkey = key
     else:
         widx_m = jnp.zeros((), jnp.int32)
         for axn in mask_axes:
-            widx_m = widx_m * compat_axis_size(axn) + jax.lax.axis_index(axn)
+            widx_m = widx_m * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
         mkey = jax.random.fold_in(key0, widx_m)
 
     # churn: each shard draws its own participation bit for this round from

@@ -113,10 +113,9 @@ def active_launch(default: float = 0.0) -> float:
     return _ACTIVE.t_launch if _ACTIVE is not None else default
 
 
-def default_path() -> str | None:
+def default_path() -> str:
     """Where the profile persists: next to the persistent compilation cache."""
-    d = compilecache.cache_dir()
-    return os.path.join(d, DEFAULT_PROFILE_NAME) if d else None
+    return os.path.join(compilecache.cache_dir(), DEFAULT_PROFILE_NAME)
 
 
 def load_default() -> CalibrationProfile | None:
@@ -132,7 +131,7 @@ def load_default() -> CalibrationProfile | None:
     import sys
 
     path = default_path()
-    if not (path and os.path.exists(path)):
+    if not os.path.exists(path):
         return None
     profile = CalibrationProfile.load(path)
     stored = profile.meta.get("fingerprint")
@@ -239,8 +238,8 @@ def calibrate(
 ) -> CalibrationProfile:
     """Measure this machine, fit the constants, optionally persist.
 
-    ``out``: profile path (defaults to ``<cache_dir>/calibration.json`` when
-    a persistent cache dir is configured, else not saved).  ``trace_dir``:
+    ``out``: profile path (defaults to ``<cache_dir>/calibration.json``).
+    ``trace_dir``:
     capture the measurement run under ``jax.profiler.trace`` (best-effort —
     calibration still succeeds if the profiler is unavailable)."""
     import jax
@@ -274,9 +273,8 @@ def calibrate(
             "fitted_unix": time.time(),
         })
     path = out or default_path()
-    if path:
-        profile.save(path)
-        profile.meta["path"] = path
+    profile.save(path)
+    profile.meta["path"] = path
     return profile
 
 
@@ -285,16 +283,14 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=None,
-                    help="profile JSON path (default: <cache-dir>/calibration.json)")
-    ap.add_argument("--cache-dir", default=os.environ.get(compilecache.ENV_VAR, ""),
-                    help="persistent compilation cache dir (REPRO_CACHE_DIR)")
+                    help="profile JSON path (default: calibration.json in the "
+                         "compile cache directory)")
     ap.add_argument("--trace-dir", default=None,
                     help="capture the run under jax.profiler.trace here")
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--repeats", type=int, default=5)
     args = ap.parse_args(argv)
-    if args.cache_dir:
-        compilecache.configure(args.cache_dir)
+    compilecache.configure()
     profile = calibrate(args.out or None, steps=args.steps,
                         repeats=args.repeats, trace_dir=args.trace_dir)
     print(json.dumps(profile.as_dict(), indent=1))

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
 
 from repro.core import comms
 
@@ -30,7 +29,7 @@ def _pad_to(x: jax.Array, m: int) -> jax.Array:
 
 def ring_allreduce(x: jax.Array, axis: str) -> jax.Array:
     """Bandwidth-optimal ring: reduce-scatter then all-gather [145,146]."""
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     orig = x.size
@@ -64,7 +63,7 @@ def ring_allreduce(x: jax.Array, axis: str) -> jax.Array:
 
 def rhd_allreduce(x: jax.Array, axis: str) -> jax.Array:
     """Recursive halving-doubling [146]: log2(n) exchange steps."""
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     assert n & (n - 1) == 0, f"rhd requires power-of-two workers, got {n}"

@@ -2,9 +2,9 @@
 
 Two cooperating pieces:
 
-* **jax's persistent compilation cache** — ``configure(path)`` routes through
-  ``repro.compat.enable_compilation_cache`` so every XLA executable compiled
-  by this process (engine class programs from
+* **jax's persistent compilation cache** — ``configure()`` points jax's cache
+  at :func:`cache_dir` and caches every program, so every XLA executable
+  compiled by this process (engine class programs from
   ``simulate_training_classbatch``, trainer bundles from ``build_bundle``)
   is serialized under ``path`` and deserialized by later processes instead
   of re-compiled.  jax keys those entries by a hash of the optimized HLO +
@@ -42,10 +42,10 @@ Two cooperating pieces:
 
 Nothing here imports jax at module load — configuration happens lazily so
 the ``experiments/run.py`` set-XLA_FLAGS-before-jax contract is preserved.
-The cache directory comes from ``configure(path)`` (the ``--cache-dir``
-flags) or the ``REPRO_CACHE_DIR`` environment variable; with neither set,
-every call is a counted-nothing no-op and behavior is identical to the
-pre-cache repo.
+There is one cache directory: ``$JAX_COMPILATION_CACHE_DIR`` when it is set
+(jax reads the same variable), otherwise the fixed ``.jax_cache`` directory
+of the checkout.  Entry points call ``configure()`` before their first
+compile; the manifest and executable paths configure lazily.
 """
 
 from __future__ import annotations
@@ -55,13 +55,15 @@ import json
 import os
 from dataclasses import dataclass
 
-ENV_VAR = "REPRO_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: the cache directory when ``ENV_VAR`` is unset: ``<checkout>/.jax_cache``
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 MANIFEST_DIRNAME = "repro-manifest"
 EXEC_DIRNAME = "repro-exec"
 
-_DIR: str | None = None
-_ENV_CHECKED = False
-_MECHANISM: str | None = None
+_CONFIGURED: str | None = None  # the directory jax's cache points at
 
 
 @dataclass
@@ -157,45 +159,33 @@ def stable_digest(kind: str, key) -> str:
     return hashlib.sha256(payload.encode()).hexdigest()
 
 
-def _pickup_env() -> None:
-    global _ENV_CHECKED
-    if _ENV_CHECKED or _DIR is not None:
-        return
-    _ENV_CHECKED = True
-    path = os.environ.get(ENV_VAR, "").strip()
-    if path:
-        configure(path)
+def cache_dir() -> str:
+    """The one cache directory: ``$JAX_COMPILATION_CACHE_DIR`` when set,
+    else :data:`DEFAULT_DIR`."""
+    return os.path.abspath(os.environ.get(ENV_VAR) or DEFAULT_DIR)
 
 
-def configure(path: str | None) -> str | None:
-    """Enable (or, with ``None``, detach) the persistent cache at ``path``.
+def configure() -> str:
+    """Point jax's persistent compilation cache at :func:`cache_dir` and make
+    it keep every program (the repo's programs are small but re-compiled by
+    every process, which the default 1 s / size thresholds would skip).
 
-    Enabling imports jax — call only after any XLA_FLAGS setup.  Detaching
-    stops manifest recording but cannot un-register the directory from jax's
-    own cache for this process.  Returns the previous directory.
-    """
-    global _DIR, _MECHANISM, _ENV_CHECKED
-    prev = _DIR
-    _ENV_CHECKED = True
-    if path is None:
-        _DIR = None
-        return prev
-    from repro import compat
+    Imports jax — call only after any XLA_FLAGS setup.  Re-resolves the
+    variable on every call, so a caller that moves it calls this again.
+    Returns the directory."""
+    global _CONFIGURED
+    path = cache_dir()
+    if path != _CONFIGURED:
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
-    path = os.path.abspath(path)
-    _MECHANISM = compat.enable_compilation_cache(path)
-    os.makedirs(os.path.join(path, MANIFEST_DIRNAME), exist_ok=True)
-    _DIR = path
-    return prev
-
-
-def cache_dir() -> str | None:
-    _pickup_env()
-    return _DIR
-
-
-def is_enabled() -> bool:
-    return cache_dir() is not None
+        os.makedirs(os.path.join(path, MANIFEST_DIRNAME), exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()  # re-open at the (new) directory
+        _CONFIGURED = path
+    return path
 
 
 def exec_dir(kind: str, key) -> str | None:
@@ -203,20 +193,14 @@ def exec_dir(kind: str, key) -> str | None:
     (``<cache_dir>/repro-exec/<digest>/``) — jax's own cache skips only the
     XLA backend compile; the executables serialized here
     (``jax.experimental.serialize_executable``) also skip tracing/lowering
-    on warm processes.  None when no persistent cache is configured."""
-    d = cache_dir()
-    if d is None:
-        return None
-    return os.path.join(d, EXEC_DIRNAME, stable_digest(kind, key))
+    on warm processes."""
+    return os.path.join(configure(), EXEC_DIRNAME, stable_digest(kind, key))
 
 
 def record_compile(kind: str, key) -> bool:
     """Called on a fresh in-memory-registry build.  Returns True iff the
-    signature was already in the manifest (persistent hit).  No-op (False,
-    uncounted) when no cache dir is configured."""
-    d = cache_dir()
-    if d is None:
-        return False
+    signature was already in the manifest (persistent hit)."""
+    d = configure()
     st = stats(kind)
     path = os.path.join(d, MANIFEST_DIRNAME, stable_digest(kind, key) + ".json")
     if os.path.exists(path):

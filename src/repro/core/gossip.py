@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size as compat_axis_size
 
 from repro.core import comms
 from repro.core.compression.base import Compressed
@@ -92,7 +91,7 @@ def _neighbor_sum(x: jax.Array, axes: tuple[str, ...]) -> jax.Array:
     wraps across the pod axis boundary via the same ppermute on that axis."""
     total = x
     axis = axes[-1]  # ring within the innermost data axis
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     right = [(j, (j + 1) % n) for j in range(n)]
     left = [(j, (j - 1) % n) for j in range(n)]
     return comms.ppermute(x, axis, right) + comms.ppermute(x, axis, left)
@@ -118,7 +117,7 @@ def dpsgd_mix(params_flat: list[jax.Array], axes: tuple[str, ...], w=1.0 / 3.0,
     if alive is None:
         return [(1 - 2 * w) * p + w * _neighbor_sum(p, axes) for p in params_flat]
     axis = axes[-1]
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     right = [(j, (j + 1) % n) for j in range(n)]
     left = [(j, (j - 1) % n) for j in range(n)]
     live_nbrs = (comms.ppermute(alive, axis, right)
@@ -208,7 +207,7 @@ def choco_mix(
 
     r = jnp.zeros((), f32) if rejoined is None else rejoined
     axis = axes[-1]
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     right = [(j, (j + 1) % n) for j in range(n)]
     left = [(j, (j - 1) % n) for j in range(n)]
     a_nb = [comms.ppermute(alive, axis, perm) for perm in (right, left)]
@@ -251,7 +250,7 @@ def _neighbor_sum_payload(
     from repro.core.compression.base import decompress_p
 
     axis = axes[-1]
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     right = [(j, (j + 1) % n) for j in range(n)]
     left = [(j, (j - 1) % n) for j in range(n)]
     total = None

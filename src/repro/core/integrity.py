@@ -9,8 +9,8 @@ trainer) for the detection -> quarantine -> recover pipeline:
 
 * **injection** is sender-side and post-compression: the payload leaves the
   worker corrupted *in its wire domain* (f32 words for the dense path, int8
-  codes and f32 scales/norms for the quantized families, packed uint8 words
-  for the 1/2-bit wires).  The sender keeps its clean copy — error feedback
+  codes and f32 scales/norms for the quantized families, packed uint32
+  words for the 1/2-bit wires).  The sender keeps its clean copy — error feedback
   always accumulates against what the worker actually compressed.
 * **validation** is receiver-side and only uses the redundancy the wire
   format actually has: finiteness and range of scales/norms, code-range
@@ -34,7 +34,7 @@ inf       float payloads become +Inf
 spike     float magnitudes multiplied by ``SPIKE_FACTOR`` (encodes fine;
           caught by the receiver's range check)
 bitflip   dense f32 words get an exponent bit flipped; int8 codes and
-          packed uint8 words are XORed with ``0x55``
+          packed uint32 words are XORed with ``0x55`` in every byte
 ========  ==================================================================
 """
 
@@ -93,13 +93,15 @@ def corrupt_dense(kind: str, x: jax.Array, flag) -> jax.Array:
 
 
 def corrupt_codes(kind: str, codes: jax.Array, flag) -> jax.Array:
-    """Corrupt an integer code payload (int8 quantizer codes, packed uint8
+    """Corrupt an integer code payload (int8 quantizer codes, packed uint32
     sign/ternary words).  Only ``bitflip`` has an integer-domain image; the
     float-born faults (nan/inf/spike) live in the scales/norms that
     accompany the codes and leave the codes themselves alone."""
     if kind != "bitflip":
         return codes
-    bad = codes ^ jnp.asarray(0x55, codes.dtype)
+    # 0x55 in every byte: one flipped bit per 2-bit slot at any word width
+    mask = int.from_bytes(b"\x55" * jnp.dtype(codes.dtype).itemsize, "little")
+    bad = codes ^ jnp.asarray(mask, codes.dtype)
     return jnp.where(flag > 0, bad, codes)
 
 
@@ -159,10 +161,9 @@ def packed2_valid(words: jax.Array, *, per_row: bool = False) -> jax.Array:
     the crumb value 2 is not a legal code, so an XOR fault is visible
     whenever it produces one.  (The 1-bit packed sign wire has no such
     redundancy — no validator exists for it, by design.)"""
-    w = words.astype(jnp.uint8)
     ok = None
-    for shift in (0, 2, 4, 6):
-        crumb = (w >> shift) & 3
+    for shift in range(0, 8 * jnp.dtype(words.dtype).itemsize, 2):
+        crumb = (words >> shift) & 3
         o = crumb != 2
         ok = o if ok is None else (ok & o)
     return _reduce_all(ok, per_row)

@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
 
 from repro.core import collectives, comms
 from repro.core.types import CommConfig
@@ -74,7 +73,7 @@ def average_params(params: Any, axes: tuple[str, ...], impl: str = "xla",
     """
     n = 1
     for axn in axes:
-        n *= compat_axis_size(axn)
+        n *= jax.lax.axis_size(axn)
     with comms.tag("local_sgd_sync"):
         if alive is None:
             return jax.tree.map(

@@ -2,9 +2,9 @@
 
 Two sources:
 
-* :class:`BigramSource` — sequences from a fixed random Markov chain, so a
-  language model has real structure to learn (training-loss benchmarks and
-  the convergence examples need a learnable task, not noise);
+* :class:`BigramSource` — sequences from a fixed sparse random Markov chain,
+  so a language model has real structure to learn (training-loss benchmarks
+  and the convergence examples need a learnable task, not noise);
 * :class:`SyntheticBatches` — uniform tokens + gaussian frontend embeddings,
   shaped per architecture (used for throughput work where content is
   irrelevant).
@@ -24,19 +24,28 @@ import numpy as np
 
 from repro.configs.base import InputShape, ModelConfig
 
+SUCCESSORS = 16  # candidate next tokens per token in BigramSource
+
 
 @dataclass
 class BigramSource:
+    """Each token has ``SUCCESSORS`` seeded candidate next tokens, drawn
+    uniformly from the vocab, with softmax(normal / temperature)
+    probabilities.  Host memory and per-token sampling cost are
+    O(vocab * SUCCESSORS), so the source works at real vocab sizes."""
+
     vocab: int
     seed: int = 0
     temperature: float = 0.5
 
     def __post_init__(self):
         rng = np.random.default_rng(np.random.Philox(key=self.seed))
-        logits = rng.normal(size=(self.vocab, self.vocab)) / self.temperature
-        self.P = np.exp(logits - logits.max(1, keepdims=True))
-        self.P /= self.P.sum(1, keepdims=True)
-        self.cum = np.cumsum(self.P, axis=1)
+        k = min(SUCCESSORS, self.vocab)
+        self.next = rng.integers(0, self.vocab, (self.vocab, k), dtype=np.int32)
+        logits = rng.normal(size=(self.vocab, k)) / self.temperature
+        p = np.exp(logits - logits.max(1, keepdims=True))
+        self.cum = np.cumsum(p / p.sum(1, keepdims=True), axis=1)
+        self.cum[:, -1] = 1.0  # no rounding gap past the last candidate
 
     def batch(self, step: int, batch: int, seq: int, worker: int = 0) -> dict[str, np.ndarray]:
         rng = np.random.default_rng(np.random.Philox(key=self.seed + 1, counter=[step, worker, 0, 0]))
@@ -44,7 +53,9 @@ class BigramSource:
         toks[:, 0] = rng.integers(0, self.vocab, batch)
         u = rng.random((batch, seq))
         for t in range(seq):
-            toks[:, t + 1] = (self.cum[toks[:, t]] > u[:, t : t + 1]).argmax(1)
+            cur = toks[:, t]
+            pick = (self.cum[cur] > u[:, t : t + 1]).argmax(1)
+            toks[:, t + 1] = self.next[cur, pick]
         return {"tokens": toks[:, :-1], "labels": toks[:, 1:].copy()}
 
 

@@ -141,15 +141,11 @@ def main(argv=None) -> int:
                    help="skip the engine-vs-reference speedup benchmark in "
                         "--emit-json (it runs the 300-step reference loop, "
                         "~10s+ — too heavy for smoke checks)")
-    p.add_argument("--cache-dir", default=os.environ.get("REPRO_CACHE_DIR", ""),
-                   metavar="DIR",
-                   help="persistent on-disk compiled-program cache: XLA "
-                        "executables compiled by this sweep are reused by "
-                        "later processes (default: $REPRO_CACHE_DIR)")
     p.add_argument("--calibration", default="", metavar="PATH",
                    help="machine-fitted cost-model profile (core.calibrate) "
                         "for the predicted columns; empty = auto-adopt "
-                        "<cache-dir>/calibration.json when present; 'none' = "
+                        "calibration.json of the compile cache directory "
+                        "when present; 'none' = "
                         "force the uncalibrated datasheet constants")
     args = p.parse_args(argv)
 
@@ -218,14 +214,13 @@ def main(argv=None) -> int:
 
 
 def _configure_cache_and_calibration(args) -> None:
-    """Apply ``--cache-dir`` / ``--calibration``.  Imports jax (through
-    ``compilecache.configure``), so it must run only after the lane's
-    XLA_FLAGS setup — i.e. after ``_ensure_host_devices`` in the trainer
-    lane — to preserve the set-flags-before-jax contract."""
+    """Open the persistent compile cache and apply ``--calibration``.
+    Imports jax (through ``compilecache.configure``), so it must run only
+    after the lane's XLA_FLAGS setup — i.e. after ``_ensure_host_devices``
+    in the trainer lane — to preserve the set-flags-before-jax contract."""
     from repro.core import calibrate, compilecache
 
-    if args.cache_dir:
-        compilecache.configure(args.cache_dir)
+    compilecache.configure()
     if args.calibration == "none":
         calibrate.set_active(None)
     elif args.calibration:

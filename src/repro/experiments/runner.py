@@ -352,11 +352,12 @@ def roofline_row(s: Scenario) -> dict[str, Any]:
         arch=s.arch,
         shape=s.tag(),
         mesh=f"n{s.n_workers}",
-        flops=s.compute_time * RL.PEAK_FLOPS,
+        flops=s.compute_time * RL.peaks(RL.TARGET_KIND).flops,
         hbm_bytes=_hbm_passes(s) * s.msg_bytes,
         coll_bytes=_round_wire_bytes(s, eff) * rounds_per_iter(s),
         coll_bytes_hlo=0.0,
         coll_by_kind={},
+        device_kind=RL.TARGET_KIND,
     )
     return {
         "t_compute": rl.t_compute,

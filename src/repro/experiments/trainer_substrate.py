@@ -106,7 +106,7 @@ def make_tiny_workload(vocab: int = 128, batch: int = 64, seq: int = 16):
 
     cfg = get_config("qwen3-0.6b").reduced().with_updates(
         vocab=vocab, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32, d_ff=256)
-    shape = InputShape("train", batch, seq, "train")
+    shape = InputShape("train", seq, batch, "train")
     src = BigramSource(cfg.vocab, seed=0)
 
     class Data:

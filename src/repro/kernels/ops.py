@@ -1,6 +1,6 @@
 """jit'd wrappers around the Pallas kernels: flat-vector API, padding and
-(rows, 128)-lane reshaping, backend dispatch (interpret=True off-TPU so the
-same code validates on CPU)."""
+(rows, 128)-lane reshaping, backend dispatch (compiled on a TPU, interpreted
+on the CPU so the same code validates in the tests)."""
 
 from __future__ import annotations
 
@@ -14,7 +14,7 @@ from repro.kernels import qsgd_ef as _qsgd_ef
 from repro.kernels import sign_pack as _sign
 from repro.kernels import terngrad as _tern
 from repro.kernels import threshold_sparsify as _thr
-from repro.kernels import wire_reduce as _wire
+from repro.kernels import wire_reduce as _wire_k
 from repro.kernels import wkv6 as _wkv
 
 f32 = jnp.float32
@@ -22,7 +22,13 @@ _TILE = _qsgd.BLOCK_ROWS * _qsgd.LANES  # elements per full block
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU, interpreted on the CPU; any other backend is an
+    error rather than a silent interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run on a TPU (or interpreted on "
+                           f"the CPU); the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _to2d(x: jax.Array) -> tuple[jax.Array, int]:
@@ -80,68 +86,76 @@ def terngrad_quantize(x: jax.Array, u: jax.Array) -> tuple[jax.Array, jax.Array]
     return tern.reshape(-1)[:n], smax[None]
 
 
+def _words(packed: jax.Array) -> jax.Array:
+    """uint32 wire words -> the int32 view the kernels compute on."""
+    return jax.lax.bitcast_convert_type(packed, jnp.int32)
+
+
+def _wire(words: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(-1)
+
+
 @jax.jit
 def sign_pack(x: jax.Array) -> jax.Array:
-    """Flat f32 (n,) -> uint8 bitmap, lane-interleaved layout (TPU-friendly
-    last-dim-128 tiling).  Returns the full padded byte array — unpack with
-    ``sign_unpack(packed, n)``; pad overhead is < one tile."""
+    """Flat f32 (n,) -> uint32 bitmap (32 signs per word) in the tile layout
+    of :mod:`repro.kernels.sign_pack`.  Returns the full padded word array —
+    unpack with ``sign_unpack(packed, n)``; pad overhead is < one tile."""
     n = x.size
-    lane_tile = _sign.BLOCK_ROWS * 8 * _sign.LANES
-    pad = (-n) % lane_tile
-    xp = jnp.pad(x.reshape(-1), (0, pad), constant_values=1.0)
-    x3 = xp.reshape(-1, 8, _sign.LANES)
-    packed = _sign.sign_pack_3d(x3, interpret=_interpret())
-    return packed.reshape(-1)
+    tile = _sign.BITS * _sign.WORD_ROWS * _sign.LANES
+    xp = jnp.pad(x.reshape(-1), (0, (-n) % tile), constant_values=1.0)
+    words = _sign.sign_pack_2d(xp.reshape(-1, _sign.LANES),
+                               interpret=_interpret())
+    return _wire(words)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def sign_unpack(packed: jax.Array, n: int) -> jax.Array:
-    """Inverse of sign_pack (same interleaved layout)."""
-    x3 = _sign.sign_unpack_3d(packed.reshape(-1, _sign.LANES), interpret=_interpret())
-    return x3.reshape(-1)[:n]
+    """Inverse of sign_pack (same tile layout)."""
+    x2 = _sign.sign_unpack_2d(_words(packed).reshape(-1, _sign.LANES),
+                              interpret=_interpret())
+    return x2.reshape(-1)[:n]
 
 
 def _worker_weights(weights: jax.Array, n_w: int) -> jax.Array:
     """(W,) f32 per-worker weights -> (W, 128) lane-broadcast kernel input."""
     return jnp.broadcast_to(weights.astype(f32).reshape(n_w, 1),
-                            (n_w, _wire.LANES))
+                            (n_w, _wire_k.LANES))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def sign_vote(packed: jax.Array, weights: jax.Array, *, n: int) -> jax.Array:
-    """Gathered packed bitmaps (W, bytes) + per-worker vote weights (W,) ->
+    """Gathered packed bitmaps (W, words) + per-worker vote weights (W,) ->
     weighted vote sums (n,) f32: sum_w weights[w]*(2*bit-1), decoded and
     accumulated in ONE Pallas pass (the packed payload never expands to a
     per-worker dense decode in HBM).  sign_pack's +1 pad bits only affect
     the sliced-off tail."""
     n_w = packed.shape[0]
-    p3 = packed.reshape(n_w, -1, _wire.LANES)
-    votes = _wire.sign_vote_3d(p3, _worker_weights(weights, n_w),
-                               interpret=_interpret())
+    p3 = _words(packed).reshape(n_w, -1, _wire_k.LANES)
+    votes = _wire_k.sign_vote_3d(p3, _worker_weights(weights, n_w),
+                                 interpret=_interpret())
     return votes.reshape(-1)[:n]
 
 
 @jax.jit
 def tern_pack(tern: jax.Array) -> jax.Array:
-    """int8 {-1,0,+1} (n,) -> 2-bit/element uint8 wire payload (returns the
-    full padded byte array; zero pad slots decode to 0 so accumulation is
-    unaffected).  Layout matches ``tern_acc``."""
+    """int8 {-1,0,+1} (n,) -> uint32 wire words, 16 two-bit slots per word
+    (returns the full padded word array; zero pad slots decode to 0 so
+    accumulation is unaffected).  Layout matches ``tern_acc``."""
     n = tern.size
-    tile = _wire.BLOCK_ROWS * 4 * _wire.LANES
-    pad = (-n) % tile
-    t3 = jnp.pad(tern.reshape(-1), (0, pad)).reshape(-1, 4, _wire.LANES)
-    return _wire.tern_pack_3d(t3, interpret=_interpret()).reshape(-1)
+    tile = _wire_k.TERN_SLOTS * _sign.WORD_ROWS * _wire_k.LANES
+    t2 = jnp.pad(tern.reshape(-1), (0, (-n) % tile)).reshape(-1, _wire_k.LANES)
+    return _wire(_wire_k.tern_pack_2d(t2, interpret=_interpret()))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def tern_acc(packed: jax.Array, weights: jax.Array, *, n: int) -> jax.Array:
-    """Gathered 2-bit payloads (W, bytes) + per-worker weights (W,) (e.g.
+    """Gathered 2-bit payloads (W, words) + per-worker weights (W,) (e.g.
     ternary scale x churn mask) -> sum_w weights[w]*tern_w as (n,) f32,
     decode fused with the accumulate."""
     n_w = packed.shape[0]
-    p3 = packed.reshape(n_w, -1, _wire.LANES)
-    out = _wire.tern_acc_3d(p3, _worker_weights(weights, n_w),
-                            interpret=_interpret())
+    p3 = _words(packed).reshape(n_w, -1, _wire_k.LANES)
+    out = _wire_k.tern_acc_3d(p3, _worker_weights(weights, n_w),
+                              interpret=_interpret())
     return out.reshape(-1)[:n]
 
 
@@ -152,11 +166,11 @@ def int8_weighted_sum(codes: jax.Array, weights: jax.Array) -> jax.Array:
     The widening accumulate happens inside the kernel — the (W, n) f32
     decode is never materialized."""
     n_w, n = codes.shape
-    tile = _wire.BLOCK_ROWS * _wire.LANES
-    pad = (-n) % tile
-    c3 = jnp.pad(codes, ((0, 0), (0, pad))).reshape(n_w, -1, _wire.LANES)
-    out = _wire.int8_acc_3d(c3, _worker_weights(weights, n_w),
-                            interpret=_interpret())
+    tile = _wire_k.INT8_ROWS * _wire_k.LANES
+    c3 = jnp.pad(codes, ((0, 0), (0, (-n) % tile))).reshape(n_w, -1,
+                                                           _wire_k.LANES)
+    out = _wire_k.int8_acc_3d(c3, _worker_weights(weights, n_w),
+                              interpret=_interpret())
     return out.reshape(-1)[:n]
 
 
@@ -187,7 +201,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array, u: jax.Array,
     # pad decay with 1.0 (identity for state)
     wp = jnp.pad(w.astype(f32), ((0, 0), (0, pad), (0, 0), (0, 0)), constant_values=1.0)
     ww = jnp.moveaxis(wp, 2, 1).reshape(B * H, S + pad, hd)
-    uu = jnp.broadcast_to(u.astype(f32)[None], (B, H, hd)).reshape(B * H, hd)
+    uu = jnp.broadcast_to(u.astype(f32)[None], (B, H, hd)).reshape(B * H, 1, hd)
     ss = s0.astype(f32).reshape(B * H, hd, hd)
     y, sT = _wkv.wkv6_chunked(rr, kk, vv, ww, uu, ss, chunk=chunk,
                               interpret=_interpret())

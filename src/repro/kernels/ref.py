@@ -6,6 +6,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.sign_pack import LANES, WORD_ROWS, block_rows
+
 f32 = jnp.float32
 
 
@@ -33,47 +35,75 @@ def terngrad_ref(x: jax.Array, u: jax.Array, smax: jax.Array) -> jax.Array:
     return (jnp.sign(x).astype(jnp.int8) * b).astype(jnp.int8)
 
 
+def _slot_view(flat: jax.Array, slots: int, pad) -> jax.Array:
+    """Flat (n,) -> (steps, slots, R, 128): the packed-wire tile layout of
+    :mod:`repro.kernels.sign_pack` (slot j of word row w in a grid step is
+    element row j*R + w), padded to whole tiles with ``pad``."""
+    tile = slots * WORD_ROWS * LANES
+    x = jnp.pad(flat.reshape(-1), (0, (-flat.size) % tile), constant_values=pad)
+    r = block_rows(x.size // (slots * LANES))
+    return x.reshape(-1, slots, r, LANES)
+
+
+def _pack_slots(codes: jax.Array, bits: int) -> jax.Array:
+    """(steps, slots, R, 128) small unsigned codes -> flat uint32 words."""
+    shifts = (bits * jnp.arange(codes.shape[1], dtype=jnp.uint32)).reshape(1, -1, 1, 1)
+    return jnp.sum(codes.astype(jnp.uint32) << shifts, axis=1,
+                   dtype=jnp.uint32).reshape(-1)
+
+
+def _unpack_slots(words: jax.Array, slots: int, bits: int) -> jax.Array:
+    """Flat uint32 words -> (steps, slots, R, 128) codes."""
+    r = block_rows(words.size // LANES)
+    w = words.reshape(-1, 1, r, LANES)
+    shifts = (bits * jnp.arange(slots, dtype=jnp.uint32)).reshape(1, -1, 1, 1)
+    return (w >> shifts) & ((1 << bits) - 1)
+
+
 def sign_pack_ref(x: jax.Array) -> jax.Array:
-    """x (..., 8k) f32 -> (..., k) uint8 bitmap (bit=1 means x>=0)."""
-    bits = (x >= 0).astype(jnp.uint8)
-    b = bits.reshape(*x.shape[:-1], -1, 8)
-    weights = (2 ** jnp.arange(8, dtype=jnp.uint8)).astype(jnp.uint8)
-    return jnp.sum(b * weights, axis=-1, dtype=jnp.uint8)
+    """Flat x (n,) -> uint32 words, 32 sign bits each (bit=1 means x>=0);
+    the pad tail packs as +1."""
+    return _pack_slots(_slot_view(x, 32, 1.0) >= 0, 1)
 
 
-def sign_unpack_ref(packed: jax.Array) -> jax.Array:
-    """(..., k) uint8 -> (..., 8k) f32 in {-1, +1}."""
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (packed[..., None] >> shifts) & 1
-    signs = bits.astype(f32) * 2.0 - 1.0
-    return signs.reshape(*packed.shape[:-1], -1)
+def sign_unpack_ref(packed: jax.Array, n: int) -> jax.Array:
+    """uint32 words -> (n,) f32 in {-1, +1}."""
+    bits = _unpack_slots(packed, 32, 1)
+    return (bits.astype(f32) * 2.0 - 1.0).reshape(-1)[:n]
+
+
+def _sequential_sum(vals: jax.Array, weights: jax.Array) -> jax.Array:
+    """sum_w weights[w]*vals[w], accumulated in worker order (the order the
+    fused kernels use, so float sums match bit for bit)."""
+    acc = vals[0].astype(f32) * weights[0].astype(f32)
+    for w in range(1, vals.shape[0]):
+        acc = acc + vals[w].astype(f32) * weights[w].astype(f32)
+    return acc
 
 
 def sign_vote_ref(signs: jax.Array, weights: jax.Array) -> jax.Array:
     """signs (W, n) in {-1,+1}, weights (W,) -> weighted vote sums (n,)."""
-    return jnp.sum(signs.astype(f32) * weights.astype(f32)[:, None], axis=0)
+    return _sequential_sum(signs, weights)
 
 
 def tern_pack_ref(tern: jax.Array) -> jax.Array:
-    """tern (..., 4k) int8 in {-1,0,+1} -> (..., k) uint8; 2-bit slots with
+    """tern (n,) int8 in {-1,0,+1} -> uint32 words of 16 two-bit slots;
     code 0=zero, 1=+1, 3=-1 (bit0 nonzero, bit1 negative)."""
-    t = tern.reshape(*tern.shape[:-1], -1, 4)
-    code = (t != 0).astype(jnp.uint8) | ((t < 0).astype(jnp.uint8) << 1)
-    shifts = (2 * jnp.arange(4, dtype=jnp.uint8)).astype(jnp.uint8)
-    return jnp.sum(code << shifts, axis=-1, dtype=jnp.uint8)
+    t = _slot_view(tern, 16, 0)
+    return _pack_slots((t != 0).astype(jnp.uint32)
+                       | ((t < 0).astype(jnp.uint32) << 1), 2)
 
 
-def tern_unpack_ref(packed: jax.Array) -> jax.Array:
-    """(..., k) uint8 -> (..., 4k) f32 in {-1, 0, +1}."""
-    shifts = (2 * jnp.arange(4, dtype=jnp.uint8)).astype(jnp.uint8)
-    slot = (packed[..., None] >> shifts) & 3
+def tern_unpack_ref(packed: jax.Array, n: int) -> jax.Array:
+    """uint32 words -> (n,) f32 in {-1, 0, +1}."""
+    slot = _unpack_slots(packed, 16, 2)
     val = (slot == 1).astype(f32) - (slot == 3).astype(f32)
-    return val.reshape(*packed.shape[:-1], -1)
+    return val.reshape(-1)[:n]
 
 
 def weighted_sum_ref(vals: jax.Array, weights: jax.Array) -> jax.Array:
     """vals (W, n), weights (W,) -> sum_w weights[w]*vals[w] as (n,) f32."""
-    return jnp.sum(vals.astype(f32) * weights.astype(f32)[:, None], axis=0)
+    return _sequential_sum(vals, weights)
 
 
 def threshold_ref(x: jax.Array, tau: jax.Array) -> tuple[jax.Array, jax.Array]:

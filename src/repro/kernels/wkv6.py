@@ -33,7 +33,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref, S):
     def _init():
         S[...] = s0_ref[0].astype(f32)
 
-    u = u_ref[0, :].astype(f32)  # (hd,)
+    u = u_ref[0, 0, :].astype(f32)  # (hd,)
 
     def step(t, _):
         r_t = r_ref[0, t, :].astype(f32)  # (hd,)
@@ -55,7 +55,7 @@ def wkv6_chunked(
     k: jax.Array,
     v: jax.Array,
     w: jax.Array,
-    u: jax.Array,  # (BH, hd) (head bonus broadcast per batch)
+    u: jax.Array,  # (BH, 1, hd) (head bonus broadcast per batch)
     s0: jax.Array,  # (BH, hd, hd)
     *,
     chunk: int = CHUNK,
@@ -79,7 +79,7 @@ def wkv6_chunked(
         grid=grid,
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((1, hd), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda i, j: (i, 0, 0)),
         ],
         out_specs=(

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: .lower().compile() every (arch x shape) on the
 production mesh; print memory_analysis / cost_analysis; extract roofline
 terms (see repro.launch.roofline) and write JSON records.
@@ -10,10 +7,15 @@ terms (see repro.launch.roofline) and write JSON records.
 
 Shape kinds: train_4k -> train_step; prefill_32k -> prefill_step;
 decode_32k / long_500k -> serve_step (1 new token, seq_len KV cache).
+
+The programs compile for the host's CPU devices (``main`` forces 512 of
+them before jax starts); the roofline terms are priced for the named target
+chip, ``roofline.TARGET_KIND``, not for the CPU that compiled them.
 """
 
 import argparse
 import json
+import os
 import sys
 import time
 
@@ -27,26 +29,9 @@ from repro.core import comms
 from repro.core.types import CommConfig
 from repro.launch import roofline as RL
 from repro.launch.mesh import make_production_mesh
+from repro.launch.specs import COMM_PRESETS
 from repro.optim.optimizers import adamw
 from repro.train.steps import build_bundle, build_serve
-
-# Named comm presets exercised by the dry-run (paper-faithful baseline and
-# the compressed variants; see EXPERIMENTS.md §Perf for the hillclimbs).
-COMM_PRESETS = {
-    "dense_bsp": CommConfig(),
-    "topk_ef": CommConfig(
-        compressor="topk", compressor_kwargs={"ratio": 0.01},
-        error_feedback=True, momentum_correction=0.9, bucket_mb=32,
-    ),
-    "qsgd": CommConfig(compressor="qsgd", compressor_kwargs={"levels": 16}, bucket_mb=32),
-    "signsgd_mv": CommConfig(compressor="signsgd", bucket_mb=32),
-    "local_sgd": CommConfig(sync="local", local_steps=8),
-    "ring_manual": CommConfig(collective="ring", bucket_mb=32),
-    # multi-pod: BSP on ICI inside each pod, Local-SGD across the DCN
-    # boundary every 8 steps (survey §III-D at pod scale)
-    "pod_local_sgd": CommConfig(pod_local=True, local_steps=8),
-}
-
 
 def _lower_step(cfg, mesh, shape, comm_name: str):
     if shape.kind == "train":
@@ -133,6 +118,7 @@ def dry_run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         record["cost_analysis"].get("bytes accessed", 0)))
 
     rl = RL.extract(arch, shape_name, mesh_name, cost_compiled, log,
+                    device_kind=RL.TARGET_KIND,
                     backward_factor=backward_factor)
     # HLO collective cross-check from the scanned module (static count)
     rl.coll_bytes_hlo, _ = RL.hlo_collective_bytes(compiled.as_text())
@@ -143,6 +129,9 @@ def dry_run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main(argv=None) -> int:
+    # the production mesh needs 512 devices: force host ones before jax's
+    # CPU backend starts (nothing above has touched a device yet)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="all", help=f"one of {ARCHS} or 'all'")
     p.add_argument("--shape", default="all", help=f"one of {tuple(INPUT_SHAPES)} or 'all'")

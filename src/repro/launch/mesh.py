@@ -1,13 +1,11 @@
 """Mesh construction. Importing this module never touches jax device state;
 ``make_production_mesh`` is a function per the dry-run contract.
-
-Meshes are built through :mod:`repro.compat` so ``axis_types`` is forwarded
-on jax versions that support it and silently dropped on those that don't.
 """
 
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -11,8 +11,6 @@ import sys
 
 from repro.configs import get_config
 from repro.configs.base import INPUT_SHAPES, active_params
-from repro.launch.roofline import HBM_BW, ICI_BW, ICI_LINKS, PEAK_FLOPS
-
 HBM_PER_CHIP = 16e9  # v5e
 
 

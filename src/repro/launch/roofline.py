@@ -1,10 +1,11 @@
 """Roofline-term extraction from a compiled dry-run artifact.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, priced with the peaks of
+the named device kind (:data:`PEAKS`):
 
-    compute    = HLO_FLOPs / peak_FLOPs            (197 TF/s bf16 / chip)
-    memory     = HLO_bytes / HBM_bw                (819 GB/s / chip)
-    collective = collective_bytes / (links x bw)   (~50 GB/s per ICI link)
+    compute    = HLO_FLOPs / peak_FLOPs
+    memory     = HLO_bytes / HBM_bw
+    collective = collective_bytes / (links x ICI bw per link)
 
 ``HLO_FLOPs``/``HLO_bytes`` come from ``compiled.cost_analysis()`` (the
 per-device SPMD module).  ``collective_bytes`` has two independent sources:
@@ -22,10 +23,33 @@ from dataclasses import dataclass
 
 import numpy as np
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s per link
-ICI_LINKS = 2  # effective links engaged per collective phase (2D torus ring dims)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM B/s per chip
+    ici_bw: float  # B/s per ICI link
+    ici_links: int  # links engaged per collective phase (2D torus ring dims)
+
+
+#: Published peaks by ``jax.Device.device_kind``.  "TPU v5 lite" is the TPU
+#: v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s
+#: HBM, 1,600 Gbit/s ICI per chip = 4 links x 50 GB/s.
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                               ici_links=2),
+}
+
+#: The device kind the dry-run and the analytic roofline rows are priced for.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; a kind missing from the table is an error."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 @dataclass
@@ -38,19 +62,21 @@ class Roofline:
     coll_bytes: float  # per device, from comms capture
     coll_bytes_hlo: float  # cross-check (static HLO text, no loop multiplicity)
     coll_by_kind: dict
+    device_kind: str
     backward_factor: float = 1.0
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes * self.backward_factor / (ICI_BW * ICI_LINKS)
+        pk = peaks(self.device_kind)
+        return self.coll_bytes * self.backward_factor / (pk.ici_bw * pk.ici_links)
 
     @property
     def bottleneck(self) -> str:
@@ -66,6 +92,7 @@ class Roofline:
             "arch": self.arch,
             "shape": self.shape,
             "mesh": self.mesh,
+            "device_kind": self.device_kind,
             "flops": self.flops,
             "hbm_bytes": self.hbm_bytes,
             "coll_bytes": self.coll_bytes,
@@ -124,6 +151,7 @@ def extract(
     compiled,
     comm_log,
     *,
+    device_kind: str,
     backward_factor: float = 1.0,
 ) -> Roofline:
     ca = compiled.cost_analysis()
@@ -148,5 +176,6 @@ def extract(
         coll_bytes=weighted,
         coll_bytes_hlo=hlo_bytes,
         coll_by_kind=comm_log.by_kind(),
+        device_kind=device_kind,
         backward_factor=1.0,
     )

@@ -16,10 +16,33 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
+from repro.core.types import CommConfig
 from repro.models.sharding import AxisCtx
 from repro.utils.tree import tree_map_with_name
 
 f32 = jnp.float32
+
+# Named comm presets of the launchers (paper-faithful baseline and the
+# compressed variants).
+COMM_PRESETS = {
+    "dense_bsp": CommConfig(),
+    "topk_ef": CommConfig(
+        compressor="topk", compressor_kwargs={"ratio": 0.01},
+        error_feedback=True, momentum_correction=0.9, bucket_mb=32,
+    ),
+    "qsgd": CommConfig(compressor="qsgd", compressor_kwargs={"levels": 16}, bucket_mb=32),
+    "signsgd_mv": CommConfig(compressor="signsgd", bucket_mb=32),
+    # the Pallas-kernel compressors: 8-bit QSGD codes and 1-bit packed signs
+    # (pair with --wire-format compressed for the compressed-domain reduce)
+    "qsgd_int8": CommConfig(compressor="qsgd_kernel",
+                            compressor_kwargs={"levels": 127}, bucket_mb=32),
+    "signsgd_packed": CommConfig(compressor="signsgd_packed", bucket_mb=32),
+    "local_sgd": CommConfig(sync="local", local_steps=8),
+    "ring_manual": CommConfig(collective="ring", bucket_mb=32),
+    # multi-pod: BSP on ICI inside each pod, Local-SGD across the DCN
+    # boundary every 8 steps (survey §III-D at pod scale)
+    "pod_local_sgd": CommConfig(pod_local=True, local_steps=8),
+}
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -7,18 +7,26 @@
         [--ckpt-dir ckpts --ckpt-every 100]
 
 On CPU development hosts pass --fake-devices N to simulate the mesh.
-Comm presets come from repro.launch.dryrun.COMM_PRESETS; any preset can be
+Comm presets come from repro.launch.specs.COMM_PRESETS; any preset can be
 further tweaked with --local-steps / --bucket-mb / --pod-local /
---overlap pipelined [--overlap-staleness 0|1] (§VII microbatch-pipelined
-bucketized aggregation).
+--wire-format compressed / --overlap pipelined [--overlap-staleness 0|1]
+(§VII microbatch-pipelined bucketized aggregation).  Compiled programs are
+cached in $JAX_COMPILATION_CACHE_DIR, else in the checkout's .jax_cache.
+Importing this module touches neither jax nor XLA_FLAGS.
 """
 
 import argparse
 import os
+import statistics
 import sys
 
 
-def main(argv=None) -> int:
+def train(argv=None) -> dict:
+    """Build the bundle for the command line ``argv``, train, and return
+    ``{"history", "bundle", "state", "first_step_s", "median_step_s"}``.
+    Every step is logged (and so synchronized) when ``--steps`` < 40; step
+    times are the differences of those logged wall clocks, and the first
+    one includes the compile."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--reduced", action="store_true", help="reduced smoke-scale variant")
@@ -37,6 +45,9 @@ def main(argv=None) -> int:
     p.add_argument("--pod-local", action="store_true")
     p.add_argument("--local-steps", type=int, default=0)
     p.add_argument("--bucket-mb", type=float, default=-1.0)
+    p.add_argument("--wire-format", default="", choices=("", "dense", "compressed"),
+                   help="compressed: the wire carries the packed/int8 payload "
+                        "and a fused kernel decodes and reduces it")
     p.add_argument("--overlap", default="", choices=("", "sequential", "pipelined"),
                    help="§VII schedule: pipelined issues each microbatch's "
                         "bucket all-reduces inside the accumulation scan")
@@ -47,14 +58,7 @@ def main(argv=None) -> int:
     p.add_argument("--restore", default="")
     p.add_argument("--fake-devices", type=int, default=0)
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--cache-dir", default=os.environ.get("REPRO_CACHE_DIR", ""),
-                   metavar="DIR",
-                   help="persistent on-disk compiled-program cache: a later "
-                        "launch of the same bundle shape deserializes the "
-                        "XLA executables instead of re-compiling "
-                        "(default: $REPRO_CACHE_DIR)")
     args = p.parse_args(argv)
-
     if args.fake_devices:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
@@ -65,18 +69,16 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config
     from repro.configs.base import InputShape
+    from repro.core import compilecache
     from repro.data.pipeline import BigramSource
-    from repro.launch.dryrun import COMM_PRESETS
     from repro.launch.mesh import make_test_mesh
+    from repro.launch.specs import COMM_PRESETS
     from repro.optim.optimizers import adamw, momentum_sgd, sgd, zero1
     from repro.optim.schedules import warmup_cosine
     from repro.train.steps import build_bundle
     from repro.train.trainer import Trainer
 
-    if args.cache_dir:
-        from repro.core import compilecache
-
-        compilecache.configure(args.cache_dir)  # after XLA_FLAGS are settled
+    compilecache.configure()  # after XLA_FLAGS are settled
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -89,6 +91,8 @@ def main(argv=None) -> int:
         upd["local_steps"] = args.local_steps
     if args.bucket_mb >= 0:
         upd["bucket_mb"] = args.bucket_mb
+    if args.wire_format:
+        upd["wire_format"] = args.wire_format
     if args.overlap:
         upd["overlap"] = args.overlap
         upd["overlap_staleness"] = args.overlap_staleness
@@ -122,8 +126,19 @@ def main(argv=None) -> int:
         state, start = restore(args.restore, state,
                                bundle.shardings(bundle.state_specs))
         print(f"restored step {start} from {args.restore}")
-    state = trainer.fit(state, args.steps, start_step=start)
-    for row in trainer.history:
+    state = jax.block_until_ready(trainer.fit(state, args.steps, start_step=start))
+    walls = [row["wall"] for row in trainer.history]
+    steps = [b - a for a, b in zip(walls, walls[1:])]
+    return {
+        "history": trainer.history, "bundle": bundle, "state": state,
+        "first_step_s": walls[0] if walls else float("nan"),
+        "median_step_s": statistics.median(steps) if steps else float("nan"),
+    }
+
+
+def main(argv=None) -> int:
+    run = train(argv)
+    for row in run["history"]:
         print(f"step {row['step']:5d} loss {row['loss']:.4f} "
               f"ce {row['ce']:.4f} wall {row['wall']:.1f}s")
     return 0

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size as compat_axis_size
 
 from repro.configs.base import ModelConfig
 from repro.core.comms import all_gather, all_to_all, pmax, psum
@@ -539,10 +538,10 @@ def _cache_write(cache, new, pos, window, seq_axes):
     S_l = cache["pos"].shape[0]
     n_shards = 1
     for axn in seq_axes:
-        n_shards *= compat_axis_size(axn)
+        n_shards *= jax.lax.axis_size(axn)
     shard = 0
     for axn in seq_axes:
-        shard = shard * compat_axis_size(axn) + jax.lax.axis_index(axn)
+        shard = shard * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
     S_alloc = S_l * n_shards
     slot_global = pos % S_alloc
     owner = slot_global // S_l

@@ -13,7 +13,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
 
 f32 = jnp.float32
 
@@ -100,13 +99,13 @@ def zero1(opt: Optimizer, data_axes: tuple[str, ...]) -> Optimizer:
     def n_shards():
         n = 1
         for a in data_axes:
-            n *= compat_axis_size(a)
+            n *= jax.lax.axis_size(a)
         return n
 
     def shard_index():
         i = jnp.zeros((), jnp.int32)
         for a in data_axes:
-            i = i * compat_axis_size(a) + jax.lax.axis_index(a)
+            i = i * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return i
 
     def _slice(leaf):

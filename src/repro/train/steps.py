@@ -31,7 +31,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-from repro.compat import shard_map, axis_size as compat_axis_size
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import aggregate, comms, gossip, integrity, sync
 from repro.core.compression.base import get_compressor
@@ -103,7 +102,7 @@ def _fix_model_grads(grads: Any, specs: Any, model_axis: str) -> Any:
     (tests/test_tp_equivalence.py).  The replicated-leaf psums are real wire
     traffic (tagged 'tp_grad_fixup' in the roofline accounting)."""
 
-    msize = compat_axis_size(model_axis)
+    msize = jax.lax.axis_size(model_axis)
 
     def fix(g, s):
         if _mentions_model(s):
@@ -157,8 +156,8 @@ class _PersistentStep:
       NO lowering, NO backend compile;
     * first call, no blob (cold): AOT-compile from the build-time avals and
       serialize for the next process — same work the jit path would do;
-    * any serialization/topology mismatch: permanent fallback to the plain
-      jitted path (the cache can make a call cheaper, never fail it).
+    * an unreadable or foreign blob is compiled afresh; a compile the
+      backend refuses raises here, where it happens.
 
     If the first call happens under an open ``comms.capture()``, the
     deserialize shortcut is skipped and the step AOT-compiles from the
@@ -188,23 +187,27 @@ class _PersistentStep:
             serialize,
         )
 
-        try:
-            if os.path.exists(self._path) and not comms.capturing():
+        if os.path.exists(self._path) and not comms.capturing():
+            try:
                 with open(self._path, "rb") as f:
                     payload, in_tree, out_tree = pickle.loads(f.read())
                 self._compiled = deserialize_and_load(payload, in_tree, out_tree)
                 return
-            compiled = self._jit.lower(*self._avals).compile()
-            if not os.path.exists(self._path):
-                blob = pickle.dumps(serialize(compiled))
-                os.makedirs(os.path.dirname(self._path), exist_ok=True)
-                tmp = self._path + f".tmp{os.getpid()}"
-                with open(tmp, "wb") as f:
-                    f.write(blob)
-                os.replace(tmp, self._path)  # atomic: writers race benignly
-            self._compiled = compiled
-        except Exception:  # pragma: no cover - mismatched topology/pickle
-            self._compiled = None
+            except (OSError, EOFError, pickle.PickleError,
+                    jax.errors.JaxRuntimeError):
+                pass  # unreadable or foreign blob: compile afresh below
+        self._compiled = self._jit.lower(*self._avals).compile()
+        if os.path.exists(self._path):
+            return
+        try:
+            blob = pickle.dumps(serialize(self._compiled))
+            os.makedirs(os.path.dirname(self._path), exist_ok=True)
+            tmp = self._path + f".tmp{os.getpid()}"
+            with open(tmp, "wb") as f:
+                f.write(blob)
+            os.replace(tmp, self._path)  # atomic: writers race benignly
+        except (OSError, pickle.PickleError):
+            pass  # the next process compiles again
 
     def __call__(self, *args):
         if not self._resolved:
@@ -566,7 +569,7 @@ def _compile_bundle(
                 "step": jnp.zeros((), jnp.int32)}
 
     init_state = jax.jit(
-        shard_map(_init, mesh=mesh, in_specs=(param_specs,), out_specs=state_specs,
+        jax.shard_map(_init, mesh=mesh, in_specs=(param_specs,), out_specs=state_specs,
                       check_vma=False)
     )
 
@@ -655,7 +658,7 @@ def _compile_bundle(
                 maxes = mask_axes if mask_axes is not None else agg_axes
                 widx = jnp.zeros((), jnp.int32)
                 for axn in maxes:
-                    widx = widx * compat_axis_size(axn) + jax.lax.axis_index(axn)
+                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
                 mkey = jax.random.fold_in(key, widx)
                 drop = knobs["dropout"]
                 if getattr(drop, "ndim", 0) == 1:
@@ -759,7 +762,7 @@ def _compile_bundle(
                 out,
             )
 
-        raw = shard_map(
+        raw = jax.shard_map(
             _step, mesh=mesh,
             in_specs=(state_specs, batch_pspecs, P(), knob_pspecs),
             out_specs=(state_specs, {"loss": P(), "ce": P(), "aux": P()}),
@@ -808,7 +811,7 @@ def _compile_bundle(
                 # participation unit = the data shard (sync_axes == ax.data)
                 widx = jnp.zeros((), jnp.int32)
                 for axn in sync_axes:
-                    widx = widx * compat_axis_size(axn) + jax.lax.axis_index(axn)
+                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
                 mkey = jax.random.fold_in(
                     jax.random.fold_in(jax.random.key(knobs["seed"]),
                                        state["step"]),
@@ -887,7 +890,7 @@ def _compile_bundle(
 
     raw_sync = sync_step = None
     if comm.sync in ("local", "post_local") or comm.pod_local:
-        raw_sync = shard_map(_sync, mesh=mesh, in_specs=(state_specs, knob_pspecs),
+        raw_sync = jax.shard_map(_sync, mesh=mesh, in_specs=(state_specs, knob_pspecs),
                              out_specs=state_specs, check_vma=False)
         sync_step = jax.jit(raw_sync, donate_argnums=(0,))
 
@@ -918,7 +921,7 @@ def _compile_bundle(
             if spec.churn:
                 widx = jnp.zeros((), jnp.int32)
                 for axn in ax.data:
-                    widx = widx * compat_axis_size(axn) + jax.lax.axis_index(axn)
+                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
                 mkey = jax.random.fold_in(
                     jax.random.fold_in(jax.random.key(knobs["seed"]), state["step"]),
                     widx)
@@ -960,7 +963,7 @@ def _compile_bundle(
             return ({"params": new_params, "opt": opt_state, "comm": cstate,
                      "step": state["step"] + 1}, out)
 
-        raw_gossip = shard_map(
+        raw_gossip = jax.shard_map(
             _gstep, mesh=mesh,
             in_specs=(state_specs, batch_pspecs, P(), knob_pspecs),
             out_specs=(state_specs, {"loss": P(), "ce": P(), "aux": P()}),
@@ -974,7 +977,7 @@ def _compile_bundle(
         return comms.pmean(loss, ax.data)
 
     eval_step = jax.jit(
-        shard_map(_eval, mesh=mesh, in_specs=(state_specs, batch_pspecs),
+        jax.shard_map(_eval, mesh=mesh, in_specs=(state_specs, batch_pspecs),
                       out_specs=P(), check_vma=False)
     )
 
@@ -1094,7 +1097,7 @@ def build_serve(cfg: ModelConfig, mesh, shape: InputShape) -> ServeBundle:
         return last, cache
 
     prefill_step = jax.jit(
-        shard_map(_prefill, mesh=mesh, in_specs=(param_specs, batch_pspecs),
+        jax.shard_map(_prefill, mesh=mesh, in_specs=(param_specs, batch_pspecs),
                       out_specs=(P(baxes), cache_pspecs), check_vma=False)
     )
 
@@ -1104,7 +1107,7 @@ def build_serve(cfg: ModelConfig, mesh, shape: InputShape) -> ServeBundle:
         )
 
     serve_step = jax.jit(
-        shard_map(_serve, mesh=mesh,
+        jax.shard_map(_serve, mesh=mesh,
                       in_specs=(param_specs, cache_pspecs, tok_pspec),
                       out_specs=(tok_pspec, cache_pspecs), check_vma=False),
         donate_argnums=(1,),

@@ -33,16 +33,15 @@ class Trainer:
         }
 
     def init(self, seed: int = 0):
+        """Initialize the parameters straight into their shardings (no full
+        copy on one device first) and build the training state."""
         b = self.bundle
         from repro.models.transformer import init_params
 
-        # init on host then shard (small/test models; big models are dry-run only)
-        params = init_params(b.cfg, jax.random.key(seed), b.mesh.shape["model"])
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(b.mesh, s)),
-            params, b.param_specs, is_leaf=lambda l: hasattr(l, "shape"),
-        )
-        return b.init_state(params)
+        msize = b.mesh.shape["model"]
+        make = jax.jit(lambda key: init_params(b.cfg, key, msize),
+                       out_shardings=b.shardings(b.param_specs))
+        return b.init_state(make(jax.random.key(seed)))
 
     def restore_rejoin(self, path: str):
         """Churn-aware restore for a process re-entering a run: pull params,

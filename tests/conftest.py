@@ -12,21 +12,24 @@ def rng():
 
 @pytest.fixture(autouse=True, scope="session")
 def _isolated_persistent_cache(tmp_path_factory):
-    """Point the persistent compiled-program cache (REPRO_CACHE_DIR,
+    """Point the persistent compiled-program cache (JAX_COMPILATION_CACHE_DIR,
     repro.core.compilecache) at a session tmpdir BEFORE any test touches it:
     the suite must never read from — or, worse, clear — a developer's real
     warm cache, and its own writes vanish with the tmpdir.  Subprocess tests
     inherit the override through the environment."""
     import os
 
+    from repro.core import compilecache
+
     cache_dir = str(tmp_path_factory.mktemp("repro-cache"))
-    prev = os.environ.get("REPRO_CACHE_DIR")
-    os.environ["REPRO_CACHE_DIR"] = cache_dir
+    prev = os.environ.get(compilecache.ENV_VAR)
+    os.environ[compilecache.ENV_VAR] = cache_dir
+    compilecache.configure()
     yield cache_dir
     if prev is None:
-        os.environ.pop("REPRO_CACHE_DIR", None)
+        os.environ.pop(compilecache.ENV_VAR, None)
     else:
-        os.environ["REPRO_CACHE_DIR"] = prev
+        os.environ[compilecache.ENV_VAR] = prev
 
 
 @pytest.fixture(autouse=True, scope="module")

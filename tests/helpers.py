@@ -16,8 +16,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_subprocess_devices(script: str, n_devices: int = 8, timeout: int = 900) -> str:
-    """Run a python snippet in a subprocess with N fake host devices."""
+    """Run a python snippet in a subprocess with N fake host devices (on
+    the CPU platform: the child never competes for an accelerator)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(

@@ -9,7 +9,8 @@ from tests.helpers import run_subprocess_devices
 SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import AxisType, make_mesh, shard_map
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import collectives, comms, aggregate, gossip
 from repro.core.types import CommConfig
 from repro.core.compression import get_compressor

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs import get_config
 from repro.configs.base import InputShape

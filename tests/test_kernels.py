@@ -53,7 +53,9 @@ def test_terngrad_kernel(n, dtype):
 def test_sign_pack_roundtrip(n):
     x = _x(n)
     packed = ops.sign_pack(x)
-    assert packed.dtype == jnp.uint8
+    assert packed.dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(packed),
+                                  np.asarray(ref.sign_pack_ref(x)))
     out = ops.sign_unpack(packed, n)
     np.testing.assert_array_equal(np.asarray(out), np.where(np.asarray(x) >= 0, 1.0, -1.0))
 

@@ -84,17 +84,20 @@ def compute_key_reprs() -> dict:
 
 @contextlib.contextmanager
 def isolated_cache(path):
-    """Point the persistent cache at ``path`` for the duration; restore the
-    session-level dir (conftest's tmpdir) and zeroed counters after."""
+    """Point the persistent cache at ``path`` (through its one variable) for
+    the duration; restore the session-level dir (conftest's tmpdir) and
+    zeroed counters after."""
     from repro.core import compilecache
 
-    compilecache.cache_dir()  # force env pickup so prev is the real prior dir
-    prev = compilecache.configure(str(path))
+    prev = os.environ[compilecache.ENV_VAR]
+    os.environ[compilecache.ENV_VAR] = str(path)
+    compilecache.configure()
     compilecache.reset_stats()
     try:
         yield compilecache
     finally:
-        compilecache.configure(prev)
+        os.environ[compilecache.ENV_VAR] = prev
+        compilecache.configure()
         compilecache.reset_stats()
 
 
@@ -116,20 +119,22 @@ def test_record_compile_miss_then_hit(tmp_path):
         assert len(os.listdir(manifest)) == 2  # one entry per (kind, key)
 
 
-def test_unconfigured_cache_is_a_counted_nothing_noop():
+def test_cache_dir_follows_the_variable_else_the_checkout(monkeypatch, tmp_path):
+    """One variable places the cache; without it the cache sits at the
+    checkout's fixed .jax_cache, and jax's own cache follows either way."""
+    import jax
+
     from repro.core import compilecache
 
-    compilecache.cache_dir()  # consume the env before detaching
-    prev = compilecache.configure(None)
-    compilecache.reset_stats()
-    try:
-        assert compilecache.record_compile("engine", ("k",)) is False
-        st = compilecache.stats("engine")
-        assert (st.hits, st.misses) == (0, 0)
-        assert st.as_dict()["dir"] is None
-    finally:
-        compilecache.configure(prev)
-        compilecache.reset_stats()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv(compilecache.ENV_VAR)
+    assert compilecache.cache_dir() == os.path.join(repo, ".jax_cache")
+    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path))
+    assert compilecache.cache_dir() == str(tmp_path)
+    with isolated_cache(tmp_path / "moved") as cc:
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "moved")
+        assert cc.exec_dir("bundle", ("k",)).startswith(
+            os.path.join(str(tmp_path / "moved"), cc.EXEC_DIRNAME))
 
 
 def test_stats_surfaced_on_both_cache_stat_objects(tmp_path):
@@ -225,6 +230,7 @@ def test_key_digests_stable_across_processes(tmp_path):
     )
     out = subprocess.run(
         [sys.executable, "-c", code, os.path.dirname(__file__)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, check=True, timeout=240)
     there = json.loads(out.stdout.strip().splitlines()[-1])
     assert there == here

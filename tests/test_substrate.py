@@ -9,7 +9,7 @@ import pytest
 
 from repro.core.costmodel import TABLE_III_ALGS, Link, allreduce_cost, ps_cost, upload_bits
 from repro.core.schedule import LayerSpec, simulate_schedule
-from repro.data.pipeline import BigramSource, SyntheticBatches
+from repro.data.pipeline import SUCCESSORS, BigramSource, SyntheticBatches
 from repro.configs import get_config
 from repro.configs.base import INPUT_SHAPES, InputShape, active_params, n_params
 from repro.optim.optimizers import adamw, global_clip, momentum_sgd, sgd
@@ -34,6 +34,30 @@ def test_bigram_determinism_and_structure():
             pairs.setdefault(int(x), []).append(int(y))
     ent = np.mean([len(set(v)) / 64 for v in pairs.values() if len(v) > 10])
     assert ent < 0.8  # far from uniform
+
+
+def test_bigram_at_real_vocab_stays_sparse():
+    """Qwen3's 151936-token vocab: the chain holds ``SUCCESSORS`` candidates
+    per token (O(vocab * k) host memory, not vocab^2), and every sampled
+    transition is one of them."""
+    src = BigramSource(151936, seed=0)
+    assert src.next.shape == src.cum.shape == (151936, SUCCESSORS)
+    b = src.batch(0, 4, 2048)
+    t, y = b["tokens"], b["labels"]
+    assert t.shape == (4, 2048) and 0 <= t.min() and t.max() < 151936
+    assert all(np.isin(y[r, i], src.next[t[r, i]])
+               for r in range(4) for i in range(0, 2048, 97))
+
+
+def test_importing_the_launcher_leaves_xla_flags(monkeypatch):
+    import importlib
+    import sys
+
+    monkeypatch.setenv("XLA_FLAGS", "--caller-flag")
+    for mod in ("repro.launch.train", "repro.launch.dryrun"):
+        sys.modules.pop(mod, None)
+        importlib.import_module(mod)
+        assert os.environ["XLA_FLAGS"] == "--caller-flag"
 
 
 def test_synthetic_batches_per_arch():

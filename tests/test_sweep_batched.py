@@ -308,7 +308,7 @@ def test_cli_trainer_lane_runs_on_forced_devices(tmp_path):
     import sys
 
     out = tmp_path / "trainer.json"
-    env = {**os.environ, "PYTHONPATH": "src"}
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-m", "repro.experiments.run", "--substrate", "trainer",

@@ -78,7 +78,8 @@ for shape in (InputShape("t", 64, 8, "train"), InputShape("p", 64, 8, "prefill")
             low = sb.serve_step.lower(sb.param_abstract, sb.cache_abstract,
                                       jax.ShapeDtypeStruct((shape.global_batch, 1), jnp.int32))
     compiled = low.compile()
-    rl = RL.extract(cfg.name, shape.name, "4x2", compiled, log)
+    rl = RL.extract(cfg.name, shape.name, "4x2", compiled, log,
+                    device_kind=RL.TARGET_KIND)
     assert rl.flops > 0 and rl.hbm_bytes > 0
     assert compiled.memory_analysis() is not None
     hlo_bytes, kinds = RL.hlo_collective_bytes(compiled.as_text())

@@ -10,7 +10,8 @@ from tests.helpers import run_subprocess_devices
 SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import AxisType, make_mesh, shard_map
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.models.sharding import AxisCtx, make_plan, tree_specs

@@ -70,10 +70,11 @@ def test_tern_pack_acc_matches_reference(n_w):
     acc = ops.tern_acc(packed, weights, n=n)
     expect = kref.weighted_sum_ref(tern.astype(jnp.float32), weights)
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(expect))
-    # roundtrip through the 2-bit wire payload is lossless (the op packs
-    # lane-interleaved: element e -> (row, slot=(e//128)%4, lane=e%128))
-    un = kref.tern_unpack_ref(packed[0].reshape(-1, 128))
-    un = un.reshape(-1, 128, 4).transpose(0, 2, 1).reshape(-1)[:n]
+    # the wire words match the oracle's tile layout, and the roundtrip
+    # through the 2-bit payload is lossless
+    np.testing.assert_array_equal(np.asarray(packed[0]),
+                                  np.asarray(kref.tern_pack_ref(tern[0])))
+    un = kref.tern_unpack_ref(packed[0], n)
     np.testing.assert_array_equal(np.asarray(un),
                                   np.asarray(tern[0], dtype=np.float32))
 
