@@ -1,0 +1,12 @@
+"""backward_ms: device ms per step and chip of the backward pass, the
+operations AD names ``transpose(jvp(forward))``, the remat recompute
+included (``bench/program_trace.py``).  None where the program opens no such
+scope."""
+
+from __future__ import annotations
+
+from bench import program_trace
+
+
+def read(tr, run):
+    return program_trace.scope_ms(tr, run, program_trace.backward)

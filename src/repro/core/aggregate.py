@@ -244,19 +244,20 @@ def _int8_code_reduce(compressor, c: Compressed, p, axes, alive_g, denom,
         sg = comms.all_gather(payload["s"], axes, axis=0).reshape(-1)
     else:
         sg = jnp.asarray((p or {}).get("levels", compressor.levels), f32)
-    w = ng / sg
-    if alive_g is not None:
-        w = w * alive_g
-    if integ is not None:
-        valid_g = (integrity.scale_valid(ng, sg)
-                   * integrity.code_valid(cg, sg, per_row=True))
-        w = jnp.where(valid_g > 0, w, 0.0)
-        denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
-        own_s = (payload["s"].reshape(()) if "s" in payload else sg)
-        integ["valid_bucket"] = (
-            integrity.scale_valid(payload["norm"].reshape(()), own_s)
-            * integrity.code_valid(payload["code"], own_s))
-    return ops.int8_weighted_sum(cg, w) / denom
+    with jax.named_scope("decode"):
+        w = ng / sg
+        if alive_g is not None:
+            w = w * alive_g
+        if integ is not None:
+            valid_g = (integrity.scale_valid(ng, sg)
+                       * integrity.code_valid(cg, sg, per_row=True))
+            w = jnp.where(valid_g > 0, w, 0.0)
+            denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
+            own_s = (payload["s"].reshape(()) if "s" in payload else sg)
+            integ["valid_bucket"] = (
+                integrity.scale_valid(payload["norm"].reshape(()), own_s)
+                * integrity.code_valid(payload["code"], own_s))
+        return ops.int8_weighted_sum(cg, w) / denom
 
 
 def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
@@ -283,23 +284,28 @@ def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
 
     if wr in ("sign_vote", "sign_acc"):
         # pack straight from a — the int8 sign payload is never formed
-        packed = ops.sign_pack(a)
+        with jax.named_scope("encode"):
+            packed = ops.sign_pack(a)
         if integ is not None:
             packed = integrity.corrupt_codes(integ["kind"], packed,
                                              integ["flag"])
         with comms.wire_format("packed1"):
             pg = comms.all_gather(packed, axes, axis=0)
-        w = jnp.ones((pg.shape[0],), f32) if alive_g is None else alive_g
-        votes = ops.sign_vote(pg, w, n=a.size)
-        self_hat = jnp.where(a >= 0, 1.0, -1.0).astype(f32)
-        if wr == "sign_vote":  # majority: masked shards cast zero votes
-            return jnp.where(votes >= 0, 1.0, -1.0).astype(f32), self_hat
-        return votes / denom, self_hat  # mean of ±1 votes
+        with jax.named_scope("decode"):
+            w = jnp.ones((pg.shape[0],), f32) if alive_g is None else alive_g
+            votes = ops.sign_vote(pg, w, n=a.size)
+            self_hat = jnp.where(a >= 0, 1.0, -1.0).astype(f32)
+            if wr == "sign_vote":  # majority: masked shards cast zero votes
+                return jnp.where(votes >= 0, 1.0, -1.0).astype(f32), self_hat
+            return votes / denom, self_hat  # mean of ±1 votes
 
-    c = compress_p(compressor, key, a, p)
-    self_hat = decompress_p(compressor, c, p)
+    with jax.named_scope("encode"):
+        c = compress_p(compressor, key, a, p)
+    with jax.named_scope("decode"):
+        self_hat = decompress_p(compressor, c, p)
     if wr == "tern_acc":
-        packed = ops.tern_pack(c.payload["tern"])
+        with jax.named_scope("encode"):
+            packed = ops.tern_pack(c.payload["tern"])
         scale = c.payload["scale"]
         if integ is not None:
             packed = integrity.corrupt_codes(integ["kind"], packed,
@@ -309,16 +315,17 @@ def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
         with comms.wire_format("packed2"):
             pg = comms.all_gather(packed, axes, axis=0)
         sg = comms.all_gather(scale, axes, axis=0).reshape(-1)
-        w = sg if alive_g is None else sg * alive_g
-        if integ is not None:
-            valid_g = (integrity.packed2_valid(pg, per_row=True)
-                       * integrity.scale_valid(sg))
-            w = jnp.where(valid_g > 0, w, 0.0)
-            denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
-            integ["valid_bucket"] = (
-                integrity.packed2_valid(packed)
-                * integrity.scale_valid(scale.reshape(())))
-        return ops.tern_acc(pg, w, n=c.n) / denom, self_hat
+        with jax.named_scope("decode"):
+            w = sg if alive_g is None else sg * alive_g
+            if integ is not None:
+                valid_g = (integrity.packed2_valid(pg, per_row=True)
+                           * integrity.scale_valid(sg))
+                w = jnp.where(valid_g > 0, w, 0.0)
+                denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
+                integ["valid_bucket"] = (
+                    integrity.packed2_valid(packed)
+                    * integrity.scale_valid(scale.reshape(())))
+            return ops.tern_acc(pg, w, n=c.n) / denom, self_hat
     if wr == "int8_acc":
         return _int8_code_reduce(compressor, c, p, axes, alive_g, denom,
                                  integ=integ), self_hat
@@ -382,8 +389,10 @@ def _aggregate_one(
                                   _gather_alive(alive, axes), denom,
                                   integ=integ)
 
-    c = compress_p(compressor, key, a, p)
-    self_hat = decompress_p(compressor, c, p)
+    with jax.named_scope("encode"):
+        c = compress_p(compressor, key, a, p)
+    with jax.named_scope("decode"):
+        self_hat = decompress_p(compressor, c, p)
     mode = compressor.reduce_mode
 
     if mode == "majority":
@@ -399,7 +408,8 @@ def _aggregate_one(
         elif alive is not None:
             sign = sign * alive.astype(sign.dtype)
         votes = comms.psum(sign, axes)
-        agg = jnp.where(votes >= 0, 1.0, -1.0).astype(f32)
+        with jax.named_scope("decode"):
+            agg = jnp.where(votes >= 0, 1.0, -1.0).astype(f32)
     elif mode == "sum":
         dense = c.payload["dense"]
         if integ is not None:
@@ -441,28 +451,29 @@ def _aggregate_one(
                     valid_g = valid_g * integrity.code_valid(
                         v.reshape(n_workers, -1), code_bound, per_row=True)
             denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
-        if "indices" in gathered:  # sparse (values, indices): one scatter-add
-            vals2d = gathered["values"].reshape(n_workers, -1)
-            if valid_g is not None:
-                wrow = alive_g * valid_g
-                vals2d = jnp.where(wrow[:, None] > 0, vals2d, 0.0)
-            elif alive_g is not None:
-                vals2d = vals2d * alive_g[:, None]
-            vals = vals2d.reshape(-1)
-            idx = gathered["indices"].reshape(-1)
-            agg = jnp.zeros((c.n,), f32).at[idx].add(vals) / denom
-        else:
-            wrow_g = None if valid_g is None else alive_g * valid_g
+        with jax.named_scope("decode"):
+            if "indices" in gathered:  # sparse (values, indices): one scatter-add
+                vals2d = gathered["values"].reshape(n_workers, -1)
+                if valid_g is not None:
+                    wrow = alive_g * valid_g
+                    vals2d = jnp.where(wrow[:, None] > 0, vals2d, 0.0)
+                elif alive_g is not None:
+                    vals2d = vals2d * alive_g[:, None]
+                vals = vals2d.reshape(-1)
+                idx = gathered["indices"].reshape(-1)
+                agg = jnp.zeros((c.n,), f32).at[idx].add(vals) / denom
+            else:
+                wrow_g = None if valid_g is None else alive_g * valid_g
 
-            def body(w, acc):
-                pw = {k: jax.lax.dynamic_index_in_dim(v, w, 0, keepdims=False) for k, v in gathered.items()}
-                dec = decompress_p(compressor, Compressed(pw, c.n), p)
-                if wrow_g is not None:
-                    return acc + jnp.where(wrow_g[w] > 0, dec,
-                                           jnp.zeros_like(dec))
-                return acc + (dec if alive_g is None else alive_g[w] * dec)
+                def body(w, acc):
+                    pw = {k: jax.lax.dynamic_index_in_dim(v, w, 0, keepdims=False) for k, v in gathered.items()}
+                    dec = decompress_p(compressor, Compressed(pw, c.n), p)
+                    if wrow_g is not None:
+                        return acc + jnp.where(wrow_g[w] > 0, dec,
+                                               jnp.zeros_like(dec))
+                    return acc + (dec if alive_g is None else alive_g[w] * dec)
 
-            agg = jax.lax.fori_loop(0, n_workers, body, jnp.zeros((c.n,), f32)) / denom
+                agg = jax.lax.fori_loop(0, n_workers, body, jnp.zeros((c.n,), f32)) / denom
 
     if getattr(compressor, "re_sparsify", False):  # gTop-k [191]
         kk = compressor.k or max(1, int(c.n * compressor.ratio))
@@ -605,8 +616,9 @@ def aggregate_buckets(
                 decay = (knobs["ef_decay"] if knobs is not None
                          else jnp.asarray(comm.ef_decay, f32))
                 ef_prev = state["ef"][i]
-                c, e_new = compressor.compress_ef_p(
-                    jax.random.fold_in(key, i), g, ef_prev, p_i, decay)
+                with jax.named_scope("encode"):
+                    c, e_new = compressor.compress_ef_p(
+                        jax.random.fold_in(key, i), g, ef_prev, p_i, decay)
                 denom = n_workers if n_eff is None else n_eff
                 agg = _int8_code_reduce(
                     compressor, c, p_i, axes, _gather_alive(alive, axes),
@@ -623,8 +635,9 @@ def aggregate_buckets(
                 out_bufs.append(agg)
                 continue
             u_prev = state["u"][i] if "u" in state else None
-            a = feedback.pre_compress(comm, g, state, i, n_workers,
-                                      knobs=knobs, alive=alive)
+            with jax.named_scope("encode"):
+                a = feedback.pre_compress(comm, g, state, i, n_workers,
+                                          knobs=knobs, alive=alive)
             if getattr(compressor, "reduce_mode", "") == "powersgd":
                 # powersgd's wire is a pair of factor psums — no per-worker
                 # payload to corrupt in-domain (rejected at scenario level)
@@ -643,7 +656,8 @@ def aggregate_buckets(
             if integ is not None:
                 av = alive * integ["valid_bucket"]
             if compressor is not None:
-                feedback.post_compress(comm, a, self_hat, state, i, alive=av)
+                with jax.named_scope("decode"):
+                    feedback.post_compress(comm, a, self_hat, state, i, alive=av)
             if integ is not None and u_prev is not None:
                 # momentum accumulated the quarantined round pre-compression;
                 # undo — the freeze path for a state the validator gates late
@@ -695,10 +709,14 @@ def aggregate_gradients(
     pass through to :func:`aggregate_buckets` (pod-granular churn masks /
     externally-held pipelined masks)."""
     leaves, treedef = jax.tree.flatten(grads)
-    bufs = _gather_buckets(plan, leaves)
+    # the bucket packing and unpacking carry no collective, so tagging them
+    # moves no wire bytes between tags; it names them in a device trace
+    with comms.tag("grad_agg"):
+        bufs = _gather_buckets(plan, leaves)
     out_bufs, state = aggregate_buckets(
         comm, plan, bufs, comm_state, key, axes, knobs=knobs,
         mask_axes=mask_axes, alive_info=alive_info,
     )
-    new_leaves = _scatter_buckets(plan, out_bufs, leaves)
+    with comms.tag("grad_agg"):
+        new_leaves = _scatter_buckets(plan, out_bufs, leaves)
     return jax.tree.unflatten(treedef, new_leaves), state

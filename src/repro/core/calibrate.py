@@ -21,8 +21,7 @@ This module measures the machine instead:
   workload (dense BSP), the compute term for trainer-lane step-time
   predictions.
 
-Measurements are optionally captured under ``jax.profiler.trace`` so the raw
-trace backing a profile can be inspected.  The fitted
+The fitted
 :class:`CalibrationProfile` persists as JSON next to the persistent
 compilation cache (``<cache_dir>/calibration.json``,
 :mod:`repro.core.compilecache`) and threads into predictions through the
@@ -31,7 +30,7 @@ module-level ACTIVE profile: ``set_active(profile)`` makes
 launch, and compute constants; with no active profile every prediction is
 bit-identical to the uncalibrated repo.
 
-CLI: ``python -m repro.core.calibrate [--out PATH] [--trace-dir PATH]``.
+CLI: ``python -m repro.core.calibrate [--out PATH]``.
 """
 
 from __future__ import annotations
@@ -234,34 +233,14 @@ def calibrate(
     *,
     steps: int = 6,
     repeats: int = 5,
-    trace_dir: str | None = None,
 ) -> CalibrationProfile:
     """Measure this machine, fit the constants, optionally persist.
 
-    ``out``: profile path (defaults to ``<cache_dir>/calibration.json``).
-    ``trace_dir``:
-    capture the measurement run under ``jax.profiler.trace`` (best-effort —
-    calibration still succeeds if the profiler is unavailable)."""
-    import jax
-
-    tracing = False
-    if trace_dir is not None:
-        try:
-            jax.profiler.start_trace(trace_dir)
-            tracing = True
-        except Exception:  # pragma: no cover - profiler backend missing
-            pass
-    try:
-        sizes, times = measure_collective_times(repeats=repeats)
-        alpha, beta = fit_alpha_beta(sizes, times)
-        t_launch = measure_launch_overhead()
-        t_step = measure_dense_step(steps=steps)
-    finally:
-        if tracing:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # pragma: no cover
-                pass
+    ``out``: profile path (defaults to ``<cache_dir>/calibration.json``)."""
+    sizes, times = measure_collective_times(repeats=repeats)
+    alpha, beta = fit_alpha_beta(sizes, times)
+    t_launch = measure_launch_overhead()
+    t_step = measure_dense_step(steps=steps)
     profile = CalibrationProfile(
         alpha=alpha, beta=beta, t_launch=t_launch, t_step_dense=t_step,
         meta={
@@ -269,7 +248,6 @@ def calibrate(
             "sizes_bytes": sizes,
             "times_s": times,
             "dense_steps": steps,
-            "trace_dir": trace_dir if tracing else None,
             "fitted_unix": time.time(),
         })
     path = out or default_path()
@@ -285,14 +263,12 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None,
                     help="profile JSON path (default: calibration.json in the "
                          "compile cache directory)")
-    ap.add_argument("--trace-dir", default=None,
-                    help="capture the run under jax.profiler.trace here")
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--repeats", type=int, default=5)
     args = ap.parse_args(argv)
     compilecache.configure()
     profile = calibrate(args.out or None, steps=args.steps,
-                        repeats=args.repeats, trace_dir=args.trace_dir)
+                        repeats=args.repeats)
     print(json.dumps(profile.as_dict(), indent=1))
     return 0
 

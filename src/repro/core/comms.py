@@ -153,10 +153,14 @@ def loop(n: int):
 
 @contextlib.contextmanager
 def tag(name: str):
+    """Record collectives issued inside under ``name``; the operations traced
+    inside also carry ``name`` as a ``jax.named_scope``, so a device trace
+    finds them under the same name."""
     prev = _tag()
     _STATE.tag = name
     try:
-        yield
+        with jax.named_scope(name):
+            yield
     finally:
         _STATE.tag = prev
 

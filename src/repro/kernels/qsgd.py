@@ -50,5 +50,6 @@ def qsgd_2d(x2: jax.Array, u2: jax.Array, inv_norm: jax.Array,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
+        name="qsgd_quantize",
         interpret=interpret,
     )(x2, u2, inv_norm, levels)

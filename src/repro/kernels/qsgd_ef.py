@@ -51,5 +51,6 @@ def qsgd_ef_2d(g2, e2, u2, inv_norm, levels, decay, *, interpret: bool = False):
         grid=grid,
         in_specs=[blk(), blk(), blk(), scalar(), scalar(), scalar()],
         out_specs=(blk(), blk()),
+        name="qsgd_ef_fused",
         interpret=interpret,
     )(g2, e2, u2, inv_norm, levels, decay)

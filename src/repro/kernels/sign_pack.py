@@ -54,6 +54,7 @@ def sign_pack_2d(x2: jax.Array, *, interpret: bool = False) -> jax.Array:
         grid=(word_rows // r,),
         in_specs=[pl.BlockSpec((BITS * r, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
+        name="sign_pack",
         interpret=interpret,
     )(x2)
 
@@ -75,5 +76,6 @@ def sign_unpack_2d(packed: jax.Array, *, interpret: bool = False) -> jax.Array:
         grid=(word_rows // r,),
         in_specs=[pl.BlockSpec((r, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BITS * r, LANES), lambda i: (i, 0)),
+        name="sign_unpack",
         interpret=interpret,
     )(packed)

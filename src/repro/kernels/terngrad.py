@@ -28,5 +28,6 @@ def terngrad_2d(x2: jax.Array, u2: jax.Array, inv_smax: jax.Array, *,
         grid=(rows // BLOCK_ROWS,),
         in_specs=[blk(), blk(), pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=blk(),
+        name="terngrad_quantize",
         interpret=interpret,
     )(x2, u2, inv_smax)

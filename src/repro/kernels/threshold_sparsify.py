@@ -45,5 +45,6 @@ def threshold_2d(x2: jax.Array, tau: jax.Array, *, interpret: bool = False):
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((COUNT_ROWS, LANES), lambda i: (i, 0)),
         ),
+        name="threshold_sparsify",
         interpret=interpret,
     )(x2, tau)

@@ -47,7 +47,7 @@ def _vote_kernel(p_ref, w_ref, o_ref):
             lambda p: ((p >> j) & 1).astype(f32) * 2.0 - 1.0, p_ref, w_ref)
 
 
-def _acc_call(kernel, packed, weights, slots, interpret):
+def _acc_call(kernel, packed, weights, slots, interpret, name):
     n_w, word_rows, _ = packed.shape
     r = block_rows(word_rows)
     return pl.pallas_call(
@@ -59,6 +59,7 @@ def _acc_call(kernel, packed, weights, slots, interpret):
             pl.BlockSpec((n_w, LANES), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((slots * r, LANES), lambda i: (i, 0)),
+        name=name,
         interpret=interpret,
     )(packed, weights)
 
@@ -67,7 +68,8 @@ def sign_vote_3d(packed: jax.Array, weights: jax.Array, *,
                  interpret: bool = False) -> jax.Array:
     """packed (W, word_rows, 128) int32, weights (W, 128) f32 ->
     (BITS*word_rows, 128) f32 weighted vote sums."""
-    return _acc_call(_vote_kernel, packed, weights, BITS, interpret)
+    return _acc_call(_vote_kernel, packed, weights, BITS, interpret,
+                     "sign_vote")
 
 
 def _tern_pack_kernel(t_ref, o_ref):
@@ -93,6 +95,7 @@ def tern_pack_2d(t2: jax.Array, *, interpret: bool = False) -> jax.Array:
         grid=(word_rows // r,),
         in_specs=[pl.BlockSpec((TERN_SLOTS * r, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
+        name="tern_pack",
         interpret=interpret,
     )(t2)
 
@@ -115,7 +118,8 @@ def tern_acc_3d(packed: jax.Array, weights: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
     """packed (W, word_rows, 128) int32, weights (W, 128) f32 ->
     (16*word_rows, 128) f32 = sum_w weights[w] * decode(packed[w])."""
-    return _acc_call(_tern_acc_kernel, packed, weights, TERN_SLOTS, interpret)
+    return _acc_call(_tern_acc_kernel, packed, weights, TERN_SLOTS,
+                     interpret, "tern_acc")
 
 
 def _int8_acc_kernel(c_ref, w_ref, o_ref):
@@ -138,5 +142,6 @@ def int8_acc_3d(codes: jax.Array, weights: jax.Array, *,
             pl.BlockSpec((n_w, LANES), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
+        name="int8_weighted_sum",
         interpret=interpret,
     )(codes, weights)

@@ -87,6 +87,7 @@ def wkv6_chunked(
             pl.BlockSpec((1, hd, hd), lambda i, j: (i, 0, 0)),
         ),
         scratch_shapes=[pltpu.VMEM((hd, hd), f32)],
+        name="wkv6",
         interpret=interpret,
         **kwargs,
     )(r, k, v, w, u, s0)

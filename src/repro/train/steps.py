@@ -587,7 +587,10 @@ def _compile_bundle(
     def make_step(do_aggregate: bool):
         def _grads(params, batch):
             def loss_fn(p):
-                loss, metrics = T.forward_loss(cfg, p, batch, ax)
+                # under AD the scope names the backward pass too:
+                # transpose(jvp(forward)), its remat recompute inside it
+                with jax.named_scope("forward"):
+                    loss, metrics = T.forward_loss(cfg, p, batch, ax)
                 return loss, metrics
 
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -642,7 +645,9 @@ def _compile_bundle(
                 (l, m), g = _grads(params, b)
                 g = _fix_model_grads(g, param_specs, ax.model)
                 leaves, _ = jax.tree.flatten(g)
-                return aggregate._gather_buckets(bplan, leaves), (l, m)
+                with comms.tag("grad_agg"):
+                    bufs = aggregate._gather_buckets(bplan, leaves)
+                return bufs, (l, m)
 
             acc0 = [jnp.zeros((b.size,), f32) for b in bplan.buckets]
 
@@ -730,8 +735,9 @@ def _compile_bundle(
                 acc = [a + g for a, g in zip(acc, agg)]
                 cstate = dict(cstate)
             leaves, treedef = jax.tree.flatten(params)
-            new_leaves = aggregate._scatter_buckets(
-                bplan, [a / M for a in acc], leaves)
+            with comms.tag("grad_agg"):
+                new_leaves = aggregate._scatter_buckets(
+                    bplan, [a / M for a in acc], leaves)
             return jax.tree.unflatten(treedef, new_leaves), cstate, loss, metrics
 
         def _step(state, batch, lr, knobs):
@@ -747,9 +753,10 @@ def _compile_bundle(
                         comm, bplan, grads, cstate, key, agg_axes, knobs=knobs,
                         mask_axes=mask_axes,
                     )
-            if clip_norm:
-                grads = global_clip(grads, knobs["clip_norm"])
-            new_params, opt_state = opt.update(grads, state["opt"], params, lr)
+            with jax.named_scope("optimizer"):
+                if clip_norm:
+                    grads = global_clip(grads, knobs["clip_norm"])
+                new_params, opt_state = opt.update(grads, state["opt"], params, lr)
             loss = comms.pmean(loss, ax.data)
             out = {
                 "loss": loss,
@@ -903,14 +910,16 @@ def _compile_bundle(
             params = state["params"]
 
             def loss_fn(p):
-                loss, m = T.forward_loss(cfg, p, batch, ax)
+                with jax.named_scope("forward"):
+                    loss, m = T.forward_loss(cfg, p, batch, ax)
                 return loss, m
 
             (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             grads = _fix_model_grads(grads, param_specs, ax.model)
             # grads are per-worker over the data axes (decentralized);
             # local SGD update then neighbor mixing (D-PSGD [51] / CHOCO [164])
-            new_params, opt_state = opt.update(grads, state["opt"], params, lr)
+            with jax.named_scope("optimizer"):
+                new_params, opt_state = opt.update(grads, state["opt"], params, lr)
             leaves, treedef = jax.tree.flatten(new_params)
             bufs = aggregate._gather_buckets(bplan, leaves)
             cstate = dict(state["comm"])

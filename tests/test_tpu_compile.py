@@ -75,17 +75,28 @@ def _cases():
     }
 
 
-KERNELS = ("qsgd_2d", "qsgd_ef_2d", "terngrad_2d", "sign_pack_2d",
-           "sign_unpack_2d", "sign_vote_3d", "tern_pack_2d", "tern_acc_3d",
-           "int8_acc_3d", "threshold_2d", "wkv6_chunked")
+# kernel -> the ``kernels.ops`` wrapper that calls it: each kernel's
+# custom-call carries that name, which a device trace reads
+KERNELS = {"qsgd_2d": "qsgd_quantize", "qsgd_ef_2d": "qsgd_ef_fused",
+           "terngrad_2d": "terngrad_quantize", "sign_pack_2d": "sign_pack",
+           "sign_unpack_2d": "sign_unpack", "sign_vote_3d": "sign_vote",
+           "tern_pack_2d": "tern_pack", "tern_acc_3d": "tern_acc",
+           "int8_acc_3d": "int8_weighted_sum",
+           "threshold_2d": "threshold_sparsify", "wkv6_chunked": "wkv6"}
 
 
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(one_chip, name):
+    import re
+
     import jax
 
     kernel, specs = _cases()[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
     compiled = jax.jit(lambda *a: kernel(*a, interpret=False)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    calls = re.findall(
+        r"(%[\w.-]+) = [^\n]*custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert calls and all(re.fullmatch(rf"%{KERNELS[name]}\.\d+", c) for c in calls), calls
