@@ -9,6 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention as _fa
 from repro.kernels import qsgd as _qsgd
 from repro.kernels import qsgd_ef as _qsgd_ef
 from repro.kernels import sign_pack as _sign
@@ -207,3 +208,40 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array, u: jax.Array,
                               interpret=_interpret())
     y = jnp.moveaxis(y.reshape(B, H, S + pad, hd), 1, 2)[:, :S]
     return y, sT.reshape(B, H, hd, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k"))
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, q_offset, *, window: int,
+                    block_q: int, block_k: int) -> jax.Array:
+    """Causal attention with an optional sliding window, fused: q (B, Sq, H,
+    hd), k (B, Sk, KV, hd), v (B, Sk, KV, hd_v) -> (B, Sq, H, hd_v) in q's
+    dtype.  Query head h reads KV head h // (H / KV); query row r sits at
+    position ``q_offset + r`` (a traced scalar is fine), key c at c, and sees
+    the keys with ``0 <= q - k < window``; every row must see one.  Block
+    sizes divide the sequence lengths and are multiples of 128, as are the
+    head dims (:func:`repro.kernels.flash_attention.block_sizes`)."""
+    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
+    return _flash(q, k, v, off, window, block_q, block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, off, window, block_q, block_k):
+    return _flash_fwd(q, k, v, off, window, block_q, block_k)[0]
+
+
+def _flash_fwd(q, k, v, off, window, block_q, block_k):
+    o, lse = _fa.flash_fwd(q, k, v, off, window=window, block_q=block_q, block_k=block_k,
+                           interpret=_interpret())
+    return o, (q, k, v, off, o, lse)
+
+
+def _flash_bwd(window, block_q, block_k, res, do):
+    q, k, v, off, o, lse = res
+    di = jnp.einsum("bqhd,bqhd->bhq", o.astype(f32), do.astype(f32))[:, :, None]
+    kw = dict(window=window, block_q=block_q, block_k=block_k, interpret=_interpret())
+    dq = _fa.flash_bwd_dq(q, k, v, off, do, lse, di, **kw)
+    dk, dv = _fa.flash_bwd_dkv(q, k, v, off, do, lse, di, **kw)
+    return dq, dk, dv, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)

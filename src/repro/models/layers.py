@@ -20,11 +20,13 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 
 from repro.configs.base import ModelConfig
 from repro.core.comms import all_gather, all_to_all, pmax, psum
+from repro.kernels import flash_attention, ops
 from repro.models.sharding import AxisCtx, ParamDef, ShapePlan
 
 f32 = jnp.float32
@@ -269,22 +271,63 @@ def _window_mask(q_pos: jax.Array, k_pos: jax.Array, window: int, causal: bool) 
     return ok
 
 
+def flash_blocks(
+    backend: str, q_shape: tuple, k_shape: tuple, v_shape: tuple, causal: bool
+) -> tuple[int, int] | None:
+    """(block_q, block_k) of the fused attention kernel where it runs these
+    shapes, else None: on a TPU, causal, head dims multiples of 128, query
+    heads a multiple of the KV heads, sequence lengths that blocks divide."""
+    (_, Sq, H, hd), (_, Sk, KV, hd_k), hd_v = q_shape, k_shape, v_shape[-1]
+    if backend != "tpu" or not causal or hd != hd_k or H % KV:
+        return None
+    if hd % flash_attention.LANES or hd_v % flash_attention.LANES:
+        return None
+    return flash_attention.block_sizes(Sq, Sk)
+
+
+def _is_grouping(kv_map, H: int, KV: int) -> bool:
+    """A static map that is sdpa's own grouping: q head h -> kv head h // (H/KV)."""
+    return isinstance(kv_map, np.ndarray) and np.array_equal(kv_map, np.arange(H) // (H // KV))
+
+
 def sdpa_chunked(
     q: jax.Array,  # (B, Sq, H, hd)
     k: jax.Array,  # (B, Sk, KV, hd)
     v: jax.Array,
     *,
-    q_pos: jax.Array,  # (Sq,)
-    k_pos: jax.Array,  # (Sk,)
     window: int,
     causal: bool = True,
+    q_offset: int | jax.Array = 0,  # position of query row 0; keys sit at 0..Sk-1
+    kv_map: np.ndarray | jax.Array | None = None,  # (H,) kv head of each q head
     q_chunk: int = 1024,
 ) -> jax.Array:
-    """Exact attention, scanned over query chunks to bound the score buffer.
+    """Exact attention under the name scope ``attention``.
 
     GQA: H must be a multiple of KV (after padding); each group of
-    H/KV query heads shares one KV head.
+    H/KV query heads shares one KV head, unless ``kv_map`` names each q
+    head's KV head, which then gathers K/V to H heads (no gather where the
+    map is static and is that grouping, and the kernel runs).
+
+    On a TPU, where :func:`flash_blocks` fits the shapes, one fused kernel
+    (``kernels/flash_attention.py``) computes it with no score matrix in HBM;
+    elsewhere the jnp path below, scanned over query chunks to bound the
+    score buffer.
     """
+    with jax.named_scope("attention"):
+        _, Sq, H, _ = q.shape
+        blocks = flash_blocks(jax.default_backend(), q.shape, k.shape, v.shape, causal)
+        if kv_map is not None and not (blocks and _is_grouping(kv_map, H, k.shape[2])):
+            k = jnp.take(k, kv_map, axis=2)
+            v = jnp.take(v, kv_map, axis=2)
+        if blocks:
+            return ops.flash_attention(q, k, v, q_offset, window=window, block_q=blocks[0],
+                                       block_k=blocks[1])
+        return _sdpa_jnp(q, k, v, q_pos=q_offset + jnp.arange(Sq),
+                         k_pos=jnp.arange(k.shape[1]), window=window, causal=causal,
+                         q_chunk=q_chunk)
+
+
+def _sdpa_jnp(q, k, v, *, q_pos, k_pos, window, causal, q_chunk):
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     KV = k.shape[2]
@@ -356,20 +399,21 @@ def attention(
     if kv_source is None:
         q = apply_rope(cfg, q, positions)
         kk = apply_rope(cfg, kk, positions)
-    q_pos = jnp.arange(S)
-    k_pos = jnp.arange(Sk)
     H_l, KV_l = q.shape[2], kk.shape[2]
     i = jax.lax.axis_index(ax.model)
     gheads = i * H_l + jnp.arange(H_l)  # global (padded) q-head ids
+    sel = None
     if KV_l == cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
-        # KV replicated: gather each local q head's kv head explicitly
-        # (q-head h -> kv-head h * KV / H; padded dummy heads -> head 0).
-        sel = jnp.clip(gheads, 0, cfg.n_heads - 1) * cfg.n_kv_heads // cfg.n_heads
-        kk = jnp.take(kk, sel, axis=2)
-        vv = jnp.take(vv, sel, axis=2)
+        # KV replicated: each local q head reads kv head h * KV / H (padded
+        # dummy heads -> the last real head's); static where this shard holds
+        # every head
+        if H_l == cfg.n_heads:
+            sel = np.arange(H_l) * cfg.n_kv_heads // cfg.n_heads
+        else:
+            sel = jnp.clip(gheads, 0, cfg.n_heads - 1) * cfg.n_kv_heads // cfg.n_heads
     # else: KV sharded with aligned contiguous groups — reshape grouping works
     out = sdpa_chunked(
-        q, kk, vv, q_pos=q_pos, k_pos=k_pos, window=window, causal=causal and kv_source is None
+        q, kk, vv, window=window, causal=causal and kv_source is None, kv_map=sel
     )
     # zero padded dummy heads so their (random-weight) outputs never leak
     out = out * (gheads < cfg.n_heads)[None, None, :, None].astype(out.dtype)
@@ -391,9 +435,7 @@ def _mla_attention(cfg, p, x, ax, *, positions, window):
     H_l = q.shape[2]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H_l, cfg.qk_rope_dim))], -1)
     qq = jnp.concatenate([q_nope, q_rope], -1)
-    out = sdpa_chunked(
-        qq, k, v, q_pos=jnp.arange(S), k_pos=jnp.arange(S), window=window, causal=True
-    )
+    out = sdpa_chunked(qq, k, v, window=window, causal=True)
     i = jax.lax.axis_index(ax.model)
     gheads = i * H_l + jnp.arange(H_l)
     out = out * (gheads < cfg.n_heads)[None, None, :, None].astype(out.dtype)
@@ -408,7 +450,6 @@ def attention_seqpar(
     ax: AxisCtx,
     *,
     positions_l: jax.Array,  # (3, B, S_l) local absolute positions
-    seq_len: int,
     window: int,
 ) -> jax.Array:
     """Sequence-parallel attention (beyond-paper; DeepSpeed-Ulysses-flavored,
@@ -430,13 +471,9 @@ def attention_seqpar(
     with jax.named_scope("kv_allgather"):
         kk = all_gather(kk, ax.model, axis=1, tiled=True)  # (B, S, KV, hd)
         vv = all_gather(vv, ax.model, axis=1, tiled=True)
-    q_pos = i * S_l + jnp.arange(S_l)
     # all heads are local here (16x the baseline's per-shard head count), so
     # bound the f32 score buffer with a smaller q chunk
-    out = sdpa_chunked(
-        q, kk, vv, q_pos=q_pos, k_pos=jnp.arange(seq_len), window=window,
-        causal=True, q_chunk=128,
-    )
+    out = sdpa_chunked(q, kk, vv, window=window, causal=True, q_offset=i * S_l, q_chunk=128)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])  # no psum: wo replicated
 
 

@@ -386,7 +386,7 @@ def prefill_seqpar(
     def block(x, p):
         h_in = L.rmsnorm(p["ln1"], x)
         x = x + L.attention_seqpar(cfg, p["attn"], h_in, ax, positions_l=pos_l,
-                                   seq_len=S, window=cfg.layer_window("global", S))
+                                   window=cfg.layer_window("global", S))
         # FFN on sequence shards: tokens stay local, so each shard needs the
         # FULL dff — gather the (column/row-sharded) weights per layer
         # (ZeRO-3-style transient gather; a psum here would wrongly mix

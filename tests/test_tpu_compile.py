@@ -5,7 +5,8 @@ the kernels compute; they cannot see what the TPU compiler refuses: blocks
 not aligned to the native tiles, casts and reductions Mosaic does not
 lower, shape casts it cannot lay out.  These tests compile each kernel with
 ``interpret=False`` for a described ``v5e:2x2`` topology (no chip needed)
-at a 4M-element bucket, W=4 gathered workers for the wire kernels.
+at a 4M-element bucket, W=4 gathered workers for the wire kernels, and the
+attention kernels at the benchmark cells' shapes.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -47,7 +48,9 @@ def one_chip():
 def _cases():
     """name -> (kernel call, [(shape, dtype name)]) at the shapes the
     ``kernels.ops`` wrappers hand the kernels for an N-element bucket."""
-    from repro.kernels import (qsgd, qsgd_ef, sign_pack, terngrad,
+    import functools
+
+    from repro.kernels import (flash_attention, qsgd, qsgd_ef, sign_pack, terngrad,
                                threshold_sparsify, wire_reduce, wkv6)
 
     x2, s11 = ((ROWS, LANES), "float32"), ((1, 1), "float32")
@@ -55,7 +58,7 @@ def _cases():
     tern_words = ROWS // wire_reduce.TERN_SLOTS  # 16 two-bit slots per word
     weights = ((W, LANES), "float32")
     bh, seq, hd = 64, 4096, 64  # wkv6: B*H rows of a 4096-token sequence
-    return {
+    cases = {
         "qsgd_2d": (qsgd.qsgd_2d, [x2, x2, s11, s11]),
         "qsgd_ef_2d": (qsgd_ef.qsgd_ef_2d, [x2, x2, x2, s11, s11, s11]),
         "terngrad_2d": (terngrad.terngrad_2d, [x2, x2, s11]),
@@ -73,6 +76,20 @@ def _cases():
                          [((bh, seq, hd), "float32")] * 4
                          + [((bh, 1, hd), "float32"), ((bh, hd, hd), "float32")]),
     }
+    # the attention kernels at the benchmark's cells: GLM-4-9B (4096 tokens,
+    # 32 query heads on 2 KV heads) and Qwen3-0.6B (2048, 16 on 8), hd 128
+    for cell, (S, H, KV) in {"glm4": (4096, 32, 2), "qwen3": (2048, 16, 8)}.items():
+        q, kv = ((1, S, H, 128), "bfloat16"), ((1, S, KV, 128), "bfloat16")
+        row = ((1, H, 1, S), "float32")
+        bq, bk = flash_attention.block_sizes(S, S)
+        kw = dict(window=S, block_q=bq, block_k=bk)
+        fwd = [q, kv, kv, ((1,), "int32")]
+        cases[f"flash_fwd_{cell}"] = (functools.partial(flash_attention.flash_fwd, **kw), fwd)
+        for k in ("dq", "dkv"):
+            cases[f"flash_{k}_{cell}"] = (
+                functools.partial(getattr(flash_attention, f"flash_bwd_{k}"), **kw),
+                fwd + [q, row, row])
+    return cases
 
 
 # kernel -> the ``kernels.ops`` wrapper that calls it: each kernel's
@@ -82,7 +99,9 @@ KERNELS = {"qsgd_2d": "qsgd_quantize", "qsgd_ef_2d": "qsgd_ef_fused",
            "sign_unpack_2d": "sign_unpack", "sign_vote_3d": "sign_vote",
            "tern_pack_2d": "tern_pack", "tern_acc_3d": "tern_acc",
            "int8_acc_3d": "int8_weighted_sum",
-           "threshold_2d": "threshold_sparsify", "wkv6_chunked": "wkv6"}
+           "threshold_2d": "threshold_sparsify", "wkv6_chunked": "wkv6",
+           **{f"flash_{k}_{cell}": f"flash_attention_{k}"
+              for k in ("fwd", "dq", "dkv") for cell in ("glm4", "qwen3")}}
 
 
 @pytest.mark.parametrize("name", KERNELS)
