@@ -70,7 +70,7 @@ def main(argv=None, *, platform: str = "tpu") -> int:
     program.configure(c.root)
     import jax  # noqa: F401
 
-    from bench.reference.model import Reference
+    from bench.reference.training import Reference
     from bench.run import NoChip, device_info
 
     try:
@@ -89,14 +89,16 @@ def main(argv=None, *, platform: str = "tpu") -> int:
         prog = program_readings(c, seeds, chips)
         batches = [program.make_source(c.config, t, seeds.data).batch(s, rows, t["seq_len"])
                    for s in range(program.CHECK_STEPS)]
-        ref = Reference(c.config, t, workers=chips).run(seeds.weights, seeds.comm, batches)
+        ref = Reference(c.config, t, c.reference, workers=chips).run(
+            seeds.weights, seeds.comm, batches)
         run = {"seed": seed, "program": correct.readings(prog, ref),
                "losses": {"program": prog["losses"], "reference": ref["losses"]},
                "worst_leaves": worst(prog, ref)}
         if i < args.faulty_seeds:
             for name, kw in [("control", {"precision": "fp8"})] + [
                     (f, {"fault": f}) for f in faults]:
-                other = Reference(c.config, t, workers=chips, **kw).run(seeds.weights, seeds.comm, batches)
+                other = Reference(c.config, t, c.reference, workers=chips, **kw).run(
+                    seeds.weights, seeds.comm, batches)
                 run[name] = correct.readings(other, ref)
         run["seconds"] = time.perf_counter() - t0
         record["runs"].append(run)

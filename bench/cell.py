@@ -6,15 +6,19 @@ A cell names a configuration and a traffic mix.  Everything else is a file:
 * ``bench/configs/<config>.json`` (the path ``BENCHMARK.json`` gives),
 * ``bench/traffic/<traffic>.json``,
 * ``bench/limits/<cell>.json`` (the limits that decide ``correct``),
+* ``bench/reference/<reference>.py`` (the configuration's reference model:
+  its ``"reference"`` key, ``model`` without it),
 * ``bench/metrics/<metric>.py`` (one reader per per-layer metric),
 * ``bench/kernels/<kernel>.py`` (a kernel's trace names and logical work).
 
-So a later change adds a cell, a metric or a kernel as new files plus an
-entry, and edits nothing that is here.
+So a later change adds a cell, a configuration with its reference model, a
+metric or a kernel as new files plus an entry, and edits nothing that is
+here.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -22,6 +26,8 @@ from dataclasses import dataclass
 from types import ModuleType
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+# what a reference model file supplies (bench/reference/model.py says what each is)
+REFERENCE_API = ("param_layout", "loss_fn", "n_params", "flops_per_token")
 
 
 @dataclass
@@ -40,6 +46,21 @@ class Cell:
     @property
     def chips(self) -> int:
         return int(self.workload["chips"])
+
+    @property
+    def reference_name(self) -> str:
+        return self.config.get("reference", "model")
+
+    @functools.cached_property
+    def reference(self) -> ModuleType:
+        """The configuration's reference model, loaded on first use: it
+        imports JAX, which has to wait for ``program.configure``."""
+        mod = load_module("reference", self.reference_name, self.root)
+        missing = [f for f in REFERENCE_API if not callable(getattr(mod, f, None))]
+        if missing:
+            raise AttributeError(f"bench/reference/{self.reference_name}.py, the reference "
+                                 f"model of {self.workload['config']!r}, lacks {missing}")
+        return mod
 
     def metrics(self, trace: bool) -> list[dict]:
         """The metrics this cell reports: end-to-end without the trace,
@@ -69,7 +90,11 @@ def resolve(name: str, root: str | None = None) -> Cell:
     bench = os.path.join(root, "bench")
     traffic = _load_json(os.path.join(bench, "traffic", w["traffic"] + ".json"))
     limits = _load_json(os.path.join(bench, "limits", name + ".json"))
-    return Cell(root, spec, w, config, traffic, limits)
+    c = Cell(root, spec, w, config, traffic, limits)
+    ref = os.path.join(bench, "reference", c.reference_name + ".py")
+    if not os.path.isfile(ref):
+        raise FileNotFoundError(f"no reference model {ref} for {w['config']!r}")
+    return c
 
 
 def load_module(kind: str, name: str, root: str | None = None) -> ModuleType:

@@ -8,8 +8,8 @@ Bound: HBM bytes.
 
 from __future__ import annotations
 
-# the kernel's custom-call in a v5e trace is named after the jitted wrapper
-# (repro.kernels.ops.int8_weighted_sum); the pallas_call itself carries no name
+# the kernel's custom-call in a v5e trace carries the pallas_call's name,
+# which is its wrapper's (repro.kernels.ops.int8_weighted_sum)
 PATTERN = r"^%int8_weighted_sum\."
 
 

@@ -11,8 +11,8 @@ against the same work.  Bound: HBM bytes.
 
 from __future__ import annotations
 
-# the kernel's custom-call in a v5e trace is named after the jitted wrapper
-# (repro.kernels.ops.qsgd_quantize); the pallas_call itself carries no name
+# the kernel's custom-call in a v5e trace carries the pallas_call's name,
+# which is its wrapper's (repro.kernels.ops.qsgd_quantize)
 PATTERN = r"^%qsgd_quantize\."
 
 

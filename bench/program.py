@@ -173,9 +173,9 @@ def update_norms(readings: dict) -> dict[str, float]:
     leaf at a time on the device."""
     import jax
 
-    from bench.reference.model import _diff_norm
+    from bench.reference.training import diff_norm
 
     flat0 = jax.tree.leaves(readings["p0"])
     flat1 = jax.tree.leaves(readings["p_end"])
-    return {path: float(_diff_norm(jax.numpy.asarray(b), jax.numpy.asarray(a)))
+    return {path: float(diff_norm(jax.numpy.asarray(b), jax.numpy.asarray(a)))
             for path, a, b in zip(leaf_paths(readings["p0"]), flat0, flat1)}

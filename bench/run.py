@@ -13,8 +13,9 @@ One run is one process:
    only at chunk ends, and report the end-to-end metrics; with ``--trace 1``,
    trace a short window of its own with the profiler and report the
    per-layer metrics, read by ``bench/metrics/<metric>.py`` from the trace;
-5. free the program's state, run the plain reference (``bench/reference``)
-   over the same first steps and compare (``bench/correct.py``);
+5. free the program's state, run the plain reference over the same first
+   steps (``bench/reference/training.py`` around the configuration's
+   reference model) and compare (``bench/correct.py``);
 6. print the result as the last line of standard output, and each compared
    number beside its limit as the last lines of standard error.
 
@@ -41,7 +42,6 @@ if ROOT not in sys.path:
 
 from bench import cell as cells  # noqa: E402
 from bench import correct, program  # noqa: E402
-from bench.flops import flops_per_token  # noqa: E402
 
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -113,6 +113,13 @@ def memory_peak(chips: int) -> int:
 
     return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in jax.devices()[:chips])
+
+
+def work(c: cells.Cell) -> dict:
+    """The work the metrics divide by, from the configuration's reference
+    model: FLOPs per trained token and the gradient's elements."""
+    return {"flops_per_token": c.reference.flops_per_token(c.config, c.traffic["seq_len"]),
+            "grad_elements": c.reference.n_params(c.config)}
 
 
 def per_layer(c: cells.Cell, tr, run: dict) -> dict:
@@ -202,13 +209,10 @@ def main(argv=None, *, platform: str = "tpu", peak: dict | None = None) -> int:
     result: dict = {}
     if args.trace:
         from bench import trace as traces
-        from bench.reference.model import n_params
 
         tr = traces.load(trace_dir)
         run = {"root": c.root, "chips": chips, "steps": steps, "tokens_per_s": tokens_per_s,
-               "flops_per_token": flops_per_token(cfg, t["seq_len"]), "peak": peak,
-               "compile_s": clock.total,
-               "grad_elements": n_params(cfg), "workers": chips}
+               "peak": peak, "compile_s": clock.total, "workers": chips, **work(c)}
         metrics = per_layer(c, tr, run)
         device.update(busy_s=traces.busy_s(tr), window_s=tr.window_s)
         result["breakdown"] = traces.breakdown(tr)
@@ -221,13 +225,13 @@ def main(argv=None, *, platform: str = "tpu", peak: dict | None = None) -> int:
                    for m in c.metrics(trace=False)}
 
     # the reference follows the same first steps, once the program is gone
-    from bench.reference.model import Reference
+    from bench.reference.training import Reference
 
     t_ref = time.perf_counter()
     batches = [program.make_source(cfg, t, seeds.data).batch(
         s, t["batch_per_chip"] * chips, t["seq_len"]) for s in range(program.CHECK_STEPS)]
     in_use = (jax.devices()[0].memory_stats() or {}).get("bytes_in_use")
-    ref = Reference(c.config, t, workers=chips).run(seeds.weights, seeds.comm, batches)
+    ref = Reference(cfg, t, c.reference, workers=chips).run(seeds.weights, seeds.comm, batches)
     prog["update_norms"] = program.update_norms(prog)
     values = correct.readings(prog, ref)
     ok, checks = correct.judge(values, c.limits)

@@ -44,7 +44,7 @@ def plant(fault: str) -> None:
 
         program.build = build_frozen
     elif fault == "half_batch":
-        from bench.reference.model import half_labels
+        from bench.reference.training import half_labels
 
         batch = program.Feed.batch
 

@@ -18,6 +18,8 @@ def test_committed_cells_resolve():
         c = cells.resolve(w["name"], REPO)
         assert c.traffic["chips"] == c.chips
         assert set(c.limits) >= {"loss_gap", "grad_gap", "update_gap"}
+        for f in cells.REFERENCE_API:
+            assert callable(getattr(c.reference, f)), (c.reference_name, f)
         for m in c.metrics(trace=True):
             cells.load_module("metrics", m["name"], REPO).read  # noqa: B018
 

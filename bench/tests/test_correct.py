@@ -47,7 +47,7 @@ def test_control_is_not_correct(tiny_root, workload):
     """The reference in fp8 (one step below bfloat16), put in the program's
     place, fails the cell's limits on every seed tried."""
     from bench import program
-    from bench.reference.model import Reference
+    from bench.reference.training import Reference
 
     c = cells.resolve(workload, tiny_root)
     t = c.traffic
@@ -55,7 +55,9 @@ def test_control_is_not_correct(tiny_root, workload):
         seeds = program.Seeds.derive(seed)
         batches = [program.make_source(c.config, t, seeds.data).batch(
             s, t["batch_per_chip"] * t["chips"], t["seq_len"]) for s in range(program.CHECK_STEPS)]
-        ref = Reference(c.config, t, workers=c.chips).run(seeds.weights, seeds.comm, batches)
-        ctl = Reference(c.config, t, workers=c.chips, precision="fp8").run(seeds.weights, seeds.comm, batches)
+        ref = Reference(c.config, t, c.reference, workers=c.chips).run(
+            seeds.weights, seeds.comm, batches)
+        ctl = Reference(c.config, t, c.reference, workers=c.chips, precision="fp8").run(
+            seeds.weights, seeds.comm, batches)
         ok, checks = correct.judge(correct.readings(ctl, ref), c.limits)
         assert not ok, checks

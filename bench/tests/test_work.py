@@ -8,7 +8,7 @@ import os
 import pytest
 
 from bench import cell as cells
-from bench.flops import flops_per_token, matmul_params
+from bench.reference.model import flops_per_token, matmul_params
 from bench.tests.helpers import REPO
 
 
@@ -44,6 +44,24 @@ def test_parameter_count(name, total):
     from bench.reference.model import n_params
 
     assert n_params(config(name)) == total
+
+
+@pytest.mark.parametrize("cell, per_token, total", [
+    # the numbers pinned above, read through the run from each committed
+    # cell's reference model
+    ("glm4-9b.dense-bsp.c1", 6 * (4 * 203_948_032 + 77_594_624) + 12 * 4 * 32 * 128 * 4096,
+     893_386_752 + 55_296),
+    ("qwen3-0.6b.dense-bsp.c1", 6 * (28 * 15_728_640 + 155_582_464) + 12 * 28 * 16 * 128 * 2048,
+     595_984_384 + 65_536),
+    ("qwen3-0.6b.qsgd-int8-cwire.c4",
+     6 * (28 * 15_728_640 + 155_582_464) + 12 * 28 * 16 * 128 * 2048, 595_984_384 + 65_536),
+])
+def test_cell_work(cell, per_token, total):
+    from bench import run
+
+    c = cells.resolve(cell, REPO)
+    assert c.reference_name == "model"
+    assert run.work(c) == {"flops_per_token": per_token, "grad_elements": total}
 
 
 @pytest.mark.parametrize("kernel, elements, workers, flops, nbytes", [
