@@ -62,10 +62,19 @@ class ModelConfig:
     # Repeating per-layer pattern of attention types, e.g. 5*("local",)+("global",)
     attn_pattern: tuple[str, ...] = ("global",)
     window: int = 1024  # sliding window for "local" layers
-    rope_type: str = "rope"  # rope | mrope | partial | none
+    rope_type: str = "rope"  # rope | mrope | partial | yarn | none
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0  # "partial": fraction of head_dim rotated
     mrope_sections: tuple[int, int, int] = (16, 24, 24)  # half-dims (t, h, w)
+    # "yarn" [arXiv:2309.00071], as DeepSeek-V2's rope_scaling names them:
+    # context extension factor, pretraining length, the ramp's rotation
+    # bounds and the two attention-temperature coefficients
+    rope_factor: float = 1.0
+    rope_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (deepseek) ---------------------------------------------------------
     kv_lora: int = 0  # latent dim; >0 enables MLA
@@ -75,18 +84,22 @@ class ModelConfig:
 
     # MoE --------------------------------------------------------------------
     moe: bool = False
+    #: routed experts held (the router's experts 0..n_experts-1); every token
+    #: routed to one of them is computed: no capacity, no token dropped
     n_experts: int = 0
+    #: the router's width; 0 means n_experts.  Wider where this program holds
+    #: its share of an expert-parallel layer, whose other experts lie on
+    #: chips that are not here
+    router_experts: int = 0
     experts_per_token: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0  # expert hidden size (d_ff used for dense layers)
     first_dense_layers: int = 0  # leading layers with dense FFN (deepseek)
+    norm_topk_prob: bool = True  # renormalize the top-k gates to sum to 1
     router_aux_coef: float = 0.001
-    #: expert-capacity factor: each expert buffers C = cf*T*k/E tokens and
-    #: DROPS the overflow. Dropping depends on how many tokens are in the
-    #: batch, so prefill+decode and a full forward pass legitimately diverge
-    #: once any expert overflows; equivalence tests raise this to disable
-    #: dropping (see tests/test_decode_equivalence.py).
-    moe_capacity_factor: float = 1.25
+    #: balance loss per sequence with DeepSeek's 1/k (seq_aux), else
+    #: Switch-style over the whole batch
+    seq_aux: bool = False
 
     # SSM / hybrid (rwkv6, hymba) ---------------------------------------------
     ssm_state: int = 16
@@ -124,6 +137,10 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.n_experts
 
     @property
     def pattern_repeats(self) -> int:
@@ -173,6 +190,7 @@ class ModelConfig:
         if self.moe:
             upd.update(
                 n_experts=min(self.n_experts, 4),
+                router_experts=min(self.router_width, 4),
                 experts_per_token=min(self.experts_per_token, 2),
                 d_ff_expert=min(self.d_ff_expert or self.d_ff, 256),
                 first_dense_layers=min(self.first_dense_layers, 1),
@@ -213,7 +231,7 @@ def n_params(cfg: ModelConfig) -> int:
     # ffn
     if cfg.moe:
         dff = cfg.d_ff_expert or cfg.d_ff
-        moe_ffn = 3 * d * dff * (cfg.n_experts + cfg.n_shared_experts) + d * cfg.n_experts
+        moe_ffn = 3 * d * dff * (cfg.n_experts + cfg.n_shared_experts) + d * cfg.router_width
         dense_ffn = 3 * d * cfg.d_ff
         n_moe = cfg.n_layers - cfg.first_dense_layers
         ffn_total = n_moe * moe_ffn + cfg.first_dense_layers * dense_ffn

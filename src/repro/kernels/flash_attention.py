@@ -11,6 +11,9 @@ times.  These kernels keep every score tile in VMEM (flash attention):
 - dK/dV: per KV block, a sweep over the query heads that share it (GQA) and
   the query blocks that see it, so K/V stay at their KV heads.
 
+Scores are q.k times ``scale``, hd^-0.5 unless given (a q/k head dim
+zero-padded to the lanes keeps the scale of its unpadded dim).
+
 The mask is causal plus an optional sliding window on ``q_pos = offset +
 arange(Sq)`` and ``k_pos = arange(Sk)``: key k is seen by query q iff
 ``0 <= q - k < window``.  Blocks that lie wholly outside it are skipped: the
@@ -167,20 +170,21 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc
 
 
 def flash_fwd(q, k, v, offset, *, window: int, block_q: int, block_k: int,
-              interpret: bool = False):
+              interpret: bool = False, scale: float | None = None):
     """q (B, Sq, H, hd), k (B, Sk, KV, hd), v (B, Sk, KV, hd_v), offset (1,)
     int32 -> (o (B, Sq, H, hd_v) in q's dtype, lse (B, H, 1, Sq) f32)."""
     B, Sq, H, hd = q.shape
     _, Sk, KV, hd_v = v.shape
     group, bq, bk = H // KV, block_q, block_k
     nq, nk = Sq // bq, Sk // bk
+    scale = hd**-0.5 if scale is None else scale
     steps = _steps(bq, bk, window, nk)
 
     def kv_block(b, h, i, j, off_ref):
         lo, hi = _kv_range(off_ref[0], i, bq=bq, bk=bk, window=window, nk=nk)
         return b, _clamp(_span(lo, steps, nk) + j, lo, hi, nk), h // group
 
-    kernel = functools.partial(_fwd_kernel, scale=hd**-0.5, window=window, bq=bq, bk=bk,
+    kernel = functools.partial(_fwd_kernel, scale=scale, window=window, bq=bq, bk=bk,
                                nk=nk, steps=steps)
     o, lse = pl.pallas_call(
         kernel,
@@ -242,7 +246,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, ac
 
 
 def flash_bwd_dq(q, k, v, offset, do, lse, di, *, window: int, block_q: int, block_k: int,
-                 interpret: bool = False):
+                 interpret: bool = False, scale: float | None = None):
     """dQ (B, Sq, H, hd) from the forward's inputs, the output cotangent do
     (B, Sq, H, hd_v), the forward's lse and di = rowsum(o * do), both
     (B, H, 1, Sq) f32."""
@@ -250,6 +254,7 @@ def flash_bwd_dq(q, k, v, offset, do, lse, di, *, window: int, block_q: int, blo
     _, Sk, KV, hd_v = v.shape
     group, bq, bk = H // KV, block_q, block_k
     nq, nk = Sq // bq, Sk // bk
+    scale = hd**-0.5 if scale is None else scale
     steps = _steps(bq, bk, window, nk)
 
     def kv_block(b, h, i, j, off_ref):
@@ -258,7 +263,7 @@ def flash_bwd_dq(q, k, v, offset, do, lse, di, *, window: int, block_q: int, blo
 
     q_block = lambda b, h, i, j, o: (b, i, h)  # noqa: E731
     row = pl.BlockSpec((None, None, 1, bq), lambda b, h, i, j, o: (b, h, 0, i))
-    kernel = functools.partial(_dq_kernel, scale=hd**-0.5, window=window, bq=bq, bk=bk,
+    kernel = functools.partial(_dq_kernel, scale=scale, window=window, bq=bq, bk=bk,
                                nk=nk, steps=steps)
     dq = pl.pallas_call(
         kernel,
@@ -321,13 +326,14 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, d
 
 
 def flash_bwd_dkv(q, k, v, offset, do, lse, di, *, window: int, block_q: int, block_k: int,
-                  interpret: bool = False):
+                  interpret: bool = False, scale: float | None = None):
     """(dK (B, Sk, KV, hd), dV (B, Sk, KV, hd_v)), summed over the query heads
     of each KV head; arguments as for :func:`flash_bwd_dq`."""
     B, Sq, H, hd = q.shape
     _, Sk, KV, hd_v = v.shape
     group, bq, bk = H // KV, block_q, block_k
     nq, nk = Sq // bq, Sk // bk
+    scale = hd**-0.5 if scale is None else scale
     steps = _steps(bk, bq, window, nq)
 
     def q_block(b, c, j, g, t, off_ref):
@@ -339,7 +345,7 @@ def flash_bwd_dkv(q, k, v, offset, do, lse, di, *, window: int, block_q: int, bl
         return b, h, 0, i
 
     kv_block = lambda b, c, j, g, t, o: (b, j, c)  # noqa: E731
-    kernel = functools.partial(_dkv_kernel, scale=hd**-0.5, window=window, bq=bq, bk=bk,
+    kernel = functools.partial(_dkv_kernel, scale=scale, window=window, bq=bq, bk=bk,
                                nq=nq, steps=steps, group=group)
     dk, dv = pl.pallas_call(
         kernel,

@@ -8,6 +8,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import qsgd as _qsgd
@@ -20,6 +21,9 @@ from repro.kernels import wkv6 as _wkv
 
 f32 = jnp.float32
 _TILE = _qsgd.BLOCK_ROWS * _qsgd.LANES  # elements per full block
+# (rows, contraction, output) tile of the grouped matmul: the largest of the
+# sizes tried on a TPU v5e whose weight-gradient kernel fits its VMEM (PERF.md)
+GMM_TILING = (512, 512, 512)
 
 
 def _interpret() -> bool:
@@ -210,38 +214,58 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array, u: jax.Array,
     return y, sT.reshape(B, H, hd, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k", "scale"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, q_offset, *, window: int,
-                    block_q: int, block_k: int) -> jax.Array:
+                    block_q: int, block_k: int, scale: float | None = None) -> jax.Array:
     """Causal attention with an optional sliding window, fused: q (B, Sq, H,
     hd), k (B, Sk, KV, hd), v (B, Sk, KV, hd_v) -> (B, Sq, H, hd_v) in q's
     dtype.  Query head h reads KV head h // (H / KV); query row r sits at
     position ``q_offset + r`` (a traced scalar is fine), key c at c, and sees
     the keys with ``0 <= q - k < window``; every row must see one.  Block
     sizes divide the sequence lengths and are multiples of 128, as are the
-    head dims (:func:`repro.kernels.flash_attention.block_sizes`)."""
+    head dims (:func:`repro.kernels.flash_attention.block_sizes`).  Scores
+    are scaled by ``scale``, hd^-0.5 where None."""
     off = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    return _flash(q, k, v, off, window, block_q, block_k)
+    return _flash(q, k, v, off, window, block_q, block_k, scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, off, window, block_q, block_k):
-    return _flash_fwd(q, k, v, off, window, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, off, window, block_q, block_k, scale):
+    return _flash_fwd(q, k, v, off, window, block_q, block_k, scale)[0]
 
 
-def _flash_fwd(q, k, v, off, window, block_q, block_k):
+def _flash_fwd(q, k, v, off, window, block_q, block_k, scale):
     o, lse = _fa.flash_fwd(q, k, v, off, window=window, block_q=block_q, block_k=block_k,
-                           interpret=_interpret())
+                           scale=scale, interpret=_interpret())
     return o, (q, k, v, off, o, lse)
 
 
-def _flash_bwd(window, block_q, block_k, res, do):
+def _flash_bwd(window, block_q, block_k, scale, res, do):
     q, k, v, off, o, lse = res
     di = jnp.einsum("bqhd,bqhd->bhq", o.astype(f32), do.astype(f32))[:, :, None]
-    kw = dict(window=window, block_q=block_q, block_k=block_k, interpret=_interpret())
+    kw = dict(window=window, block_q=block_q, block_k=block_k, scale=scale,
+              interpret=_interpret())
     dq = _fa.flash_bwd_dq(q, k, v, off, do, lse, di, **kw)
     dk, dv = _fa.flash_bwd_dkv(q, k, v, off, do, lse, di, **kw)
     return dq, dk, dv, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@jax.jit
+def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
+    """x (R, a) whose first rows lie in consecutive groups of ``sizes`` (G,)
+    rows, w (G, a, b) -> (R, b) in x's dtype: each group's rows times its
+    matrix, f32-accumulated, and zero for the rows past the groups, whose
+    tiles the kernel never visits.  JAX's megablox grouped-matmul kernel
+    (``gmm``; its VJP is ``gmm`` for the rows' gradient and ``tgmm`` for the
+    weights'), handed the rows past the groups as one more group that no
+    matrix holds: it zeroes them, in the rows' gradient too."""
+    R = x.shape[0]
+    pad = -R % GMM_TILING[0]
+    sizes = sizes.astype(jnp.int32)
+    rest = (R + pad - jnp.sum(sizes)).reshape(1)
+    out = _megablox.gmm(jnp.pad(x, ((0, pad), (0, 0))), w, jnp.concatenate([sizes, rest]),
+                        x.dtype, GMM_TILING, None, None, False, _interpret())
+    return out[:R]

@@ -16,6 +16,7 @@ Conventions
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -58,6 +59,39 @@ def _rope_cos_sin(pos: jax.Array, dim: int, theta: float) -> tuple[jax.Array, ja
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """YaRN's RoPE frequencies over ``dim`` rotated dims, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: the extrapolated
+    frequencies theta^(-2i/dim) for the dims that turn more than
+    ``yarn_beta_fast`` times over ``rope_original_len`` positions, the
+    interpolated ones (divided by ``rope_factor``) for those that turn fewer
+    than ``yarn_beta_slow`` times, and a linear ramp between."""
+    def correction_dim(rotations):
+        return (dim * math.log(cfg.rope_original_len / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(correction_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / cfg.rope_theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0.0, 1.0)
+    return (extra / cfg.rope_factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def softmax_scale(cfg: ModelConfig, qk_dim: int) -> float:
+    """qk_dim^-0.5, times YaRN's mscale(factor, mscale_all_dim)^2 where set."""
+    scale = qk_dim**-0.5
+    if cfg.rope_type == "yarn" and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x (..., dim); cos/sin (..., dim//2) broadcastable (rotate-half pairs)."""
     x1, x2 = jnp.split(x.astype(f32), 2, axis=-1)
@@ -91,6 +125,11 @@ def apply_rope(
         sin = jnp.concatenate(sin_parts, -1)[:, :, None, :]
         return _rotate(x, cos, sin)
     pos = positions[0]  # (B, S)
+    if cfg.rope_type == "yarn":
+        ang = pos.astype(f32)[..., None] * jnp.asarray(yarn_inv_freq(cfg, hd))
+        m = (yarn_mscale(cfg.rope_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim))
+        return _rotate(x, (jnp.cos(ang) * m)[:, :, None, :], (jnp.sin(ang) * m)[:, :, None, :])
     if cfg.rope_type == "partial" and cfg.rope_fraction < 1.0:
         rot = int(hd * cfg.rope_fraction)
         rot -= rot % 2
@@ -132,7 +171,7 @@ def mlp(p: dict[str, jax.Array], x: jax.Array, ax: AxisCtx, *, reduce: bool = Tr
 def moe_defs(cfg: ModelConfig, plan: ShapePlan) -> dict[str, Any]:
     d, E, dff = plan.d, plan.E, plan.Dff_e
     defs: dict[str, Any] = {
-        "router": ParamDef((d, E), P(None, None), init="small"),
+        "router": ParamDef((d, cfg.router_width), P(None, None), init="small"),
         "wi": ParamDef((E, d, dff), P("model", None, None)),
         "wg": ParamDef((E, d, dff), P("model", None, None)),
         "wo": ParamDef((E, dff, d), P("model", None, None)),
@@ -142,69 +181,93 @@ def moe_defs(cfg: ModelConfig, plan: ShapePlan) -> dict[str, Any]:
     return defs
 
 
+def balance_loss(cfg: ModelConfig, probs: jax.Array, top_i: jax.Array, batch: int) -> jax.Array:
+    """sum_e f_e * P_e, P_e the mean router probability of expert e and f_e
+    its count of top-k picks over the tokens, times E: per sequence with
+    DeepSeek's 1/k (f_e = E / (k S) count_e) and averaged over the sequences
+    where ``seq_aux``, else over the whole batch (Switch: f_e = E count_e / T)."""
+    E, k = probs.shape[-1], top_i.shape[-1]
+    groups = batch if cfg.seq_aux else 1
+    counts = jnp.sum(jax.nn.one_hot(top_i, E, dtype=f32), axis=1).reshape(groups, -1, E)
+    f = jnp.mean(counts, axis=1) * (E / k if cfg.seq_aux else E)
+    return jnp.mean(jnp.sum(f * jnp.mean(probs.reshape(groups, -1, E), axis=1), axis=-1))
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
+    """x (R, a) whose first rows lie in consecutive groups of ``sizes`` (G,)
+    rows, w (G, a, b) -> (R, b): each group's rows times its matrix, zero
+    for the rows past the groups.  On a TPU the grouped-matmul kernel
+    (``kernels/ops.py``), whose work follows the grouped rows, not R;
+    elsewhere ``jax.lax.ragged_dot``.  (On a TPU v5e ``ragged_dot`` leaves
+    the rows past the groups unset in the rows' gradient: PERF.md.)"""
+    if jax.default_backend() == "tpu":
+        return ops.grouped_matmul(x, w, sizes)
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
 def moe_ffn(
     cfg: ModelConfig,
     p: dict[str, Any],
     x: jax.Array,
     ax: AxisCtx,
-    *,
-    capacity_factor: float | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Dropping-style top-k MoE with expert parallelism.
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Dropless top-k MoE over the experts held here, expert-parallel over
+    the model axis.
 
-    Tokens are replicated over the model axis; each shard runs only its
-    local experts (capacity-buffered scatter/gather) and the outputs are
-    combined with a single ``psum`` (merged with the shared-expert
-    row-parallel reduction).  Returns (out, aux_loss).
-    """
+    The router scores each token over all ``cfg.router_width`` experts (f32
+    logits, softmax) and picks the greedy top-k; a pick's gate is its score,
+    renormalized over the k where ``norm_topk_prob`` (DeepSeek-V2-Lite's
+    ``routed_scaling_factor`` is 1, so there is none).  Tokens are
+    replicated over the model axis and each shard holds experts
+    [lo, lo + E_l) of the router's: the (token, pick) rows routed to them are
+    sorted by expert and run through grouped matmuls whose work follows
+    those rows.  No capacity: no row is dropped,
+    whatever the routing.  Experts the router has and no shard holds (they
+    would lie on other chips) add nothing here.  The shared experts are
+    added once (row-parallel) and one ``psum`` combines the shards.
+
+    Returns (out, stats): ``aux``, the balance loss without its coefficient,
+    and the counters ``moe_routed_rows`` (rows computed here) and
+    ``moe_max_expert_rows`` (those of the busiest expert held here)."""
     B, S, d = x.shape
     T = B * S
     k = cfg.experts_per_token
-    E = cfg.n_experts
     E_l = p["wi"].shape[0]
-    n_shards = E // E_l
     xt = x.reshape(T, d)
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            logits = jnp.einsum("td,de->te", xt.astype(f32), p["router"].astype(f32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_i = jax.lax.top_k(probs, k)  # (T, k)
+            if cfg.norm_topk_prob:
+                top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+            aux = balance_loss(cfg, probs, top_i, B)
 
-    logits = jnp.einsum("td,de->te", xt.astype(f32), p["router"].astype(f32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)  # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        with jax.named_scope("dispatch"):
+            local = top_i.reshape(-1) - jax.lax.axis_index(ax.model) * E_l  # (T*k,)
+            held = (local >= 0) & (local < E_l)
+            group = jnp.where(held, local, E_l)  # rows held elsewhere sort last
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.sum(group[:, None] == jnp.arange(E_l), axis=0, dtype=jnp.int32)
+            rows = jnp.take(xt, order // k, axis=0)
 
-    # Load-balance auxiliary loss (Switch-style): E * sum_e f_e * P_e.
-    f_e = jnp.mean(jnp.sum(jax.nn.one_hot(top_i, E, dtype=f32), axis=1), axis=0)
-    P_e = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(f_e * P_e)
+        with jax.named_scope("experts"):
+            h = grouped_matmul(rows, p["wi"], sizes)
+            g = grouped_matmul(rows, p["wg"], sizes)
+            out = grouped_matmul(jax.nn.silu(g) * h, p["wo"], sizes)
 
-    # --- local-expert dispatch ------------------------------------------------
-    shard = jax.lax.axis_index(ax.model) % n_shards
-    lo = shard * E_l
-    flat_e = top_i.reshape(-1)  # (T*k,)
-    flat_w = top_p.reshape(-1)
-    local = (flat_e >= lo) & (flat_e < lo + E_l)
-    le = jnp.where(local, flat_e - lo, 0)
-    cf = cfg.moe_capacity_factor if capacity_factor is None else capacity_factor
-    C = max(1, int(cf * T * k / E))
-    onehot = jax.nn.one_hot(le, E_l, dtype=jnp.int32) * local[:, None].astype(jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) - onehot  # position within expert
-    slot_in_e = jnp.sum(pos * onehot, axis=-1)
-    keep = local & (slot_in_e < C)
-    slot = jnp.where(keep, le * C + slot_in_e, E_l * C)  # dummy tail row
+        with jax.named_scope("combine"):
+            unsort = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+            gate = jnp.where(held, top_p.reshape(-1), 0.0).astype(out.dtype)
+            y = (jnp.take(out, unsort, axis=0) * gate[:, None]).reshape(T, k, d).sum(1)
 
-    tok_idx = jnp.arange(T * k) // k
-    buf = jnp.zeros((E_l * C + 1, d), x.dtype).at[slot].set(xt[tok_idx] * keep[:, None].astype(x.dtype))
-    eb = buf[: E_l * C].reshape(E_l, C, d)
-    h = jnp.einsum("ecd,edf->ecf", eb, p["wi"])
-    g = jnp.einsum("ecd,edf->ecf", eb, p["wg"])
-    h = jax.nn.silu(g) * h
-    eo = jnp.einsum("ecf,efd->ecd", h, p["wo"]).reshape(E_l * C, d)
-    eo = jnp.concatenate([eo, jnp.zeros((1, d), x.dtype)], 0)
-    y = eo[slot] * (flat_w * keep.astype(f32)).astype(x.dtype)[:, None]
-    y = y.reshape(T, k, d).sum(1)
-
-    if "shared" in p:
-        y = y + mlp(p["shared"], x, ax, reduce=False).reshape(T, d)
-    y = psum(y, ax.model)  # combine expert shards (+ shared row-parallel)
-    return y.reshape(B, S, d), aux
+        if "shared" in p:
+            with jax.named_scope("shared"):
+                y = y + mlp(p["shared"], x, ax, reduce=False).reshape(T, d)
+        y = psum(y, ax.model)  # combine expert shards (+ shared row-parallel)
+    stats = {"aux": aux, "moe_routed_rows": jnp.sum(sizes).astype(f32),
+             "moe_max_expert_rows": jnp.max(sizes).astype(f32)}
+    return y.reshape(B, S, d), stats
 
 
 # ---------------------------------------------------------------------------
@@ -300,6 +363,7 @@ def sdpa_chunked(
     q_offset: int | jax.Array = 0,  # position of query row 0; keys sit at 0..Sk-1
     kv_map: np.ndarray | jax.Array | None = None,  # (H,) kv head of each q head
     q_chunk: int = 1024,
+    scale: float | None = None,  # of the scores; None: hd^-0.5
 ) -> jax.Array:
     """Exact attention under the name scope ``attention``.
 
@@ -321,19 +385,19 @@ def sdpa_chunked(
             v = jnp.take(v, kv_map, axis=2)
         if blocks:
             return ops.flash_attention(q, k, v, q_offset, window=window, block_q=blocks[0],
-                                       block_k=blocks[1])
+                                       block_k=blocks[1], scale=scale)
         return _sdpa_jnp(q, k, v, q_pos=q_offset + jnp.arange(Sq),
                          k_pos=jnp.arange(k.shape[1]), window=window, causal=causal,
-                         q_chunk=q_chunk)
+                         q_chunk=q_chunk, scale=scale)
 
 
-def _sdpa_jnp(q, k, v, *, q_pos, k_pos, window, causal, q_chunk):
+def _sdpa_jnp(q, k, v, *, q_pos, k_pos, window, causal, q_chunk, scale=None):
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     KV = k.shape[2]
     hd_v = v.shape[-1]
     group = H // KV
-    scale = hd**-0.5
+    scale = hd**-0.5 if scale is None else scale
     qg = q.reshape(B, Sq, KV, group, hd)
 
     n_chunks = max(1, Sq // q_chunk)
@@ -422,7 +486,10 @@ def attention(
 
 
 def _mla_attention(cfg, p, x, ax, *, positions, window):
-    """Multi-head Latent Attention (training path, decompressed K/V)."""
+    """Multi-head Latent Attention (training path, decompressed K/V).  The
+    q/k head dim (qk_nope + qk_rope, 192 for DeepSeek-V2) is zero-padded to
+    the lanes' multiple the fused kernel takes: zero columns add nothing to
+    q.k, and the scale stays that of the unpadded dim."""
     B, S, _ = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope = q[..., : cfg.qk_nope_dim]
@@ -435,7 +502,10 @@ def _mla_attention(cfg, p, x, ax, *, positions, window):
     H_l = q.shape[2]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H_l, cfg.qk_rope_dim))], -1)
     qq = jnp.concatenate([q_nope, q_rope], -1)
-    out = sdpa_chunked(qq, k, v, window=window, causal=True)
+    qk = qq.shape[-1]
+    pad = [(0, 0)] * 3 + [(0, -qk % flash_attention.LANES)]
+    out = sdpa_chunked(jnp.pad(qq, pad), jnp.pad(k, pad), v, window=window, causal=True,
+                       scale=softmax_scale(cfg, qk))
     i = jax.lax.axis_index(ax.model)
     gheads = i * H_l + jnp.arange(H_l)
     out = out * (gheads < cfg.n_heads)[None, None, :, None].astype(out.dtype)
@@ -621,7 +691,7 @@ def _mla_decode(cfg, p, x, cache, ax, *, pos, window, seq_axes):
     H_pad = q_lat.shape[2]
     q_lat = q_lat[:, :, : cfg.n_heads]
     q_rope = q_rope[:, :, : cfg.n_heads]
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    scale = softmax_scale(cfg, cfg.qk_nope_dim + cfg.qk_rope_dim)
     s = jnp.einsum("bhc,btc->bht", q_lat[:, 0].astype(f32), cache["lat"].astype(f32))
     s = s + jnp.einsum("bhr,btr->bht", q_rope[:, 0].astype(f32), cache["rope"].astype(f32))
     s = s * scale

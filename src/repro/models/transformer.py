@@ -154,9 +154,10 @@ def _run_block(
     collect_cache: bool,
     causal: bool = True,
     max_seq: int = 0,  # decode-cache capacity (collect_cache only)
-) -> tuple[jax.Array, Any, jax.Array]:
-    """Returns (x, cache_or_state, aux_loss)."""
-    aux = jnp.zeros((), f32)
+) -> tuple[jax.Array, Any, dict[str, jax.Array]]:
+    """Returns (x, cache_or_state, stats): the MoE layer's balance loss and
+    counters (:func:`layer_stats`), zero for other layers."""
+    stats = layer_stats(cfg)
     cache: Any = ()
     if cfg.family == "ssm":
         h, tm_state = RW.rwkv_block(cfg, p, L.rmsnorm(p["ln1"], x), ax)
@@ -165,7 +166,7 @@ def _run_block(
         x = x + h
         if collect_cache:
             cache = {"tm": tm_state, "cm_last": cm_last}
-        return x, cache, aux
+        return x, cache, stats
 
     window = cfg.layer_window(attn_type, seq_len)
     h_in = L.rmsnorm(p["ln1"], x)
@@ -184,7 +185,7 @@ def _run_block(
         x = x + xa
     h2 = L.rmsnorm(p["ln2"], x)
     if "moe" in p:
-        ff, aux = L.moe_ffn(cfg, p["moe"], h2, ax)
+        ff, stats = L.moe_ffn(cfg, p["moe"], h2, ax)
     else:
         ff = L.mlp(p["mlp"], h2, ax)
     x = x + ff
@@ -192,7 +193,24 @@ def _run_block(
         cache = {"attn": _build_cache_from_prefill(cfg, p, h_in, positions, attn_type, ax, max_seq or seq_len)}
         if ssm_state is not None:
             cache["ssm"] = ssm_state
-    return x, cache, aux
+    return x, cache, stats
+
+
+def layer_stats(cfg: ModelConfig) -> dict[str, jax.Array]:
+    """Zeros for what a layer reports: the balance loss ``aux``, and in a MoE
+    model the rows computed by its held experts and by the busiest of them
+    (device counters, summed over the layers into the step's metrics)."""
+    names = ("aux", "moe_routed_rows", "moe_max_expert_rows") if cfg.moe else ("aux",)
+    return {n: jnp.zeros((), f32) for n in names}
+
+
+def metric_names(cfg: ModelConfig) -> tuple[str, ...]:
+    """The keys of :func:`forward_loss`'s metrics."""
+    return ("ce", *layer_stats(cfg))
+
+
+def _add(a: dict, b: dict) -> dict:
+    return jax.tree.map(jnp.add, a, b)
 
 
 def _build_cache_from_prefill(cfg, p, h_in, positions, attn_type, ax, max_seq):
@@ -305,17 +323,17 @@ def forward_loss(
     positions = make_positions(cfg, B, S)
     enc_out = _encode(cfg, params, batch, ax) if cfg.is_encoder_decoder else None
     pat = cfg.attn_pattern
-    aux_total = jnp.zeros((), f32)
+    total = layer_stats(cfg)
 
     for p in params["prefix"]:
-        x, _, aux = _run_block(
+        x, _, st = _run_block(
             cfg, p, x, ax, attn_type=pat[0], seq_len=S, positions=positions,
             enc_out=enc_out, collect_cache=False,
         )
-        aux_total += aux
+        total = _add(total, st)
 
     def super_block(x, pgroup):
-        aux = jnp.zeros((), f32)
+        stats = layer_stats(cfg)
         for i, attn_type in enumerate(pat):
             blk = functools.partial(
                 _run_block, cfg, pgroup[str(i)], ax=ax, attn_type=attn_type,
@@ -328,19 +346,19 @@ def forward_loss(
                     if cfg.remat == "dots_saveable"
                     else None,
                 )
-            x, _, a = blk(x)
-            aux += a
-        return x, aux
+            x, _, st = blk(x)
+            stats = _add(stats, st)
+        return x, stats
 
     repeats = (cfg.n_layers - cfg.first_dense_layers) // len(pat)
     if cfg.scan_layers:
         with comms.loop(repeats):
-            x, auxs = jax.lax.scan(super_block, x, params["blocks"])
-        aux_total += jnp.sum(auxs)
+            x, stats = jax.lax.scan(super_block, x, params["blocks"])
+        total = _add(total, jax.tree.map(jnp.sum, stats))
     else:
         for pgroup in params["blocks"]:
-            x, a = super_block(x, pgroup)
-            aux_total += a
+            x, st = super_block(x, pgroup)
+            total = _add(total, st)
 
     x = L.rmsnorm(params["ln_f"], x)
     if cfg.modality == "vision":  # only text positions carry labels
@@ -350,8 +368,8 @@ def forward_loss(
     # per-shard gradient is already complete, so scale by 1/msize so that the
     # replicated-grad psum fix-up (train.steps._fix_model_grads) is exact.
     msize = comms.axis_size(ax.model)
-    loss = ce + cfg.router_aux_coef * aux_total / msize
-    return loss, {"ce": ce, "aux": aux_total}
+    loss = ce + cfg.router_aux_coef * total["aux"] / msize
+    return loss, {"ce": ce, **total}
 
 
 # ---------------------------------------------------------------------------

@@ -584,6 +584,9 @@ def _compile_bundle(
     knob_pspecs = jax.tree.map(lambda _: P(), knobs0)
 
     # ---- train steps -----------------------------------------------------------
+    # the step's metrics: the loss and what forward_loss reports beside it
+    metric_specs = {"loss": P(), **{k: P() for k in T.metric_names(cfg)}}
+
     def make_step(do_aggregate: bool):
         def _grads(params, batch):
             def loss_fn(p):
@@ -758,11 +761,7 @@ def _compile_bundle(
                     grads = global_clip(grads, knobs["clip_norm"])
                 new_params, opt_state = opt.update(grads, state["opt"], params, lr)
             loss = comms.pmean(loss, ax.data)
-            out = {
-                "loss": loss,
-                "ce": comms.pmean(metrics["ce"], ax.data),
-                "aux": comms.pmean(metrics["aux"], ax.data),
-            }
+            out = {"loss": loss, **{k: comms.pmean(v, ax.data) for k, v in metrics.items()}}
             return (
                 {"params": new_params, "opt": opt_state, "comm": cstate,
                  "step": state["step"] + 1},
@@ -772,7 +771,7 @@ def _compile_bundle(
         raw = jax.shard_map(
             _step, mesh=mesh,
             in_specs=(state_specs, batch_pspecs, P(), knob_pspecs),
-            out_specs=(state_specs, {"loss": P(), "ce": P(), "aux": P()}),
+            out_specs=(state_specs, metric_specs),
             check_vma=False,
         )
         return raw, jax.jit(raw, donate_argnums=(0,))
@@ -967,15 +966,14 @@ def _compile_bundle(
             new_params = jax.tree.unflatten(treedef, new_leaves)
             cstate["step"] = cstate["step"] + 1
             out = {"loss": comms.pmean(loss, ax.data),
-                   "ce": comms.pmean(metrics["ce"], ax.data),
-                   "aux": comms.pmean(metrics["aux"], ax.data)}
+                   **{k: comms.pmean(v, ax.data) for k, v in metrics.items()}}
             return ({"params": new_params, "opt": opt_state, "comm": cstate,
                      "step": state["step"] + 1}, out)
 
         raw_gossip = jax.shard_map(
             _gstep, mesh=mesh,
             in_specs=(state_specs, batch_pspecs, P(), knob_pspecs),
-            out_specs=(state_specs, {"loss": P(), "ce": P(), "aux": P()}),
+            out_specs=(state_specs, metric_specs),
             check_vma=False,
         )
         gossip_step = jax.jit(raw_gossip, donate_argnums=(0,))

@@ -60,6 +60,31 @@ def test_kernel_matches_jnp_path(case):
         assert _rel(a, b) < 1e-2, (name, _rel(a, b))
 
 
+def test_kernel_scale_with_padded_head_dim():
+    """MLA's q/k head dim 192 zero-padded to 256 with the scale of 192 (and
+    YaRN's temperature), v at 128: the kernel against the jnp path at the
+    unpadded 192, output and the gradients of the unpadded q, k, v."""
+    key = jax.random.key(192)
+    q, k = (jax.random.normal(jax.random.fold_in(key, i), (1, 256, 2, 192), bf16) for i in (0, 1))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 256, 2, 128), bf16)
+    do = jax.random.normal(jax.random.fold_in(key, 3), (1, 256, 2, 128), bf16)
+    scale = 192**-0.5 * 1.5896
+    pad = [(0, 0)] * 3 + [(0, 64)]
+
+    def kernel(q, k, v):
+        return ops.flash_attention(jnp.pad(q, pad), jnp.pad(k, pad), v, 0, window=256,
+                                   block_q=BLOCK, block_k=BLOCK, scale=scale)
+
+    def jnp_path(q, k, v):
+        return L.sdpa_chunked(q, k, v, window=256, causal=True, q_chunk=128, scale=scale)
+
+    o, vjp = jax.vjp(kernel, q, k, v)
+    ro, rvjp = jax.vjp(jnp_path, q, k, v)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), (o, *vjp(do)), (ro, *rvjp(do))):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _rel(a, b) < 1e-2, (name, _rel(a, b))
+
+
 GLM = ((1, 4096, 32, 128), (1, 4096, 2, 128), (1, 4096, 2, 128))
 QWEN3 = ((1, 2048, 16, 128), (1, 2048, 8, 128), (1, 2048, 8, 128))
 MLA = ((1, 4096, 16, 192), (1, 4096, 16, 192), (1, 4096, 16, 128))
@@ -71,6 +96,8 @@ DISPATCH = {
     "seqpar shard": ("tpu", ((1, 512, 32, 128), (1, 4096, 2, 128), (1, 4096, 2, 128)), True, True),
     "cpu": ("cpu", GLM, True, False),
     "mla qk 192": ("tpu", MLA, True, False),
+    "mla qk padded to 256": ("tpu", ((4, 4096, 16, 256), (4, 4096, 16, 256), (4, 4096, 16, 128)),
+                             True, True),
     "cross attention": ("tpu", ((1, 512, 16, 128), (1, 1536, 16, 128), (1, 1536, 16, 128)),
                         False, False),
     "seq not a multiple of 128": ("tpu", ((1, 1000, 4, 128),) + ((1, 1000, 2, 128),) * 2,

@@ -6,7 +6,8 @@ not aligned to the native tiles, casts and reductions Mosaic does not
 lower, shape casts it cannot lay out.  These tests compile each kernel with
 ``interpret=False`` for a described ``v5e:2x2`` topology (no chip needed)
 at a 4M-element bucket, W=4 gathered workers for the wire kernels, and the
-attention kernels at the benchmark cells' shapes.
+attention kernels at the benchmark cells' shapes (DeepSeek-V2-Lite's MLA
+with its q/k head dim zero-padded to 256).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -89,6 +90,17 @@ def _cases():
             cases[f"flash_{k}_{cell}"] = (
                 functools.partial(getattr(flash_attention, f"flash_bwd_{k}"), **kw),
                 fwd + [q, row, row])
+    # DeepSeek-V2-Lite's MLA: 4 rows of 4096 tokens, 16 heads, q/k zero-padded
+    # from 192 to 256, v 128, and YaRN's softmax scale
+    q, v = ((4, 4096, 16, 256), "bfloat16"), ((4, 4096, 16, 128), "bfloat16")
+    row = ((4, 16, 1, 4096), "float32")
+    kw = dict(window=4096, block_q=1024, block_k=1024, scale=192**-0.5 * 1.5896)
+    cases["flash_fwd_mla"] = (functools.partial(flash_attention.flash_fwd, **kw),
+                              [q, q, v, ((1,), "int32")])
+    for k in ("dq", "dkv"):
+        bwd = getattr(flash_attention, f"flash_bwd_{k}")
+        cases[f"flash_{k}_mla"] = (functools.partial(bwd, **kw),
+                                   [q, q, v, ((1,), "int32"), v, row, row])
     return cases
 
 
@@ -101,7 +113,7 @@ KERNELS = {"qsgd_2d": "qsgd_quantize", "qsgd_ef_2d": "qsgd_ef_fused",
            "int8_acc_3d": "int8_weighted_sum",
            "threshold_2d": "threshold_sparsify", "wkv6_chunked": "wkv6",
            **{f"flash_{k}_{cell}": f"flash_attention_{k}"
-              for k in ("fwd", "dq", "dkv") for cell in ("glm4", "qwen3")}}
+              for k in ("fwd", "dq", "dkv") for cell in ("glm4", "qwen3", "mla")}}
 
 
 @pytest.mark.parametrize("name", KERNELS)
