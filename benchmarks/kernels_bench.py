@@ -110,11 +110,11 @@ def run() -> list[Row]:
         jax.random.normal(jax.random.fold_in(key, 40 + w), (N,)), u,
         levels=16)[0] for w in range(W)])
     dec_w = jnp.linspace(0.01, 0.02, W)
-    fused_i8 = jax.jit(lambda c, wt: ops.int8_weighted_sum(c, wt))
+    fused_i8 = jax.jit(lambda c, wt: ops.int8_weighted_sum(c, wt, W, n=N))
     composed_i8 = jax.jit(
-        lambda c, wt: (c.astype(jnp.float32) * wt[:, None]).sum(axis=0))
+        lambda c, wt: (c.astype(jnp.float32) * wt[:, None]).sum(axis=0) / W)
     us_f = time_fn(fused_i8, codes, dec_w)
-    us_c = time_fn(composed_i8, codes, dec_w)
+    us_c = time_fn(composed_i8, ops.int8_unpack(codes, N), dec_w)
     tf, tc = N * (W + 4), N * (W + 8 * W + 4)
     rows.append(Row("kernels/int8_acc_fused", us_f, _traffic("int8", tf, tc)))
     rows.append(Row("kernels/int8_acc_composed", us_c,
@@ -127,7 +127,8 @@ def run() -> list[Row]:
     fused_ef = jax.jit(lambda g, ee, uu: ops.qsgd_ef_fused(g, ee, uu, levels=16))
     def _composed_ef(g, ee, uu):
         a = ee * 1.0 + g                       # pass 1: accumulate EF
-        codes, norm = ops.qsgd_quantize(a, uu, levels=16)   # pass 2
+        words, norm = ops.qsgd_quantize(a, uu, levels=16)   # pass 2
+        codes = ops.int8_unpack(words, a.size)
         e_new = a - ops.qsgd_dequantize(codes, norm, levels=16)  # pass 3
         return codes, norm, e_new
     composed_ef = jax.jit(_composed_ef)

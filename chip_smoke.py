@@ -118,16 +118,16 @@ def kernels_phase() -> list[str]:
               "seconds": time.perf_counter() - t0})
 
     def qsgd():
-        codes, norm = ops.qsgd_quantize(x, u, levels=16)
+        words, norm = ops.qsgd_quantize(x, u, levels=16)
         np.testing.assert_array_equal(
-            a(codes), a(ref.qsgd_ref(x, u, jnp.linalg.norm(x), 16)))
+            a(ops.int8_unpack(words, N)), a(ref.qsgd_ref(x, u, jnp.linalg.norm(x), 16)))
         np.testing.assert_allclose(float(norm[0]), float(jnp.linalg.norm(x)),
                                    rtol=1e-6)
 
     def qsgd_ef():
-        codes, _, enew = ops.qsgd_ef_fused(x, e, u, levels=16, decay=0.9)
+        words, _, enew = ops.qsgd_ef_fused(x, e, u, levels=16, decay=0.9)
         cr, er = ref.qsgd_ef_ref(x, e, u, jnp.linalg.norm(e * 0.9 + x), 16, 0.9)
-        np.testing.assert_array_equal(a(codes), a(cr))
+        np.testing.assert_array_equal(a(ops.int8_unpack(words, N)), a(cr))
         np.testing.assert_allclose(a(enew), a(er), rtol=1e-5, atol=1e-7)
 
     def terngrad():
@@ -159,9 +159,10 @@ def kernels_phase() -> list[str]:
 
     def int8_sum():
         weights = jnp.linspace(0.01, 0.05, W)
+        words = jnp.stack([ops.int8_pack(codes8[w]) for w in range(W)])
         np.testing.assert_allclose(
-            a(ops.int8_weighted_sum(codes8, weights)),
-            a(ref.weighted_sum_ref(codes8.astype(jnp.float32), weights)),
+            a(ops.int8_weighted_sum(words, weights, 3.0, n=N)),
+            a(ref.weighted_sum_ref(codes8.astype(jnp.float32), weights) / 3.0),
             rtol=1e-6, atol=1e-5)
 
     for name, fn in [("qsgd", qsgd), ("qsgd_ef", qsgd_ef),

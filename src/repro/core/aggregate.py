@@ -221,24 +221,28 @@ def _gather_alive(alive: jax.Array | None, axes) -> jax.Array | None:
 
 def _int8_code_reduce(compressor, c: Compressed, p, axes, alive_g, denom,
                       integ=None):
-    """int8_acc wire reduction: all-gather the int8 codes AT WIRE WIDTH (the
-    (W, n) f32 decode is never materialized) and fold each worker's decode
-    scale norm_w/levels_w — and its churn mask — into the per-worker weight
-    of one fused widening-accumulate kernel.
+    """int8_acc wire reduction: all-gather the int8 codes as the quantize
+    kernel's 32-bit words (four codes a word, n bytes a worker), fold each
+    worker's decode scale norm_w/levels_w — and its churn mask — into the
+    per-worker weight, and let one fused kernel sign-extend, accumulate and
+    divide by ``denom``.  Neither the (W, n) codes nor their f32 decode are
+    ever materialized, and no pass repacks the words around the gather.
 
     ``integ`` (gradient-integrity context, see :mod:`repro.core.integrity`):
-    the shard's own payload is corrupted in-domain before the gather, every
+    the shard's own payload is corrupted in-domain before the gather (XOR
+    0x55 in every byte of a word is the int8 fault, code by code), every
     gathered row is validated (finite in-range norms/scales, codes within
-    the level bound), and an invalid row's weight + denominator share drop
-    to zero — a one-round quarantine.  Every select is an identity at
-    corruption rate 0."""
+    the level bound, read off words unpacked for the check), and an invalid
+    row's weight + denominator share drop to zero — a one-round quarantine.
+    Every select is an identity at corruption rate 0."""
     from repro.kernels import ops
 
     payload = dict(c.payload)
     if integ is not None:
         payload = integrity.corrupt_payload(integ["kind"], payload,
                                             integ["flag"])
-    cg = comms.all_gather_compressed({"code": payload["code"]}, axes)["code"]
+    with comms.wire_format("int8"):
+        cg = comms.all_gather(payload["code"], axes, axis=0)
     ng = comms.all_gather(payload["norm"], axes, axis=0).reshape(-1)
     if "s" in payload:
         sg = comms.all_gather(payload["s"], axes, axis=0).reshape(-1)
@@ -250,14 +254,36 @@ def _int8_code_reduce(compressor, c: Compressed, p, axes, alive_g, denom,
             w = w * alive_g
         if integ is not None:
             valid_g = (integrity.scale_valid(ng, sg)
-                       * integrity.code_valid(cg, sg, per_row=True))
+                       * integrity.code_valid(ops.int8_unpack(cg, c.n), sg,
+                                              per_row=True))
             w = jnp.where(valid_g > 0, w, 0.0)
             denom = jnp.maximum(jnp.sum(alive_g * valid_g), 1.0)
             own_s = (payload["s"].reshape(()) if "s" in payload else sg)
             integ["valid_bucket"] = (
                 integrity.scale_valid(payload["norm"].reshape(()), own_s)
-                * integrity.code_valid(payload["code"], own_s))
-        return ops.int8_weighted_sum(cg, w) / denom
+                * integrity.code_valid(ops.int8_unpack(payload["code"], c.n),
+                                       own_s))
+        return ops.int8_weighted_sum(cg, w, denom, n=c.n)
+
+
+def _int8_wire(compressor, key, a, p):
+    """(wire payload with the int8 codes as 32-bit words, self C(a)).  A
+    compressor whose kernel writes the words hands them over as they are;
+    one that makes int8 codes in jnp has them packed."""
+    from repro.kernels import ops
+
+    wire = getattr(compressor, "compress_wire_p", None)
+    with jax.named_scope("encode"):
+        if wire is None:
+            c = compress_p(compressor, key, a, p)
+            cw = Compressed({**c.payload, "code": ops.int8_pack(c.payload["code"])}, c.n)
+        else:
+            cw = wire(key, a, p)
+            c = Compressed({**cw.payload, "code": ops.int8_unpack(cw.payload["code"], cw.n)},
+                           cw.n)
+    with jax.named_scope("decode"):
+        self_hat = decompress_p(compressor, c, p)
+    return cw, self_hat
 
 
 def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
@@ -299,6 +325,11 @@ def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
                 return jnp.where(votes >= 0, 1.0, -1.0).astype(f32), self_hat
             return votes / denom, self_hat  # mean of ±1 votes
 
+    if wr == "int8_acc":
+        c, self_hat = _int8_wire(compressor, key, a, p)
+        return _int8_code_reduce(compressor, c, p, axes, alive_g, denom,
+                                 integ=integ), self_hat
+
     with jax.named_scope("encode"):
         c = compress_p(compressor, key, a, p)
     with jax.named_scope("decode"):
@@ -326,9 +357,6 @@ def _compressed_reduce(compressor, key, a, axes, p, alive_g, denom,
                     integrity.packed2_valid(packed)
                     * integrity.scale_valid(scale.reshape(())))
             return ops.tern_acc(pg, w, n=c.n) / denom, self_hat
-    if wr == "int8_acc":
-        return _int8_code_reduce(compressor, c, p, axes, alive_g, denom,
-                                 integ=integ), self_hat
     raise ValueError(f"unknown wire_reduce {wr!r} on {compressor!r}")
 
 

@@ -295,15 +295,6 @@ def psum_scatter(x, axis_name, *, scatter_dimension: int = 0, tiled: bool = True
     )
 
 
-def all_gather_compressed(payload: dict, axes, *, axis: int = 0) -> dict:
-    """All-gather a compressed wire payload dict (codes + per-tensor scales)
-    leaf by leaf.  Each leaf is recorded at its ACTUAL dtype bytes — an int8
-    code array logs N bytes, not the 4N of its dense decode — so `CommLog`
-    accounting reflects what the wire carries.  Use ``wire_format(...)``
-    around the call when the dtype under-describes the packing."""
-    return {k: all_gather(v, axes, axis=axis) for k, v in payload.items()}
-
-
 def widening_psum(x, axes):
     """All-reduce with a narrow wire dtype but f32 accumulation: gather the
     narrow payload (recorded at its actual byte width) and sum widened, so

@@ -49,10 +49,18 @@ class QSGDKernel:
     def runtime_params(self) -> dict:
         return self._check()
 
-    def compress_p(self, key, x, p) -> Compressed:
+    def compress_wire_p(self, key, x, p) -> Compressed:
+        """The wire payload of the compressed-domain aggregation: the codes
+        as the kernel's 32-bit words (``ops.qsgd_quantize``), which the
+        all-gather and ``ops.int8_weighted_sum`` take as they are."""
         u = jax.random.uniform(key, x.shape)
-        codes, norm = ops.qsgd_quantize(x, u, levels=p.get("levels", self.levels))
-        return Compressed({"code": codes, "norm": norm}, x.size)
+        words, norm = ops.qsgd_quantize(x, u, levels=(p or {}).get("levels", self.levels))
+        return Compressed({"code": words, "norm": norm}, x.size)
+
+    def compress_p(self, key, x, p) -> Compressed:
+        c = self.compress_wire_p(key, x, p)
+        return Compressed({"code": ops.int8_unpack(c.payload["code"], c.n),
+                           "norm": c.payload["norm"]}, c.n)
 
     def decompress_p(self, c, p) -> jax.Array:
         return ops.qsgd_dequantize(c.payload["code"], c.payload["norm"],
@@ -77,7 +85,8 @@ class QSGDKernel:
         with levels traced."""
         lv = p.get("levels", self.levels)
         u = jax.random.uniform(key, g.shape)
-        codes, norm, e_new = ops.qsgd_ef_fused(g, e, u, levels=lv)
+        words, norm, e_new = ops.qsgd_ef_fused(g, e, u, levels=lv)
+        codes = ops.int8_unpack(words, g.size)
         return ops.qsgd_dequantize(codes, norm, levels=lv), e_new, self._bits(g.size, p)
 
     def compress_decompress_ef(self, key, g, e):
@@ -88,14 +97,15 @@ class QSGDKernel:
     def compress_ef_p(self, key, g, e, p, decay):
         """Fused EF+quantize that returns the WIRE payload (for the
         compressed-domain aggregation path): one Pallas pass yields the int8
-        codes and the residual update, with levels and decay traced.  Uses
+        codes, as ``compress_wire_p``'s words, and the residual update, with
+        levels and decay traced.  Uses
         the same uniform draw as ``compress_p`` after ``pre_compress``'s
         a = e*decay + g, so the codes match the composed path bit for bit
         (the residual differs by one reciprocal rounding)."""
         lv = (p or {}).get("levels", self.levels)
         u = jax.random.uniform(key, g.shape)
-        codes, norm, e_new = ops.qsgd_ef_fused(g, e, u, levels=lv, decay=decay)
-        return Compressed({"code": codes, "norm": norm}, g.size), e_new
+        words, norm, e_new = ops.qsgd_ef_fused(g, e, u, levels=lv, decay=decay)
+        return Compressed({"code": words, "norm": norm}, g.size), e_new
 
     def wire_bits(self, n) -> float:
         import math

@@ -45,30 +45,52 @@ def _to2d(x: jax.Array) -> tuple[jax.Array, int]:
 
 @jax.jit
 def qsgd_quantize(x: jax.Array, u: jax.Array, *, levels=16) -> tuple[jax.Array, jax.Array]:
-    """Flat x, uniform noise u -> (codes int8 (n,), norm (1,) f32).
+    """Flat x, uniform noise u -> (code words, norm (1,) f32).  The words
+    are (rows/4, 128) int32, four int8 codes each in the layout of
+    :mod:`repro.kernels.qsgd`, for x padded to whole blocks (the pad codes
+    are 0): what the wire carries.  ``int8_unpack(words, n)`` gives the
+    (n,) codes.
 
     ``levels`` is TRACED (a value, not a jit specialization constant): cells
     that differ only in levels share this compiled program."""
     norm = jnp.maximum(jnp.linalg.norm(x.astype(f32)), 1e-30)
-    x2, n = _to2d(x.astype(f32))
+    x2, _ = _to2d(x.astype(f32))
     u2, _ = _to2d(u.astype(f32))
-    codes = _qsgd.qsgd_2d(x2, u2, (1.0 / norm).reshape(1, 1),
+    words = _qsgd.qsgd_2d(x2, u2, (1.0 / norm).reshape(1, 1),
                           jnp.asarray(levels, f32).reshape(1, 1),
                           interpret=_interpret())
-    return codes.reshape(-1)[:n], norm[None]
+    return words, norm[None]
+
+
+def int8_unpack(words: jax.Array, n: int) -> jax.Array:
+    """Code words (..., rows, 128) of qsgd_quantize / qsgd_ef_fused (a
+    gathered (W, rows, 128) too) -> the int8 codes (..., n) in element
+    order.  A jnp pass, for the paths that need the codes themselves."""
+    lead = words.shape[:-2]
+    w = words.reshape(*lead, -1, _qsgd.WORD_ROWS, _qsgd.LANES)
+    codes = jnp.stack([_qsgd.code_slot(w, j) for j in range(_qsgd.CODES)], axis=-3)
+    return codes.astype(jnp.int8).reshape(*lead, -1)[..., :n]
+
+
+def int8_pack(codes: jax.Array) -> jax.Array:
+    """(n,) int8 codes -> the code words of qsgd_quantize (inverse of
+    int8_unpack), for quantizers that make their codes in jnp."""
+    c, _ = _to2d(codes.astype(f32))
+    blocks = c.reshape(-1, _qsgd.BLOCK_ROWS, _qsgd.LANES)
+    return jax.vmap(_qsgd.pack_codes)(blocks).reshape(-1, _qsgd.LANES)
 
 
 @jax.jit
 def qsgd_dequantize(codes: jax.Array, norm: jax.Array, *, levels=16) -> jax.Array:
-    """Inverse of qsgd_quantize / the codes half of qsgd_ef_fused."""
+    """(n,) int8 codes (``int8_unpack``) and their norm -> the decoded (n,)."""
     return codes.astype(f32) / jnp.asarray(levels, f32) * norm[0]
 
 
 @jax.jit
 def qsgd_ef_fused(g: jax.Array, e: jax.Array, u: jax.Array, *, levels=16,
                   decay=1.0):
-    """Fused EF+quantize: returns (codes (n,) int8, norm (1,), e_new (n,)).
-    ``levels`` and ``decay`` are traced scalars."""
+    """Fused EF+quantize: returns (code words as qsgd_quantize's, norm (1,),
+    e_new (n,)).  ``levels`` and ``decay`` are traced scalars."""
     decay = jnp.asarray(decay, f32)
     a_norm = jnp.maximum(jnp.linalg.norm((e * decay + g).astype(f32)), 1e-30)
     g2, n = _to2d(g.astype(f32))
@@ -79,7 +101,7 @@ def qsgd_ef_fused(g: jax.Array, e: jax.Array, u: jax.Array, *, levels=16,
         jnp.asarray(levels, f32).reshape(1, 1), decay.reshape(1, 1),
         interpret=_interpret(),
     )
-    return codes.reshape(-1)[:n], a_norm[None], enew.reshape(-1)[:n]
+    return codes, a_norm[None], enew.reshape(-1)[:n]
 
 
 @jax.jit
@@ -164,17 +186,18 @@ def tern_acc(packed: jax.Array, weights: jax.Array, *, n: int) -> jax.Array:
     return out.reshape(-1)[:n]
 
 
-@jax.jit
-def int8_weighted_sum(codes: jax.Array, weights: jax.Array) -> jax.Array:
-    """Gathered int8 quantizer codes (W, n) + per-worker decode weights (W,)
-    (norm_w/levels x churn mask) -> sum_w weights[w]*codes[w] as (n,) f32.
-    The widening accumulate happens inside the kernel — the (W, n) f32
-    decode is never materialized."""
-    n_w, n = codes.shape
-    tile = _wire_k.INT8_ROWS * _wire_k.LANES
-    c3 = jnp.pad(codes, ((0, 0), (0, (-n) % tile))).reshape(n_w, -1,
-                                                           _wire_k.LANES)
-    out = _wire_k.int8_acc_3d(c3, _worker_weights(weights, n_w),
+@functools.partial(jax.jit, static_argnames=("n",))
+def int8_weighted_sum(packed: jax.Array, weights: jax.Array, denom, *,
+                      n: int) -> jax.Array:
+    """Gathered code words (W, rows, 128) of qsgd_quantize / qsgd_ef_fused
+    + per-worker decode weights (W,) (norm_w/levels x churn mask) + the
+    denominator -> (sum_w weights[w]*codes[w]) / denom as (n,) f32.  The
+    kernel reads the words as the wire left them, sign-extends each code in
+    VMEM and divides the finished sum: neither the (W, n) codes nor their
+    f32 decode are ever materialized."""
+    n_w = packed.shape[0]
+    out = _wire_k.int8_acc_3d(packed, _worker_weights(weights, n_w),
+                              jnp.asarray(denom, f32).reshape(1, 1),
                               interpret=_interpret())
     return out.reshape(-1)[:n]
 

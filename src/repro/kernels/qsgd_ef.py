@@ -8,6 +8,8 @@ gradient-sized tensors:
 = 5 reads + 3 writes of N floats.  Fused: read g, e, u; write code (1 byte)
 and e' — 3 reads + 1.25 writes.  ~2.4x less HBM traffic on the dominant
 non-matmul pass of a compressed training step (see EXPERIMENTS.md §Perf).
+The codes leave as the 32-bit wire words of :mod:`repro.kernels.qsgd`, so
+the wire takes either producer.
 """
 
 from __future__ import annotations
@@ -16,8 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_ROWS = 256
-LANES = 128
+from repro.kernels.qsgd import BLOCK_ROWS, CODES, LANES, WORD_ROWS, pack_codes
+
 f32 = jnp.float32
 
 
@@ -30,14 +32,15 @@ def _qsgd_ef_kernel(g_ref, e_ref, u_ref, inv_norm_ref, levels_ref, decay_ref,
     l = jnp.floor(y)
     l = l + (u_ref[...] < (y - l)).astype(f32)
     code = jnp.sign(a) * l
-    code_ref[...] = code.astype(jnp.int8)
+    code_ref[...] = pack_codes(code)
     deq = code / levels / jnp.maximum(inv, 1e-38)
     enew_ref[...] = a - deq
 
 
 def qsgd_ef_2d(g2, e2, u2, inv_norm, levels, decay, *, interpret: bool = False):
     """``levels`` and ``decay`` are (1,1) f32 traced scalars — the kernel no
-    longer specializes on them, so knob-varied cells share one program."""
+    longer specializes on them, so knob-varied cells share one program.
+    Returns ((rows / CODES, 128) int32 code words, (rows, 128) f32 e')."""
     rows = g2.shape[0]
     grid = (rows // BLOCK_ROWS,)
     blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
@@ -45,12 +48,12 @@ def qsgd_ef_2d(g2, e2, u2, inv_norm, levels, decay, *, interpret: bool = False):
     return pl.pallas_call(
         _qsgd_ef_kernel,
         out_shape=(
-            jax.ShapeDtypeStruct(g2.shape, jnp.int8),
+            jax.ShapeDtypeStruct((rows // CODES, LANES), jnp.int32),
             jax.ShapeDtypeStruct(g2.shape, f32),
         ),
         grid=grid,
         in_specs=[blk(), blk(), blk(), scalar(), scalar(), scalar()],
-        out_specs=(blk(), blk()),
+        out_specs=(pl.BlockSpec((WORD_ROWS, LANES), lambda i: (i, 0)), blk()),
         name="qsgd_ef_fused",
         interpret=interpret,
     )(g2, e2, u2, inv_norm, levels, decay)

@@ -101,6 +101,19 @@ def tern_unpack_ref(packed: jax.Array, n: int) -> jax.Array:
     return val.reshape(-1)[:n]
 
 
+def int8_pack_ref(codes: jax.Array) -> jax.Array:
+    """codes (n,) int8 -> uint32 words of 4 byte slots, the layout of the
+    qsgd kernels: padded with zero codes to whole (256, 128) blocks, whose
+    64 word rows each hold code rows w, 64 + w, 128 + w and 192 + w."""
+    c = jnp.pad(codes.reshape(-1), (0, (-codes.size) % (256 * LANES)))
+    return _pack_slots(_slot_view(c, 4, 0).astype(jnp.uint8), 8)
+
+
+def int8_unpack_ref(packed: jax.Array, n: int) -> jax.Array:
+    """uint32 words of ``int8_pack_ref`` -> the (n,) int8 codes."""
+    return _unpack_slots(packed, 4, 8).astype(jnp.uint8).astype(jnp.int8).reshape(-1)[:n]
+
+
 def weighted_sum_ref(vals: jax.Array, weights: jax.Array) -> jax.Array:
     """vals (W, n), weights (W,) -> sum_w weights[w]*vals[w] as (n,) f32."""
     return _sequential_sum(vals, weights)

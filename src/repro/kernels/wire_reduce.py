@@ -2,17 +2,19 @@
 
 The collective epilogue for compressed wire formats.  After an all-gather of
 *packed* payloads — 1-bit sign bitmaps (`sign_pack`), 2-bit ternary codes
-(`tern_pack_2d`), or raw int8 quantizer codes — these kernels decode each
-worker's payload and accumulate the per-worker weighted sum in f32 without
-ever materializing the (W, n) dense decode in HBM.  The worker weight input
-carries the whole per-worker epilogue: participation mask (churn `alive`),
-ternary scale, or qsgd `norm/levels`, so the kernels stay linear-algebra-free
-and the callers (``repro.core.aggregate``) keep the denominator logic.
+(`tern_pack_2d`), or 8-bit quantizer codes (`repro.kernels.qsgd`) — these
+kernels decode each worker's payload and accumulate the per-worker weighted
+sum in f32 without ever materializing the (W, n) dense decode in HBM.  The
+worker weight input carries the whole per-worker epilogue: participation
+mask (churn `alive`), ternary scale, or qsgd `norm/levels`, so the kernels
+stay linear-algebra-free and the callers (``repro.core.aggregate``) keep the
+denominator logic.
 
 Packed payloads are 32-bit words (the chip's bit arithmetic and reductions
-work on 32-bit lanes): 32 sign bits or 16 two-bit ternary slots per word, in
-the tile layout described in :mod:`repro.kernels.sign_pack`.  Workers are
-accumulated in index order, so the sums match a sequential reference.
+work on 32-bit lanes): 32 sign bits, 16 two-bit ternary slots or 4 int8
+codes per word, in the tile layout described in
+:mod:`repro.kernels.sign_pack`.  Workers are accumulated in index order, so
+the sums match a sequential reference.
 """
 
 from __future__ import annotations
@@ -21,10 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.sign_pack import BITS, LANES, block_rows, tiles_per_step
+from repro.kernels.qsgd import CODES, code_slot
+from repro.kernels.sign_pack import BITS, LANES, block_rows
 
 TERN_SLOTS = 16  # 2-bit ternary slots per 32-bit word
-INT8_ROWS = 32  # the (32, 128) native tile of 8-bit codes
 f32 = jnp.float32
 i32 = jnp.int32
 
@@ -47,7 +49,7 @@ def _vote_kernel(p_ref, w_ref, o_ref):
             lambda p: ((p >> j) & 1).astype(f32) * 2.0 - 1.0, p_ref, w_ref)
 
 
-def _acc_call(kernel, packed, weights, slots, interpret, name):
+def _acc_call(kernel, packed, weights, slots, interpret, name, *scalars):
     n_w, word_rows, _ = packed.shape
     r = block_rows(word_rows)
     return pl.pallas_call(
@@ -57,11 +59,11 @@ def _acc_call(kernel, packed, weights, slots, interpret, name):
         in_specs=[
             pl.BlockSpec((n_w, r, LANES), lambda i: (0, i, 0)),
             pl.BlockSpec((n_w, LANES), lambda i: (0, 0)),
-        ],
+        ] + [pl.BlockSpec((1, 1), lambda i: (0, 0)) for _ in scalars],
         out_specs=pl.BlockSpec((slots * r, LANES), lambda i: (i, 0)),
         name=name,
         interpret=interpret,
-    )(packed, weights)
+    )(packed, weights, *scalars)
 
 
 def sign_vote_3d(packed: jax.Array, weights: jax.Array, *,
@@ -122,26 +124,21 @@ def tern_acc_3d(packed: jax.Array, weights: jax.Array, *,
                      interpret, "tern_acc")
 
 
-def _int8_acc_kernel(c_ref, w_ref, o_ref):
-    # c (W, R, 128) int8 codes, w (W, 128) f32 -> (R, 128) f32 widening sum
-    o_ref[...] = _weighted(lambda c: c.astype(f32), c_ref, w_ref)
+def _int8_acc_kernel(p_ref, w_ref, d_ref, o_ref):
+    # p (W, R, 128) int32 words of 4 int8 codes, w (W, 128) f32, d (1, 1) f32
+    # -> (4R, 128) f32 widening sum over d
+    r = p_ref.shape[1]
+    for j in range(CODES):
+        o_ref[j * r:(j + 1) * r, :] = _weighted(
+            lambda p: code_slot(p, j).astype(f32), p_ref, w_ref) / d_ref[0, 0]
 
 
-def int8_acc_3d(codes: jax.Array, weights: jax.Array, *,
+def int8_acc_3d(packed: jax.Array, weights: jax.Array, denom: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
-    """codes (W, rows, 128) int8 with rows % 32 == 0, weights (W, 128) f32
-    -> (rows, 128) f32 = sum_w weights[w] * codes[w] (f32-widening)."""
-    n_w, rows, _ = codes.shape
-    r = INT8_ROWS * tiles_per_step(rows // INT8_ROWS)
-    return pl.pallas_call(
-        _int8_acc_kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), f32),
-        grid=(rows // r,),
-        in_specs=[
-            pl.BlockSpec((n_w, r, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((n_w, LANES), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
-        name="int8_weighted_sum",
-        interpret=interpret,
-    )(codes, weights)
+    """packed (W, word_rows, 128) int32 code words of ``repro.kernels.qsgd``
+    (word_rows a multiple of its WORD_ROWS, so a grid step covers whole
+    quantize blocks), weights (W, 128) f32, denom (1, 1) f32 ->
+    (4*word_rows, 128) f32 = (sum_w weights[w] * codes[w]) / denom, the
+    sum f32-widening and the division one IEEE divide after it."""
+    return _acc_call(_int8_acc_kernel, packed, weights, CODES, interpret,
+                     "int8_weighted_sum", denom)

@@ -16,14 +16,26 @@ def _x(n, seed=0, dtype=f32, scale=0.1):
     return (jax.random.normal(jax.random.key(seed), (n,)) * scale).astype(dtype)
 
 
+def _assert_words_hold(words, codes):
+    """The kernel's int32 code words are the oracle's packing of ``codes``,
+    and unpack to them exactly."""
+    assert words.dtype == jnp.int32 and words.shape[1] == 128
+    np.testing.assert_array_equal(
+        np.asarray(jax.lax.bitcast_convert_type(words, jnp.uint32)).reshape(-1),
+        np.asarray(ref.int8_pack_ref(codes)))
+    np.testing.assert_array_equal(np.asarray(ops.int8_unpack(words, codes.size)),
+                                  np.asarray(codes))
+
+
+# 32768 is one whole (256, 128) block, the other sizes are padded
 @pytest.mark.parametrize("n", SIZES)
-@pytest.mark.parametrize("levels", [4, 16, 64])
+@pytest.mark.parametrize("levels", [4, 15, 16, 64, 127])
 def test_qsgd_kernel(n, levels):
     x = _x(n)
     u = jax.random.uniform(jax.random.key(1), (n,))
-    codes, norm = ops.qsgd_quantize(x, u, levels=levels)
+    words, norm = ops.qsgd_quantize(x, u, levels=levels)
     expected = ref.qsgd_ref(x, u, jnp.linalg.norm(x), levels)
-    np.testing.assert_array_equal(np.asarray(codes), np.asarray(expected))
+    _assert_words_hold(words, expected)
     np.testing.assert_allclose(float(norm[0]), float(jnp.linalg.norm(x)), rtol=1e-6)
 
 
@@ -32,10 +44,10 @@ def test_qsgd_kernel(n, levels):
 def test_qsgd_ef_fused(n, decay):
     g, e = _x(n, 0), _x(n, 1, scale=0.05)
     u = jax.random.uniform(jax.random.key(2), (n,))
-    codes, norm, enew = ops.qsgd_ef_fused(g, e, u, levels=16, decay=decay)
+    words, norm, enew = ops.qsgd_ef_fused(g, e, u, levels=16, decay=decay)
     a_norm = jnp.linalg.norm(e * decay + g)
     cr, er = ref.qsgd_ef_ref(g, e, u, a_norm, 16, decay)
-    np.testing.assert_array_equal(np.asarray(codes), np.asarray(cr))
+    _assert_words_hold(words, cr)
     np.testing.assert_allclose(np.asarray(enew), np.asarray(er), rtol=1e-5, atol=1e-7)
 
 

@@ -22,13 +22,13 @@ ROWS = N // LANES
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described ``v5e:2x2`` topology (four chips)."""
     import os
 
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -41,9 +41,16 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _cases():
@@ -57,6 +64,7 @@ def _cases():
     x2, s11 = ((ROWS, LANES), "float32"), ((1, 1), "float32")
     words = ROWS // sign_pack.BITS  # 32 signs per 32-bit word
     tern_words = ROWS // wire_reduce.TERN_SLOTS  # 16 two-bit slots per word
+    int8_words = ROWS // qsgd.CODES  # 4 int8 codes per word
     weights = ((W, LANES), "float32")
     bh, seq, hd = 64, 4096, 64  # wkv6: B*H rows of a 4096-token sequence
     cases = {
@@ -71,7 +79,7 @@ def _cases():
         "tern_acc_3d": (wire_reduce.tern_acc_3d,
                         [((W, tern_words, LANES), "int32"), weights]),
         "int8_acc_3d": (wire_reduce.int8_acc_3d,
-                        [((W, ROWS, LANES), "int8"), weights]),
+                        [((W, int8_words, LANES), "int32"), weights, s11]),
         "threshold_2d": (threshold_sparsify.threshold_2d, [x2, s11]),
         "wkv6_chunked": (wkv6.wkv6_chunked,
                          [((bh, seq, hd), "float32")] * 4
@@ -131,3 +139,52 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     calls = re.findall(
         r"(%[\w.-]+) = [^\n]*custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"", text)
     assert calls and all(re.fullmatch(rf"%{KERNELS[name]}\.\d+", c) for c in calls), calls
+
+
+@pytest.mark.parametrize("n", [N, N - 1000])  # a whole number of tiles, and not
+def test_int8_exchange_carries_words_without_byte_copies(v5e, n):
+    """One bucket's compressed int8 exchange, compiled under shard_map for
+    the four described chips: the codes go from the quantize kernel over
+    the all-gather into the widening sum as 32-bit words, so no fusion of
+    the optimized entry computation writes an 8-bit array of the bucket's
+    size or W times it (the byte planes unpacked from the gathered words,
+    or the codes re-tiled for the sum)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import aggregate
+    from repro.core.types import CommConfig
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(W), ("data",))
+    comm = CommConfig(compressor="qsgd_kernel", compressor_kwargs={"levels": 127},
+                      wire_format="compressed", bucket_mb=4 * n / 2**20)
+    plan = aggregate.make_bucket_plan(comm, {"g": jax.ShapeDtypeStruct((n,), jnp.float32)})
+    assert len(plan.buckets) == 1
+
+    def exchange(g):
+        state = aggregate.init_comm_state(comm, plan)
+        out, _ = aggregate.aggregate_gradients(comm, plan, {"g": g}, state,
+                                               jax.random.key(0), ("data",))
+        return out["g"]
+
+    step = jax.jit(shard_map(exchange, mesh=mesh, in_specs=P("data"), out_specs=P(),
+                             check_vma=False))
+    g = jax.ShapeDtypeStruct((W * n,), jnp.float32, sharding=NamedSharding(mesh, P("data")))
+    text = step.lower(g).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert "int8_weighted_sum" in entry and "all-gather" in entry
+    tile = 256 * LANES
+    sizes = {n, W * n, n + (-n) % tile, W * (n + (-n) % tile)}
+    narrow = []
+    for name, out in re.findall(r"^\s*(%\S+) = (.*?) fusion\(", entry, re.M):
+        for dims in re.findall(r"\b[su]8\[([\d,]*)\]", out):
+            if int(np.prod([int(d) for d in dims.split(",") if d])) in sizes:
+                narrow.append((name, out))
+    assert not narrow, narrow

@@ -79,16 +79,22 @@ def test_tern_pack_acc_matches_reference(n_w):
                                   np.asarray(tern[0], dtype=np.float32))
 
 
-@pytest.mark.parametrize("n_w", [3, 4])
-def test_int8_weighted_sum_matches_reference(n_w):
-    n = 9000
+@pytest.mark.parametrize("n_w", [1, 2, 3, 4, 8])
+@pytest.mark.parametrize("n", [9000, 32768])  # padded, and one whole block
+def test_int8_weighted_sum_matches_reference(n_w, n):
+    """The word-reading widening sum equals the jnp sum of the unpacked
+    codes times the weights, in worker order, over denom, bit for bit."""
     key = jax.random.key(40 + n_w)
-    codes = jax.random.randint(key, (n_w, n), -127, 128).astype(jnp.int8)
+    codes = jax.random.randint(key, (n_w, n), -128, 128).astype(jnp.int8)
+    words = jnp.stack([jax.lax.bitcast_convert_type(kref.int8_pack_ref(codes[w]),
+                                                    jnp.int32).reshape(-1, 128)
+                       for w in range(n_w)])
     weights = jnp.linspace(0.01, 0.05, n_w)
-    got = ops.int8_weighted_sum(codes, weights)
-    expect = kref.weighted_sum_ref(codes.astype(jnp.float32), weights)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
-                               rtol=1e-6, atol=1e-5)
+    denom = 3.0
+    got = ops.int8_weighted_sum(words, weights, denom, n=n)
+    expect = jax.jit(lambda c, w, d: kref.weighted_sum_ref(c.astype(jnp.float32), w) / d)(
+        codes, weights, denom)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
 
 # ---------------------------------------------------------------------------
@@ -251,3 +257,113 @@ def test_packed_sign_payload_is_32x_under_dense():
     assert dense > 0 and packed > 0
     ratio = dense / packed
     assert 24.0 < ratio <= 32.0, ratio
+
+
+# ---------------------------------------------------------------------------
+# The compressed int8 wire carries the quantize kernel's 32-bit words: the
+# aggregate equals, bit for bit, the path that gathered the int8 codes
+# themselves and summed them widened (4 fake devices, subprocess).
+# ---------------------------------------------------------------------------
+
+INT8_WIRE_SCRIPT = r"""
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
+from repro.core import aggregate, comms
+from repro.core.types import CommConfig
+from repro.kernels import ops
+from repro.kernels import ref as kref
+
+W = 4
+mesh = make_mesh((W,), ("data",), axis_types=(AxisType.Auto,))
+# three buckets: one whole (256, 128) block, and two that are padded
+shapes = {"a": (256, 128), "b": (1000,), "w": (300, 130)}
+keys = jax.random.split(jax.random.key(0), len(shapes))
+grads = {k: jax.random.normal(kk, (W,) + s) * 0.1
+         for kk, (k, s) in zip(keys, sorted(shapes.items()))}
+comms_by_mode = {
+    "plain": CommConfig(),
+    "ef": CommConfig(error_feedback=True, ef_decay=0.9),
+    "churn": CommConfig(churn=True, dropout_rate=0.5),
+}
+
+
+def old_reduce(compressor, c, p, axes, alive_g, denom, integ=None):
+    # the int8 codes gathered as int8 and summed widened in worker order,
+    # then divided: what the wire did before it carried the words
+    codes = ops.int8_unpack(c.payload["code"], c.n)
+    cg = comms.all_gather(codes, axes, axis=0)
+    ng = comms.all_gather(c.payload["norm"], axes, axis=0).reshape(-1)
+    w = ng / jnp.asarray((p or {}).get("levels", compressor.levels), jnp.float32)
+    if alive_g is not None:
+        w = w * alive_g
+    return kref.weighted_sum_ref(cg.astype(jnp.float32), w) / denom
+
+
+def run(comm):
+    plan = aggregate.make_bucket_plan(
+        comm, {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype) for k, v in grads.items()})
+    assert len(plan.buckets) == 3
+
+    def f(g, ef):
+        g = {k: v[0] for k, v in g.items()}
+        state = aggregate.init_comm_state(comm, plan)
+        if "ef" in state:
+            state["ef"] = [e[0] for e in ef]
+        out, st = aggregate.aggregate_gradients(comm, plan, g, state,
+                                                jax.random.key(7), ("data",))
+        return out, [e[None] for e in st.get("ef", [])]
+
+    ef0 = [jax.random.normal(jax.random.key(9 + i), (W, b.size)) * 0.05
+           for i, b in enumerate(plan.buckets)]
+    step = jax.jit(shard_map(f, mesh=mesh, in_specs=({k: P("data") for k in grads}, P("data")),
+                             out_specs=({k: P() for k in grads}, P("data")), check_vma=False))
+    return step(grads, ef0)
+
+
+out = {}
+for mode, extra in comms_by_mode.items():
+    comm = extra.with_updates(compressor="qsgd_kernel", compressor_kwargs={"levels": 127},
+                              wire_format="compressed", bucket_mb=0.1)
+    new = run(comm)
+    words_reduce = aggregate._int8_code_reduce
+    aggregate._int8_code_reduce = old_reduce
+    try:
+        old = run(comm)
+    finally:
+        aggregate._int8_code_reduce = words_reduce
+    same = all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)))
+    moved = all(float(jnp.max(jnp.abs(v))) > 0 for v in new[0].values())
+    if mode == "plain":
+        plain = new[0]
+    # churn drops a worker, so its mean differs from the plain one
+    differs = not all(np.array_equal(np.asarray(plain[k]), np.asarray(new[0][k]))
+                      for k in grads)
+    out[mode] = {"same": same, "nonzero": moved, "ef_leaves": len(new[1]),
+                 "differs_from_plain": differs}
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def int8_wire_results():
+    import json
+
+    from tests.helpers import run_subprocess_devices
+
+    out = run_subprocess_devices(INT8_WIRE_SCRIPT, n_devices=4, timeout=600)
+    return json.loads(out.split("RESULT ", 1)[1])
+
+
+@pytest.mark.parametrize("mode", ["plain", "ef", "churn"])
+def test_int8_word_wire_matches_int8_code_gather(int8_wire_results, mode):
+    """aggregate_gradients on the compressed int8 wire (words from the
+    quantize kernel, or the fused EF kernel, straight into the widening
+    sum) gives the identical bits of the composed path: int8 codes ->
+    int8 all-gather -> widening sum -> / denom."""
+    got = int8_wire_results[mode]
+    assert got["same"] and got["nonzero"], got
+    assert got["ef_leaves"] == (3 if mode == "ef" else 0)
+    assert got["differs_from_plain"] == (mode != "plain")
