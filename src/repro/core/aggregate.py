@@ -42,18 +42,9 @@ from repro.core.compression.base import (
     runtime_knob_values,
     runtime_knobs,
 )
-from repro.core.types import CommConfig, effective_corruption_kind
+from repro.core.types import CommConfig, bundle_spec, carries_mask
 
 f32 = jnp.float32
-
-
-def churn_enabled(comm: CommConfig) -> bool:
-    """Whether the masked (churn) program structure is on for this config —
-    must mirror :func:`repro.core.types.bundle_spec`'s ``churn`` rule."""
-    return bool(getattr(comm, "churn", False)
-                or getattr(comm, "dropout_rate", 0.0) > 0
-                or any(r > 0 for r in getattr(comm, "worker_dropout", ()) or ())
-                or getattr(comm, "corruption_rate", 0.0) > 0)
 
 
 @dataclass(frozen=True)
@@ -136,20 +127,35 @@ def make_bucket_plan(comm: CommConfig, grads_abstract: Any) -> BucketPlan:
 
 
 def init_comm_state(comm: CommConfig, plan: BucketPlan) -> dict[str, Any]:
+    """Every comm-state leaf of a cell, one shard's view: scalars are
+    replicated, every other leaf is per shard."""
+    spec = bundle_spec(comm)
+
+    def per_bucket():
+        return [jnp.zeros((b.size,), f32) for b in plan.buckets]
+
     state: dict[str, Any] = {"step": jnp.zeros((), jnp.int32)}
-    if churn_enabled(comm):
-        # previous round's participation bit (per shard) — rejoin detection
+    if spec.churn:
+        # previous round's participation bit — rejoin detection
         state["alive_prev"] = jnp.ones((1,), f32)
-    if effective_corruption_kind(comm) != "none":
-        # consecutive-quarantine counter (per shard) + lifetime tallies of
-        # quarantined rounds and rejoin escalations
+        if comm.pod_local:
+            # pod-granularity liveness for the DCN sync round (derived from
+            # the per-shard bits, carried so pod rejoins are detectable)
+            state["pod_alive_prev"] = jnp.ones((1,), f32)
+    if spec.corruption_kind != "none":
+        # consecutive-quarantine counter + lifetime tallies of quarantined
+        # rounds and rejoin escalations
         state["qcount"] = jnp.zeros((1,), f32)
         state["quarantine_total"] = jnp.zeros((1,), f32)
         state["escalation_total"] = jnp.zeros((1,), f32)
+    if spec.overlap == "pipelined" and spec.overlap_staleness == 1:
+        # the last microbatch's bucket grads, double-buffered across the
+        # step boundary (aggregated by the NEXT step)
+        state["overlap_pending"] = per_bucket()
     if comm.error_feedback:
-        state["ef"] = [jnp.zeros((b.size,), f32) for b in plan.buckets]
+        state["ef"] = per_bucket()
     if comm.momentum_correction:
-        state["u"] = [jnp.zeros((b.size,), f32) for b in plan.buckets]
+        state["u"] = per_bucket()
     if plan_uses_powersgd(plan):
         qs = []
         for i, b in enumerate(plan.buckets):
@@ -160,7 +166,75 @@ def init_comm_state(comm: CommConfig, plan: BucketPlan) -> dict[str, Any]:
             else:
                 qs.append(jnp.zeros((0,), f32))
         state["psgd_q"] = qs
+    if spec.aggregator == "gossip" and spec.gossip_compress == "choco":
+        state["choco_xhat"] = per_bucket()
+        state["choco_nbr"] = per_bucket()
     return state
+
+
+def worker_index(axes) -> jax.Array:
+    """This shard's row-major index over ``axes``."""
+    widx = jnp.zeros((), jnp.int32)
+    for axn in axes:
+        widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
+    return widx
+
+
+def draw_alive(step_key: jax.Array, step: jax.Array, knobs: dict[str, Any],
+               mask_axes) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """This shard's participation bit for one round: ``(alive, in_window,
+    mask_key)``.  The mask key folds the shard's index over ``mask_axes``
+    into the round's step key; the bit is 0 with the shard's traced dropout
+    rate inside the traced ``[churn_start, churn_end)`` window, else 1."""
+    widx = worker_index(mask_axes)
+    mkey = jax.random.fold_in(step_key, widx)
+    drop = knobs["dropout"]
+    if getattr(drop, "ndim", 0) == 1:
+        # per-worker dropout vector: this shard's traced rate
+        drop = jnp.take(drop, widx)
+    u = jax.random.uniform(jax.random.fold_in(mkey, 0x6368), ())
+    stepf = step.astype(f32)
+    in_window = (stepf >= knobs["churn_start"]) & (stepf < knobs["churn_end"])
+    alive = jnp.where(in_window & (u < drop), 0.0, 1.0)
+    return alive, in_window, mkey
+
+
+def rejoin(state: dict[str, Any], alive: jax.Array,
+           slot: str = "alive_prev") -> jax.Array:
+    """The rejoin bit (alive this round, masked out the last) of a shard;
+    records this round's bit in ``state[slot]``."""
+    rejoined = alive * (1.0 - state[slot].reshape(()))
+    state[slot] = alive.reshape(1)
+    return rejoined
+
+
+def reset_comp_state(state: dict[str, Any], bit: jax.Array) -> None:
+    """Zero the compressor state (EF residual, momentum) where ``bit`` is
+    set: the frozen buffers describe a model that has since moved on.  A
+    select, so a bit that is identically 0 leaves the state bitwise."""
+    for k in ("ef", "u"):
+        if k in state:
+            state[k] = [jnp.where(bit > 0, jnp.zeros_like(e), e)
+                        for e in state[k]]
+
+
+def escalate(state: dict[str, Any], alive: jax.Array, valid: jax.Array,
+             limit: jax.Array) -> jax.Array:
+    """Bounded quarantine: a live shard's consecutive invalid rounds count
+    up in ``qcount``; at the traced ``limit`` the shard escalates to the
+    rejoin protocol's reset leg (its compressor state is stale-by-
+    quarantine the way a rejoiner's is stale-by-death) instead of retrying
+    forever.  Books the lifetime tallies and returns the escalation bit;
+    every select is the identity at corruption rate 0."""
+    q = state["qcount"].reshape(())
+    q_new = jnp.where(alive > 0, jnp.where(valid > 0, 0.0, q + 1.0), q)
+    esc = jnp.where(q_new >= limit, 1.0, 0.0)
+    reset_comp_state(state, esc)
+    state["qcount"] = jnp.where(esc > 0, 0.0, q_new).reshape(1)
+    state["quarantine_total"] = (state["quarantine_total"]
+                                 + (1.0 - valid).reshape(1))
+    state["escalation_total"] = state["escalation_total"] + esc.reshape(1)
+    return esc
 
 
 def plan_uses_powersgd(plan: BucketPlan) -> bool:
@@ -518,7 +592,7 @@ def aggregate_buckets(
     comm_state: dict[str, Any],
     key: jax.Array,
     axes: tuple[str, ...],
-    knobs: dict[str, Any] | None = None,
+    knobs: dict[str, Any],
     mask_axes: tuple[str, ...] | None = None,
     alive_info: tuple | None = None,
 ) -> tuple[list[jax.Array], dict[str, Any]]:
@@ -528,6 +602,9 @@ def aggregate_buckets(
     microbatch scan carries bucket buffers and issues these collectives with
     no data dependency on the next microbatch's compute.  Functional state
     update; safe inside ``lax.scan`` (every shape is static).
+
+    ``knobs`` is the cell's traced :class:`repro.core.types.CommKnobs` tree
+    (``knobs["comp"][i]`` per bucket, plus the scalar knobs).
 
     ``mask_axes``: the axes the churn mask is drawn over — defaults to the
     aggregation axes.  ``pod_local`` passes ALL data axes here while
@@ -542,87 +619,49 @@ def aggregate_buckets(
     n_workers = 1
     for axn in axes:
         n_workers *= jax.lax.axis_size(axn)
+    if mask_axes is None:
+        mask_axes = axes
 
     # distinct stochastic-compression keys per worker
-    key0 = key
-    widx = jnp.zeros((), jnp.int32)
-    for axn in axes:
-        widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
-    key = jax.random.fold_in(key, widx)
-    if mask_axes is None or tuple(mask_axes) == tuple(axes):
-        mkey = key
-    else:
-        widx_m = jnp.zeros((), jnp.int32)
-        for axn in mask_axes:
-            widx_m = widx_m * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
-        mkey = jax.random.fold_in(key0, widx_m)
+    step_key = key
+    key = jax.random.fold_in(key, worker_index(axes))
+
+    state = dict(comm_state)
+    for k in ("ef", "u", "psgd_q"):
+        if k in state:
+            state[k] = list(state[k])
 
     # churn: each shard draws its own participation bit for this round from
     # the per-worker key (probability/window traced via knobs); the live
     # count is one scalar psum — a real liveness round on the wire.  One
     # mask covers every bucket of the round.
-    alive = n_eff = rejoined = None
-    in_window = None
-    if churn_enabled(comm):
-        if alive_info is not None:
-            alive, rejoined, in_window = alive_info
+    alive = n_eff = None
+    if carries_mask(comm):
+        if alive_info is None:
+            alive, in_window, mkey = draw_alive(step_key, state["step"], knobs,
+                                                mask_axes)
+            rejoined = rejoin(state, alive)
         else:
-            if knobs is not None:
-                drop, cs, ce = knobs["dropout"], knobs["churn_start"], knobs["churn_end"]
-            else:
-                drop = jnp.asarray(comm.dropout_rate, f32)
-                cs = jnp.asarray(float(comm.churn_start), f32)
-                ce = jnp.asarray(float(comm.churn_end) if comm.churn_end >= 0
-                                 else float("inf"), f32)
-            if getattr(drop, "ndim", 0) == 1:
-                # per-worker dropout vector: this shard's traced rate
-                widx_d = widx if mkey is key else widx_m
-                drop = jnp.take(drop, widx_d)
-            u = jax.random.uniform(jax.random.fold_in(mkey, 0x6368), ())
-            stepf = comm_state["step"].astype(f32)
-            in_window = (stepf >= cs) & (stepf < ce)
-            alive = jnp.where(in_window & (u < drop), 0.0, 1.0)
+            alive, rejoined, in_window = alive_info
+            mkey = jax.random.fold_in(step_key, worker_index(mask_axes))
         n_eff = jnp.maximum(comms.psum(alive, axes), 1.0)
-
-    state = dict(comm_state)
-    if "ef" in state:
-        state["ef"] = list(state["ef"])
-    if "u" in state:
-        state["u"] = list(state["u"])
-
-    if "psgd_q" in state:
-        state["psgd_q"] = list(state["psgd_q"])
-
-    if alive is not None and rejoined is None and "alive_prev" in state:
-        rejoined = alive * (1.0 - state["alive_prev"].reshape(()))
-        state["alive_prev"] = alive.reshape(1)
-    if rejoined is not None:
         # rejoin protocol: a shard alive this round but masked out last
-        # round resets its compressor state — the frozen EF residual /
-        # momentum buffer describe a model that has since moved on.  The
-        # reset is a jnp.where on a rejoined bit that is identically 0 at
-        # dropout 0 (alive_prev inits to 1), preserving the bitwise
-        # churn-free equivalence; powersgd Q needs no reset because the
-        # psum'd live-set Qn overwrites every shard's factor each round.
-        for k in ("ef", "u"):
-            if k in state:
-                state[k] = [jnp.where(rejoined > 0, jnp.zeros_like(e), e)
-                            for e in state[k]]
+        # round resets its compressor state.  The rejoined bit is
+        # identically 0 at dropout 0 (alive_prev inits to 1), preserving the
+        # bitwise churn-free equivalence; powersgd Q needs no reset because
+        # the psum'd live-set Qn overwrites every shard's factor each round.
+        reset_comp_state(state, rejoined)
 
     # gradient integrity: one corruption flag per worker per round, drawn
     # from the same per-worker key stream as the churn mask (its own fold
     # tag — the mask / compressor draws are untouched); only live in-window
     # workers have a payload on the wire to corrupt
-    kind = effective_corruption_kind(comm)
-    integ = None
-    round_valid = None
-    if kind != "none" and alive is not None:
-        rate_c = (knobs["corruption"] if knobs is not None
-                  else jnp.asarray(comm.corruption_rate, f32))
-        gate = (in_window if in_window is not None
-                else jnp.asarray(True)) & (alive > 0)
-        flag = integrity.corruption_flag(mkey, rate_c, gate)
-        integ = {"kind": kind, "flag": flag, "valid_bucket": jnp.ones((), f32)}
+    integ = round_valid = None
+    if comm.corruption_kind != "none":
+        flag = integrity.corruption_flag(mkey, knobs["corruption"],
+                                         in_window & (alive > 0))
+        integ = {"kind": comm.corruption_kind, "flag": flag,
+                 "valid_bucket": jnp.ones((), f32)}
         round_valid = jnp.ones((), f32)
 
     wire_fmt = getattr(comm, "wire_format", "dense")
@@ -630,7 +669,7 @@ def aggregate_buckets(
     with comms.tag("grad_agg"):
         for i, (b, g) in enumerate(zip(plan.buckets, bufs)):
             compressor = plan.compressor(b)
-            p_i = knobs["comp"][i] if knobs is not None else None
+            p_i = knobs["comp"][i]
             if integ is not None:
                 integ["valid_bucket"] = jnp.ones((), f32)
             if (wire_fmt == "compressed" and comm.error_feedback
@@ -641,8 +680,7 @@ def aggregate_buckets(
                 # pre/post_compress collapse into the kernel; same uniform
                 # draw as the composed path (momentum correction or local
                 # clipping would need the unfused arithmetic — excluded)
-                decay = (knobs["ef_decay"] if knobs is not None
-                         else jnp.asarray(comm.ef_decay, f32))
+                decay = knobs["ef_decay"]
                 ef_prev = state["ef"][i]
                 with jax.named_scope("encode"):
                     c, e_new = compressor.compress_ef_p(
@@ -695,24 +733,7 @@ def aggregate_buckets(
                 round_valid = round_valid * integ["valid_bucket"]
             out_bufs.append(agg)
     if round_valid is not None:
-        # bounded quarantine: consecutive corrupted rounds escalate to the
-        # rejoin protocol's reset leg (the compressor state is stale-by-
-        # quarantine the same way a rejoiner's is stale-by-death) instead of
-        # retrying forever; every select is an identity at corruption 0
-        qlim = (knobs["quarantine_limit"] if knobs is not None
-                else jnp.asarray(float(comm.quarantine_limit), f32))
-        q = state["qcount"].reshape(())
-        q_new = jnp.where(alive > 0,
-                          jnp.where(round_valid > 0, 0.0, q + 1.0), q)
-        esc = jnp.where(q_new >= qlim, 1.0, 0.0)
-        for k in ("ef", "u"):
-            if k in state:
-                state[k] = [jnp.where(esc > 0, jnp.zeros_like(e), e)
-                            for e in state[k]]
-        state["qcount"] = jnp.where(esc > 0, 0.0, q_new).reshape(1)
-        state["quarantine_total"] = (state["quarantine_total"]
-                                     + (1.0 - round_valid).reshape(1))
-        state["escalation_total"] = state["escalation_total"] + esc.reshape(1)
+        escalate(state, alive, round_valid, knobs["quarantine_limit"])
     state["step"] = state["step"] + 1
     return out_bufs, state
 
@@ -724,7 +745,7 @@ def aggregate_gradients(
     comm_state: dict[str, Any],
     key: jax.Array,
     axes: tuple[str, ...],
-    knobs: dict[str, Any] | None = None,
+    knobs: dict[str, Any],
     mask_axes: tuple[str, ...] | None = None,
     alive_info: tuple | None = None,
 ) -> tuple[Any, dict[str, Any]]:
@@ -732,10 +753,9 @@ def aggregate_gradients(
 
     ``knobs`` is the traced :class:`repro.core.types.CommKnobs` tree of the
     cell (``knobs["comp"][i]`` per bucket, plus ef_decay / momentum /
-    local_clip scalars); without it every value bakes from ``comm`` as
-    before — the two paths compute identically.  ``mask_axes``/``alive_info``
-    pass through to :func:`aggregate_buckets` (pod-granular churn masks /
-    externally-held pipelined masks)."""
+    local_clip scalars).  ``mask_axes``/``alive_info`` pass through to
+    :func:`aggregate_buckets` (pod-granular churn masks / externally-held
+    pipelined masks)."""
     leaves, treedef = jax.tree.flatten(grads)
     # the bucket packing and unpacking carry no collective, so tagging them
     # moves no wire bytes between tags; it names them in a device trace

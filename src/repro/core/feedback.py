@@ -20,16 +20,6 @@ from repro.core.types import CommConfig
 f32 = jnp.float32
 
 
-def init_comm_state(comm: CommConfig, flat_template: list[jax.Array]) -> dict[str, Any]:
-    """Per-worker communication state (EF residuals, momentum buffers)."""
-    state: dict[str, Any] = {}
-    if comm.error_feedback:
-        state["ef"] = [jnp.zeros_like(v) for v in flat_template]
-    if comm.momentum_correction:
-        state["u"] = [jnp.zeros_like(v) for v in flat_template]
-    return state
-
-
 def local_clip(g: jax.Array, thr, n_workers: int) -> jax.Array:
     """Local Gradient Clipping [25] (§IX-C): each worker clips at
     thr / sqrt(N) so the aggregated gradient keeps the global threshold.
@@ -60,7 +50,7 @@ def pre_compress(
     state: dict[str, Any],
     idx: int,
     n_workers: int,
-    knobs: dict[str, Any] | None = None,
+    knobs: dict[str, Any],
     alive: jax.Array | None = None,
 ) -> jax.Array:
     """Momentum correction + EF accumulation + local clipping (order per
@@ -68,21 +58,18 @@ def pre_compress(
 
     The on/off *flags* come from ``comm`` (structural — they decide which
     state buffers exist); the coefficients come from the traced ``knobs``
-    tree when given, so cells differing only in momentum / clip / EF-decay
-    values share one compiled program.  ``alive`` (churn participation bit):
+    tree, so cells differing only in momentum / clip / EF-decay values
+    share one compiled program.  ``alive`` (churn participation bit):
     a masked-out shard neither sends nor accumulates — its momentum buffer
     freezes here and its EF residual freezes in :func:`post_compress`."""
     if comm.momentum_correction:
-        m = knobs["momentum"] if knobs is not None else comm.momentum_correction
-        u = m * state["u"][idx] + g
+        u = knobs["momentum"] * state["u"][idx] + g
         state["u"][idx] = u if alive is None else jnp.where(alive > 0, u, state["u"][idx])
         g = u
     if comm.local_clip:
-        thr = knobs["local_clip"] if knobs is not None else comm.local_clip
-        g = local_clip(g, thr, n_workers)
+        g = local_clip(g, knobs["local_clip"], n_workers)
     if comm.error_feedback:
-        decay = knobs["ef_decay"] if knobs is not None else comm.ef_decay
-        g = state["ef"][idx] * decay + g
+        g = state["ef"][idx] * knobs["ef_decay"] + g
     return g
 
 

@@ -54,8 +54,8 @@ SPIKE_FACTOR = 1e8
 VALID_MAX = 1e6
 
 #: fold_in tag for the corruption uniform draw — distinct from the churn
-#: mask tag (0x6368) so corruption draws never perturb the mask / gradient /
-#: compressor key streams ("corr")
+#: mask's tag ("ch", ``aggregate.draw_alive``) so corruption draws never
+#: perturb the mask / gradient / compressor key streams ("corr")
 CORRUPT_FOLD = 0x636F72
 
 

@@ -10,8 +10,8 @@ Two engines:
 
 2. :func:`simulate_training` — an *exact* (not event-driven) multi-worker
    SGD simulator: one jitted ``lax.scan`` over steps whose carry holds
-   ``(X, ef, delay_buf, key, total_bits)``, vmapped over workers inside the
-   step, over replica seeds outside it (:func:`simulate_training_batch`),
+   ``(X, ef, delay_buf, key, total_bits, mask)``, vmapped over workers
+   inside the step, over replica seeds outside it (:func:`simulate_training_batch`),
    and — new in PR 3 — over whole taxonomy *cells* outside that
    (:func:`simulate_training_classbatch`): a cell's config splits into a
    static :class:`EngineSpec` and a traced :class:`CellParams`, so every
@@ -362,10 +362,9 @@ class SimCfg:
     steps: int = 300
     seed: int = 0
     gossip_w: float = 1.0 / 3.0
-    # churn (elastic-worker) axis: a per-step participation mask drawn
-    # inside the scan. `churn` is STRUCTURAL (the masked program differs);
-    # the probabilities / window are traced values.
-    churn: bool = False
+    # churn (elastic-worker) axis: the scan always draws a per-step
+    # participation mask; the probabilities / window are traced values (at
+    # dropout 0, or outside the window, the mask is all ones).
     dropout_rate: float = 0.0  # shared per-step P(worker offline)
     worker_dropout: tuple = ()  # per-worker override (length n_workers)
     churn_start: int = 0  # first step (inclusive) dropout applies
@@ -374,13 +373,15 @@ class SimCfg:
     #: rejoin and lets parameters re-enter via the scheme's own averaging;
     #: "pull_avg" additionally pulls the live-set parameter average at the
     #: rejoin step (local/gossip schemes, where a rejoiner is actually
-    #: stale), charging a dense model download per rejoin event.
+    #: stale; elsewhere it normalizes to "reset"), charging a dense model
+    #: download per rejoin event.
     rejoin_policy: str = "reset"
     # gradient-integrity axis: per-round P(a live worker's wire payload is
-    # corrupted in-domain).  The KIND is structural (the guarded program
-    # differs); the rate is traced.  A detected-corrupt contribution is
-    # quarantined for one round; `quarantine_limit` consecutive quarantines
-    # escalate to the rejoin protocol above.
+    # corrupted in-domain).  The KIND is structural (naming one compiles the
+    # guarded program, the identity at rate 0); the rate is traced.  A
+    # detected-corrupt contribution is quarantined for one round;
+    # `quarantine_limit` consecutive quarantines escalate to the rejoin
+    # protocol above.
     corruption_rate: float = 0.0
     corruption_kind: str = "none"  # none | nan | inf | spike | bitflip
     quarantine_limit: int = 3
@@ -510,13 +511,11 @@ class EngineSpec:
     comp_key: tuple  # compressor shape fingerprint (("dense",) for None)
     delay_slots: int = 1  # delay-line depth >= max staleness + 1 in the class
     traced_noise: bool = False  # grad noise passed as a traced CellParams value
-    churn: bool = False  # participation mask carried through the scan
     #: "reset" | "pull_avg" — structural (the pull program differs);
-    #: normalized to "reset" when churn is off
+    #: normalized to "reset" outside local/gossip, which have no pull
     rejoin_policy: str = "reset"
     #: corruption kind (STRUCTURAL — the detect/quarantine program differs
-    #: per kind); normalized to "none" unless the rate is positive or the
-    #: cell explicitly keeps the integrity program (churn + kind set)
+    #: per kind; "none" = unguarded)
     corruption_kind: str = "none"
 
 
@@ -532,9 +531,9 @@ class CellParams:
     gossip_w: float = 1.0 / 3.0
     grad_noise: float | None = None
     comp: dict[str, float] = field(default_factory=dict)
-    # churn values (traced; present only when the spec carries the mask):
-    # per-worker dropout probabilities and the [start, end) step window
-    dropout: tuple | None = None
+    # churn values (traced): per-worker dropout probabilities and the
+    # [start, end) step window
+    dropout: tuple = ()
     churn_start: float = 0.0
     churn_end: float = float("inf")
     # gradient-integrity values (traced; present only when the spec carries
@@ -549,13 +548,12 @@ class CellParams:
             "staleness": jnp.asarray(self.staleness, jnp.int32),
             "gossip_w": jnp.asarray(self.gossip_w, f32),
             "comp": {k: jnp.asarray(v, f32) for k, v in self.comp.items()},
+            "dropout": jnp.asarray(self.dropout, f32),
+            "churn_start": jnp.asarray(self.churn_start, f32),
+            "churn_end": jnp.asarray(self.churn_end, f32),
         }
         if self.grad_noise is not None:
             out["grad_noise"] = jnp.asarray(self.grad_noise, f32)
-        if self.dropout is not None:
-            out["dropout"] = jnp.asarray(self.dropout, f32)
-            out["churn_start"] = jnp.asarray(self.churn_start, f32)
-            out["churn_end"] = jnp.asarray(self.churn_end, f32)
         if self.corruption is not None:
             out["corruption"] = jnp.asarray(self.corruption, f32)
             out["quarantine_limit"] = jnp.asarray(self.quarantine_limit, f32)
@@ -571,19 +569,10 @@ def _grad_takes_noise(grad_fn) -> bool:
         return False
 
 
-def _engine_corruption_kind(cfg: SimCfg) -> str:
-    """Structural corruption kind of a cell — mirrors
-    :func:`repro.core.types.effective_corruption_kind`: the kind stays
-    structural when the rate is positive OR the cell explicitly keeps the
-    guarded program (churn flag + kind set, for rate-0 bitwise pins);
-    otherwise it is inert and normalizes to "none" so it never splits a
-    shape class.  The opt-in gate is the EXPLICIT ``churn`` flag (mirroring
-    how ``churn=True`` keeps a dropout-0 cell in the churn class) — derived
-    churn (a positive dropout rate) with a stray kind stays inert."""
-    kind = getattr(cfg, "corruption_kind", "none")
-    if cfg.corruption_rate > 0 or (cfg.churn and kind != "none"):
-        return kind
-    return "none"
+def _rejoin_policy(cfg: SimCfg) -> str:
+    """The structural rejoin policy: only local/gossip pull a rejoiner (the
+    PS schemes' global model makes rejoin implicit)."""
+    return cfg.rejoin_policy if cfg.sync in ("local", "gossip") else "reset"
 
 
 def split_cfg(cfg: SimCfg, *, grad_noise: float | None = None,
@@ -599,8 +588,6 @@ def split_cfg(cfg: SimCfg, *, grad_noise: float | None = None,
         raise ValueError(
             f"split_cfg needs dim to derive {type(cfg.compressor).__name__} "
             f"knob values ({batch_knobs(cfg.compressor)})")
-    churn = bool(cfg.churn or cfg.dropout_rate > 0 or any(cfg.worker_dropout)
-                 or cfg.corruption_rate > 0)
     if cfg.worker_dropout and len(cfg.worker_dropout) != cfg.n_workers:
         raise ValueError("worker_dropout length must equal n_workers")
     if cfg.rejoin_policy not in ("reset", "pull_avg"):
@@ -617,7 +604,7 @@ def split_cfg(cfg: SimCfg, *, grad_noise: float | None = None,
         raise ValueError("corruption_rate must be in [0, 1)")
     if cfg.quarantine_limit < 1:
         raise ValueError("quarantine_limit must be >= 1")
-    kind = _engine_corruption_kind(cfg)
+    kind = cfg.corruption_kind
     spec = EngineSpec(
         sync=cfg.sync,
         n_workers=cfg.n_workers,
@@ -626,8 +613,7 @@ def split_cfg(cfg: SimCfg, *, grad_noise: float | None = None,
         comp_key=shape_fingerprint(cfg.compressor),
         delay_slots=cfg.staleness + 1 if cfg.sync in ("ssp", "asp") else 1,
         traced_noise=grad_noise is not None,
-        churn=churn,
-        rejoin_policy=(cfg.rejoin_policy if churn else "reset"),
+        rejoin_policy=_rejoin_policy(cfg),
         corruption_kind=kind,
     )
     dropout = (tuple(float(p) for p in cfg.worker_dropout)
@@ -640,7 +626,7 @@ def split_cfg(cfg: SimCfg, *, grad_noise: float | None = None,
         gossip_w=cfg.gossip_w,
         grad_noise=grad_noise,
         comp=batch_param_values(cfg.compressor, dim) if dim is not None else {},
-        dropout=dropout if churn else None,
+        dropout=dropout,
         churn_start=float(cfg.churn_start),
         churn_end=float(cfg.churn_end) if cfg.churn_end >= 0 else float("inf"),
         corruption=float(cfg.corruption_rate) if kind != "none" else None,
@@ -656,12 +642,9 @@ def shape_class_key(cfg: SimCfg) -> tuple:
     resolved to the class maximum after grouping."""
     from repro.core.compression.base import shape_fingerprint
 
-    churn = bool(cfg.churn or cfg.dropout_rate > 0 or any(cfg.worker_dropout)
-                 or cfg.corruption_rate > 0)
     return (cfg.sync, cfg.n_workers, cfg.steps, bool(cfg.error_feedback),
-            shape_fingerprint(cfg.compressor), churn,
-            cfg.rejoin_policy if churn else "reset",
-            _engine_corruption_kind(cfg))
+            shape_fingerprint(cfg.compressor), _rejoin_policy(cfg),
+            cfg.corruption_kind)
 
 
 def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
@@ -671,10 +654,9 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
     baked into the trace).  Workers are vmapped inside the step; the caller
     vmaps replica seeds and (for a class batch) cells — with per-cell
     ``data``, cells differing only in problem seed share the program.
-    The carry is ``(X, ef, delay_buf, key, total_bits)`` (plus the previous
-    round's participation mask under churn, for rejoin detection); wire
-    bits are
-    accumulated in-scan from the compressor roundtrip — data-dependent
+    The carry is ``(X, ef, delay_buf, key, total_bits, m_prev)`` — the last
+    is the previous round's participation mask, for rejoin detection; wire
+    bits are accumulated in-scan from the compressor roundtrip — data-dependent
     (threshold-style) payloads charge their *measured* size."""
     from repro.core import integrity
     from repro.core.compression.base import roundtrip_bits, roundtrip_bits_ef
@@ -714,7 +696,7 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
         def apply_compression(ckeys, G, ef):
             """Compress every worker's (effective) gradient; returns the
             reconstruction, the new EF residual, and the PER-WORKER wire-bit
-            vector of this round (callers sum it, masked under churn)."""
+            vector of this round (callers sum it under the mask)."""
             if comp is None:
                 return G, ef, jnp.full((n,), 32.0 * dim, f32)
             if spec.error_feedback:
@@ -726,91 +708,83 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
             return out, ef, wb
 
         def step(carry, t):
+            X, ef, delay_buf, key, total_bits, m_prev = carry[:6]
             if corrupt:
-                X, ef, delay_buf, key, total_bits, m_prev, qc, qb, qr, qe = carry
-            elif spec.churn:
-                X, ef, delay_buf, key, total_bits, m_prev = carry
-            else:
-                X, ef, delay_buf, key, total_bits = carry
+                qc, qb, qr, qe = carry[6:]
             key, k1, k2 = jax.random.split(key, 3)
             gkeys = jax.random.split(k1, n)
             ckeys = jax.random.split(k2, n)
-            if spec.churn:
-                # The mask key folds out of the NEW carry key (the split
-                # above is untouched), so the gradient/compressor key
-                # streams match the churn-free program draw for draw and a
-                # dropout-0 churn cell reproduces it bitwise.
-                u = jax.random.uniform(jax.random.fold_in(key, 0x6368), (n,))
-                tf = t.astype(f32)
-                in_window = (tf >= p["churn_start"]) & (tf < p["churn_end"])
-                m = jnp.where(in_window & (u < p["dropout"]), 0.0, 1.0)
-                n_alive = jnp.maximum(jnp.sum(m), 1.0)
-                # rejoin protocol: a worker alive now but masked last round
-                # resets its compressor state at the END of its rejoin round
-                # (the stale EF residual is garbage w.r.t. the moved model;
-                # it is dropped rather than carried — the reset merges into
-                # the post-compression freeze select below because ANY op
-                # inserted before the compression reductions re-fuses them
-                # and costs the bitwise dropout-0 equivalence).  Under
-                # pull_avg it also pulls the live-set parameter average
-                # where it is actually stale (local/gossip — PS schemes'
-                # global model makes rejoin implicit).  All selections are
-                # jnp.where on a rejoined bit that is identically 0 at
-                # dropout 0.
-                rejoined = m * (1.0 - m_prev)
-                if spec.rejoin_policy == "pull_avg" and sync in ("local", "gossip"):
-                    donors = m * m_prev  # live both rounds: not stale
-                    n_don = jnp.sum(donors)
-                    xpull = (jnp.sum(X * donors[:, None], axis=0)
-                             / jnp.maximum(n_don, 1.0))
-                    take = (rejoined[:, None] > 0) & (n_don > 0)
-                    X = jnp.where(take, xpull[None, :], X)
-                    # each pull is a dense model download (resync transfer)
-                    total_bits = total_bits + jnp.where(
-                        n_don > 0, jnp.sum(rejoined) * 32.0 * dim, 0.0)
-                if corrupt:
-                    # per-worker corruption flags: own fold tag off the carry
-                    # key, so the mask / gradient / compressor streams are
-                    # untouched; only live in-window workers send a payload
-                    cu = jax.random.uniform(
-                        jax.random.fold_in(key, integrity.CORRUPT_FOLD), (n,))
-                    cflag = jnp.where(in_window & (m > 0)
-                                      & (cu < p["corruption"]), 1.0, 0.0)
-                    valid_round = jnp.ones((n,), f32)
-                    qbits_step = jnp.zeros((), f32)
+            # participation mask: folds out of the NEW carry key (the split
+            # above is untouched), so the gradient/compressor key streams
+            # do not depend on the dropout values; all ones at dropout 0 or
+            # outside the window.  The window scales the rates instead of
+            # gating the draw: with a gate, one executable rounded a closed
+            # window 1 ulp apart from an open window at rate 0 on the CPU
+            # (the loop-invariant gate lets the compiler specialize the
+            # closed-window loop on a constant mask)
+            tf = t.astype(f32)
+            win = ((tf >= p["churn_start"]) & (tf < p["churn_end"])).astype(f32)
+            u = jax.random.uniform(jax.random.fold_in(key, 0x6368), (n,))
+            m = jnp.where(u < p["dropout"] * win, 0.0, 1.0)
+            n_alive = jnp.maximum(jnp.sum(m), 1.0)
+            # rejoin protocol: a worker alive now but masked last round
+            # resets its compressor state at the END of its rejoin round
+            # (the stale EF residual is garbage w.r.t. the moved model; it
+            # is dropped rather than carried — the reset merges into the
+            # post-compression freeze select below).  Under pull_avg
+            # (local/gossip only, where a rejoiner is actually stale) it
+            # also pulls the live-set parameter average.  All selections
+            # are jnp.where on a rejoined bit that is identically 0 at
+            # dropout 0.
+            rejoined = m * (1.0 - m_prev)
+            if spec.rejoin_policy == "pull_avg":
+                donors = m * m_prev  # live both rounds: not stale
+                n_don = jnp.sum(donors)
+                xpull = (jnp.sum(X * donors[:, None], axis=0)
+                         / jnp.maximum(n_don, 1.0))
+                take = (rejoined[:, None] > 0) & (n_don > 0)
+                X = jnp.where(take, xpull[None, :], X)
+                # each pull is a dense model download (resync transfer)
+                total_bits = total_bits + jnp.where(
+                    n_don > 0, jnp.sum(rejoined) * 32.0 * dim, 0.0)
+            if corrupt:
+                # per-worker corruption flags: own fold tag off the carry
+                # key, so the mask / gradient / compressor streams are
+                # untouched; only live in-window workers send a payload
+                cu = jax.random.uniform(
+                    jax.random.fold_in(key, integrity.CORRUPT_FOLD), (n,))
+                cflag = jnp.where((m > 0) & (cu < p["corruption"] * win),
+                                  1.0, 0.0)
+                valid_round = jnp.ones((n,), f32)
+                qbits_step = jnp.zeros((), f32)
             G = grad_all(X, gkeys)
 
             if sync == "gossip":
                 Ghat, ef2, wb = apply_compression(ckeys, G, ef)
-                if spec.churn:
-                    # dead rows are identity (frozen params), dead columns'
-                    # weight folds into each live row's self-weight — rows
-                    # still sum to 1 and W stays symmetric; a rejoiner's
-                    # stale residual is dropped (carry-out zero)
-                    ef = jnp.where(rejoined[:, None] > 0, jnp.zeros_like(ef),
-                                   jnp.where(m[:, None] > 0, ef2, ef))
-                    Y = X - lr * Ghat * m[:, None]
-                    m_eff = m
-                    if corrupt:
-                        # the wire payload is the worker's mixed row: corrupt
-                        # it in-domain, validate, and drop detected rows from
-                        # the mixing (the quarantined worker keeps its own
-                        # local update — quarantine is not death); an
-                        # UNDETECTED corruption flows into the mix for real
-                        Yw = integrity.corrupt_dense(spec.corruption_kind, Y,
-                                                     cflag[:, None])
-                        valid = integrity.dense_valid(Yw, per_row=True)
-                        m_eff = m * valid
-                        Y = jnp.where(valid[:, None] > 0, Yw, Y)
-                        valid_round = valid
-                        qbits_step = jnp.sum(wb * m * (1.0 - valid))
-                    Weff = masked_mixing_matrix(W, m_eff)
-                    X = Weff @ Y
-                    total_bits = total_bits + jnp.sum(wb * m)
-                else:
-                    ef = ef2
-                    X = W @ (X - lr * Ghat)
-                    total_bits = total_bits + jnp.sum(wb)
+                # dead rows are identity (frozen params), dead columns'
+                # weight folds into each live row's self-weight — rows
+                # still sum to 1 and W stays symmetric; a rejoiner's stale
+                # residual is dropped (carry-out zero)
+                ef = jnp.where(rejoined[:, None] > 0, jnp.zeros_like(ef),
+                               jnp.where(m[:, None] > 0, ef2, ef))
+                Y = X - lr * Ghat * m[:, None]
+                m_eff = m
+                if corrupt:
+                    # the wire payload is the worker's mixed row: corrupt it
+                    # in-domain, validate, and drop detected rows from the
+                    # mixing (the quarantined worker keeps its own local
+                    # update — quarantine is not death); an UNDETECTED
+                    # corruption flows into the mix for real
+                    Yw = integrity.corrupt_dense(spec.corruption_kind, Y,
+                                                 cflag[:, None])
+                    valid = integrity.dense_valid(Yw, per_row=True)
+                    m_eff = m * valid
+                    Y = jnp.where(valid[:, None] > 0, Yw, Y)
+                    valid_round = valid
+                    qbits_step = jnp.sum(wb * m * (1.0 - valid))
+                Weff = masked_mixing_matrix(W, m_eff)
+                X = Weff @ Y
+                total_bits = total_bits + jnp.sum(wb * m)
             else:
                 if sync == "asp":
                     delay_buf = jnp.roll(delay_buf, 1, axis=0).at[0].set(G)
@@ -821,7 +795,7 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
                 else:
                     G_eff = G
                 Ghat, ef2, wb = apply_compression(ckeys, G_eff, ef)
-                m_ef = m if spec.churn else None
+                m_ef = m
                 if corrupt and sync != "local":
                     # corrupt the post-compression reconstruction — the dense
                     # image of the worker's wire payload; a DETECTED row is
@@ -839,53 +813,41 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
                 # sent nor accumulated this round; a rejoiner drops its
                 # stale residual at the end of its rejoin round; a
                 # QUARANTINED round freezes too — it was never delivered
-                if spec.churn:
-                    ef = jnp.where(rejoined[:, None] > 0, jnp.zeros_like(ef),
-                                   jnp.where(m_ef[:, None] > 0, ef2, ef))
-                else:
-                    ef = ef2
+                ef = jnp.where(rejoined[:, None] > 0, jnp.zeros_like(ef),
+                               jnp.where(m_ef[:, None] > 0, ef2, ef))
                 if sync == "local":
-                    if spec.churn:
-                        X = X - lr * Ghat * m[:, None]
-                        is_sync = (t + 1) % p["local_steps"] == 0
-                        if corrupt:
-                            # the wire payload at a sync point is the params:
-                            # a detected-corrupt row is dropped from the
-                            # average (weight AND denominator) for one round
-                            Xw = integrity.corrupt_dense(
-                                spec.corruption_kind, X, cflag[:, None])
-                            valid = integrity.dense_valid(Xw, per_row=True)
-                            m_s = m * valid
-                            xs = (jnp.sum(jnp.where(valid[:, None] > 0, Xw,
-                                                    jnp.zeros_like(Xw))
-                                          * m[:, None], axis=0)
-                                  / jnp.maximum(jnp.sum(m_s), 1.0))
-                            valid_round = jnp.where(is_sync, valid,
-                                                    jnp.ones_like(valid))
-                            qbits_step = jnp.where(
-                                is_sync, jnp.sum(wb * m * (1.0 - valid)), 0.0)
-                        else:
-                            xs = jnp.sum(X * m[:, None], axis=0) / n_alive
-                        # only live workers adopt the (live-only) average;
-                        # a dead worker rejoins by mixing back in later
-                        X = jnp.where(is_sync & (m[:, None] > 0),
-                                      jnp.broadcast_to(xs[None], X.shape), X)
-                        total_bits = total_bits + jnp.where(
-                            is_sync, jnp.sum(wb * m), 0.0)
+                    # Local SGD communicates only at sync steps
+                    X = X - lr * Ghat * m[:, None]
+                    is_sync = (t + 1) % p["local_steps"] == 0
+                    if corrupt:
+                        # the wire payload at a sync point is the params: a
+                        # detected-corrupt row is dropped from the average
+                        # (weight AND denominator) for one round
+                        Xw = integrity.corrupt_dense(
+                            spec.corruption_kind, X, cflag[:, None])
+                        valid = integrity.dense_valid(Xw, per_row=True)
+                        m_s = m * valid
+                        xs = (jnp.sum(jnp.where(valid[:, None] > 0, Xw,
+                                                jnp.zeros_like(Xw))
+                                      * m[:, None], axis=0)
+                              / jnp.maximum(jnp.sum(m_s), 1.0))
+                        valid_round = jnp.where(is_sync, valid,
+                                                jnp.ones_like(valid))
+                        qbits_step = jnp.where(
+                            is_sync, jnp.sum(wb * m * (1.0 - valid)), 0.0)
                     else:
-                        X = X - lr * Ghat
-                        is_sync = (t + 1) % p["local_steps"] == 0
-                        X = jnp.where(
-                            is_sync,
-                            jnp.broadcast_to(jnp.mean(X, axis=0)[None], X.shape),
-                            X,
-                        )
-                        # Local SGD communicates only at sync steps.
-                        total_bits = total_bits + jnp.where(is_sync, jnp.sum(wb), 0.0)
-                elif spec.churn:
-                    # masked mean with denominator renormalized over the
-                    # live set; the global model updates every row (PS
-                    # semantics: a rejoining worker reads current params)
+                        xs = jnp.sum(X * m[:, None], axis=0) / n_alive
+                    # only live workers adopt the (live-only) average; a
+                    # dead worker rejoins by mixing back in later
+                    X = jnp.where(is_sync & (m[:, None] > 0),
+                                  jnp.broadcast_to(xs[None], X.shape), X)
+                    total_bits = total_bits + jnp.where(
+                        is_sync, jnp.sum(wb * m), 0.0)
+                else:
+                    # bsp / ssp / asp: masked mean with the denominator
+                    # renormalized over the live set; the global model
+                    # updates every row (PS semantics: a rejoining worker
+                    # reads current params)
                     if corrupt:
                         # quarantined rows left the numerator above — the
                         # denominator renormalizes over the live-AND-valid set
@@ -895,24 +857,20 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
                         gbar = jnp.sum(Ghat * m[:, None], axis=0) / n_alive
                     X = X - lr * gbar[None, :]
                     total_bits = total_bits + jnp.sum(wb * m)
-                else:  # bsp / ssp / asp: exact mean of the effective gradients
-                    X = X - lr * jnp.mean(Ghat, axis=0)[None, :]
-                    total_bits = total_bits + jnp.sum(wb)
             if corrupt:
                 # bounded quarantine: consecutive corrupted rounds escalate
                 # into the rejoin protocol (EF reset; pull_avg additionally
                 # pulls the live-valid parameter average where a worker is
                 # actually stale) instead of retrying forever.  Every select
-                # rides AFTER the compression reductions — identity at rate 0
-                # (the bitwise dropout-0 lesson).  m_prev keeps TRUE liveness:
-                # quarantine recovery is not a rejoin.
+                # rides AFTER the compression reductions — identity at rate
+                # 0.  m_prev keeps TRUE liveness: quarantine recovery is not
+                # a rejoin.
                 q_new = jnp.where(m > 0,
                                   jnp.where(valid_round > 0, 0.0, qc + 1.0),
                                   qc)
                 esc = jnp.where(q_new >= p["quarantine_limit"], 1.0, 0.0)
                 ef = jnp.where(esc[:, None] > 0, jnp.zeros_like(ef), ef)
-                if (spec.rejoin_policy == "pull_avg"
-                        and sync in ("local", "gossip")):
+                if spec.rejoin_policy == "pull_avg":
                     donors = m * valid_round * (1.0 - esc)
                     n_don = jnp.sum(donors)
                     xpull = (jnp.sum(X * donors[:, None], axis=0)
@@ -931,12 +889,9 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
                 jnp.mean(jnp.linalg.norm(X - xbar[None], axis=1)),
                 total_bits,
             )
+            carry = (X, ef, delay_buf, key, total_bits, m)
             if corrupt:
                 out = out + (qb, qr, qe)
-            carry = (X, ef, delay_buf, key, total_bits)
-            if spec.churn:
-                carry = carry + (m,)
-            if corrupt:
                 carry = carry + (qc, qb, qr, qe)
             return carry, out
 
@@ -946,9 +901,8 @@ def _build_cell_replica_fn(spec: EngineSpec, comp, problem):
             jnp.zeros((spec.delay_slots, n, dim), f32),
             seed_key,
             jnp.zeros((), f32),
+            jnp.ones((n,), f32),  # last round's mask: everyone was alive
         )
-        if spec.churn:
-            carry0 = carry0 + (jnp.ones((n,), f32),)
         if corrupt:
             carry0 = carry0 + (jnp.zeros((n,), f32), jnp.zeros((), f32),
                                jnp.zeros((), f32), jnp.zeros((), f32))

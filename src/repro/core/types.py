@@ -140,18 +140,18 @@ class CommConfig:
 DENSE = CommConfig()
 
 
-def effective_corruption_kind(comm: CommConfig) -> str:
-    """The STRUCTURAL corruption family of a config — the same normalization
-    :func:`bundle_spec` applies, shared so the runtime layers (aggregate,
-    steps) build exactly the program structure the spec advertises: the kind
-    stays structural while the traced rate can sweep 0..p in one class
-    (explicit ``churn=True`` keeps a rate-0 cell in the integrity class,
-    mirroring how it keeps dropout-0 cells in the churn class)."""
-    kind = getattr(comm, "corruption_kind", "none")
-    rate = getattr(comm, "corruption_rate", 0.0)
-    if rate > 0 or (getattr(comm, "churn", False) and kind != "none"):
-        return kind
-    return "none"
+def carries_mask(cfg) -> bool:
+    """The one churn rule: whether a cell's mesh program carries the
+    per-round participation mask.  Explicit ``churn``, any dropout rate, or
+    a named ``corruption_kind`` turns it on (a quarantined round IS a
+    one-round drop).  A cell compiles the integrity ("guarded") program iff
+    it names a kind; the rate is traced and the guard is the identity at
+    rate 0.  Read by :func:`bundle_spec`, :mod:`repro.core.aggregate` and
+    ``Scenario``; the convergence engine always carries the mask.  Takes
+    any config with these fields (``CommConfig``, ``Scenario``)."""
+    return bool(getattr(cfg, "churn", False) or cfg.dropout_rate > 0
+                or any(r > 0 for r in cfg.worker_dropout)
+                or cfg.corruption_kind != "none")
 
 
 # ---------------------------------------------------------------------------
@@ -199,8 +199,7 @@ class BundleSpec:
     wire_format: str = "dense"
     #: fault-injection family — STRUCTURAL (the integrity program adds the
     #: inject/validate/quarantine selects); the rate is traced, so corruption-
-    #: rate siblings share one bundle.  Normalized to "none" when neither the
-    #: rate nor the explicit churn flag keeps the cell in the integrity class.
+    #: rate siblings share one bundle (see :func:`carries_mask`).
     corruption_kind: str = "none"
 
 
@@ -225,9 +224,7 @@ def bundle_spec(comm: CommConfig) -> BundleSpec:
         raise ValueError(
             "pipelined overlap needs per-step aggregation (sync must be bsp, "
             f"got {comm.sync!r})")
-    churn = bool(comm.churn or comm.dropout_rate > 0
-                 or any(r > 0 for r in comm.worker_dropout)
-                 or comm.corruption_rate > 0)
+    churn = carries_mask(comm)
     if comm.rejoin_policy not in ("reset", "pull_avg"):
         raise ValueError(
             f"unknown rejoin_policy {comm.rejoin_policy!r} "
@@ -249,7 +246,6 @@ def bundle_spec(comm: CommConfig) -> BundleSpec:
     if comm.quarantine_limit < 1:
         raise ValueError(
             f"quarantine_limit must be >= 1, got {comm.quarantine_limit!r}")
-    corruption_kind = effective_corruption_kind(comm)
     comp = get_compressor(comm.compressor, **comm.compressor_kwargs)
     if comm.wire_format not in ("dense", "compressed"):
         raise ValueError(f"unknown wire_format {comm.wire_format!r}")
@@ -294,7 +290,7 @@ def bundle_spec(comm: CommConfig) -> BundleSpec:
         churn=churn,
         rejoin_policy=(comm.rejoin_policy if churn else "reset"),
         wire_format=wire_fmt,
-        corruption_kind=corruption_kind,
+        corruption_kind=comm.corruption_kind,
     )
 
 

@@ -308,7 +308,6 @@ def to_sim_cfg(s: Scenario, seed: int | None = None) -> SimCfg:
         lr=s.lr,
         steps=s.steps,
         seed=s.seed if seed is None else seed,
-        churn=s.churn,
         dropout_rate=s.dropout_rate,
         worker_dropout=s.worker_dropout,
         churn_start=s.churn_start,

@@ -23,6 +23,8 @@ import itertools
 from dataclasses import dataclass, field, fields, replace
 from typing import Any, Iterable, Mapping
 
+from repro.core.types import carries_mask
+
 SYNC_SCHEMES = ("bsp", "ssp", "asp", "local", "post_local")
 ARCHITECTURES = ("ps", "allreduce", "gossip")
 SCHEDULE_MODES = ("sequential", "wfbp", "mgwfbp", "pipelined")
@@ -108,10 +110,11 @@ class Scenario:
     straggler_slowdown: float = 1.0  # multiplicative slowdown of worker 0
 
     # --- churn / heterogeneity (survey future directions: elastic fleets) ----
-    #: Structural flag: a churn cell carries the per-step participation mask
-    #: through the program (different scan body / aggregation graph), so it
-    #: IS a shape-class boundary. The VALUES below stay traced: cells that
-    #: differ only in dropout probabilities share one compile/bundle.
+    #: Structural flag: a churn cell's mesh program carries the per-step
+    #: participation mask, so it IS a bundle-class boundary on the trainer
+    #: (the convergence engine always carries the mask). The VALUES below
+    #: stay traced: cells that differ only in dropout probabilities share
+    #: one compile/bundle.
     churn: bool = False
     dropout_rate: float = 0.0  # per-step P(worker offline) while in window
     #: per-worker dropout probabilities (overrides dropout_rate; length must
@@ -136,10 +139,11 @@ class Scenario:
 
     # --- gradient integrity (fault injection + quarantine) --------------------
     #: per-round P(a live worker's wire payload is corrupted) — traced, so
-    #: corruption-rate siblings share one compile/bundle.  Implies churn.
+    #: corruption-rate siblings share one compile/bundle.
     corruption_rate: float = 0.0
     #: STRUCTURAL corruption family injected post-compression (in the wire
-    #: domain): nan | inf | spike | bitflip | none.
+    #: domain): nan | inf | spike | bitflip | none.  Naming one compiles the
+    #: guarded program and implies churn.
     corruption_kind: str = "none"
     #: consecutive quarantined rounds before escalating to the rejoin
     #: protocol (traced knob).
@@ -157,12 +161,10 @@ class Scenario:
             object.__setattr__(self, "compressor", None)
         object.__setattr__(self, "worker_dropout", tuple(self.worker_dropout))
         object.__setattr__(self, "worker_speeds", tuple(self.worker_speeds))
-        # churn is implied by any nonzero dropout so sweeps can vary
-        # dropout_rate alone; all implied cells share the churn=True class.
-        # Corruption rides the same participation-mask machinery (a
-        # quarantined round IS a one-round drop), so it implies churn too.
-        if (self.dropout_rate > 0 or any(self.worker_dropout)
-                or self.corruption_rate > 0):
+        # churn is implied by any nonzero dropout (so sweeps can vary
+        # dropout_rate alone) and by a named corruption kind (a quarantined
+        # round IS a one-round drop): the one rule, types.carries_mask
+        if carries_mask(self):
             object.__setattr__(self, "churn", True)
 
     # -- convenience ----------------------------------------------------------
@@ -211,17 +213,10 @@ class Scenario:
                 cell += f"+drop{self.dropout_rate * 100:g}%"
             if self.rejoin_policy != "reset":
                 cell += f"+rejoin={self.rejoin_policy}"
-            if self._corruption_active:
+            if self.corruption_kind != "none":
                 cell += (f"+corrupt{self.corruption_rate * 100:g}%"
                          f"{self.corruption_kind}")
         return cell
-
-    @property
-    def _corruption_active(self) -> bool:
-        """Mirror of ``repro.core.types.effective_corruption_kind``: the
-        integrity program is in the cell's class."""
-        return (self.corruption_rate > 0
-                or (self.churn and self.corruption_kind != "none"))
 
     def replace(self, **kw) -> "Scenario":
         return replace(self, **kw)
@@ -333,7 +328,7 @@ class Scenario:
             if self.churn and substrate not in ("training", "trainer", "timeline"):
                 v.append("the churn axis runs on the executable substrates "
                          "(training/trainer) and the timeline event stream")
-            if self._corruption_active and substrate == "trainer":
+            if self.corruption_kind != "none" and substrate == "trainer":
                 if self.arch == "gossip":
                     v.append("trainer gossip corruption is unimplemented "
                              "(the engine models the corrupted mixing row; "

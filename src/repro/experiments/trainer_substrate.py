@@ -159,7 +159,7 @@ def expected_quarantine_fraction(s: Scenario) -> float:
     which the caller accounts for by this returning the *upper* bound —
     measured-vs-predicted on sign cells shows the undetectable gap."""
     rate = s.corruption_rate
-    if not s._corruption_active or rate <= 0 or s.steps <= 0:
+    if s.corruption_kind == "none" or rate <= 0 or s.steps <= 0:
         return 0.0
     start = min(max(s.churn_start, 0), s.steps)
     end = s.steps if s.churn_end == -1 else min(s.churn_end, s.steps)
@@ -399,7 +399,7 @@ def run_trainer_scenario(
             fmt: kb * frac for fmt, kb in measured["wire_format_kb"].items()}
         measured["wire_resync_kb_per_step"] = (
             trainer_wire_resync_per_step(s, bundle.wire or {}) / 1e3)
-    if s._corruption_active:
+    if s.corruption_kind != "none":
         # measured quarantine tallies live in the final comm state (per
         # shard, replicated over the model axis); the wire-rounds
         # denominator is sync_rounds x microbatch-rounds for pipelined
@@ -427,7 +427,7 @@ def run_trainer_scenario(
         s, data_par=dp,
         payload_round=plan_payload_bytes(bundle.bucket_plan),
         n_buckets=len(bundle.bucket_plan.buckets))
-    if s._corruption_active:
+    if s.corruption_kind != "none":
         qfrac = expected_quarantine_fraction(s)
         predicted["quarantine_fraction"] = qfrac
         predicted["wire_kb_per_step_quarantined"] = (

@@ -39,7 +39,6 @@ from repro.core.types import (
     CommConfig,
     CommKnobs,
     bundle_spec,
-    effective_corruption_kind,
 )
 from repro.launch import specs as SP
 from repro.models import transformer as T
@@ -478,7 +477,7 @@ def _compile_bundle(
     # pod-scoped, so shards in different pods draw independent fates (the
     # per-shard half of pod_local's dual-granularity liveness)
     mask_axes = ax.data if agg_axes != ax.data else None
-    corruption_kind = effective_corruption_kind(comm)
+    corruption_kind = spec.corruption_kind
 
     # ---- state specs ---------------------------------------------------------
     all_axes = ax.data + (ax.model,)
@@ -500,34 +499,11 @@ def _compile_bundle(
         }.get(opt.name, None)
         if opt_state_specs is None:  # momentum with other coefficient
             opt_state_specs = {"v": param_specs}
-    comm_state_specs: dict[str, Any] = {"step": P()}
-    if spec.churn:
-        # previous round's per-shard participation bit — rejoin detection
-        comm_state_specs["alive_prev"] = P(all_axes)
-        if comm.pod_local:
-            # pod-granularity liveness for the DCN sync round (derived from
-            # the per-shard bits, carried so pod rejoins are detectable)
-            comm_state_specs["pod_alive_prev"] = P(all_axes)
-    if corruption_kind != "none":
-        # consecutive-quarantine counter + lifetime quarantine/escalation
-        # tallies (per shard; see aggregate.init_comm_state)
-        comm_state_specs["qcount"] = P(all_axes)
-        comm_state_specs["quarantine_total"] = P(all_axes)
-        comm_state_specs["escalation_total"] = P(all_axes)
-    # pipelined overlap, staleness 1: the last microbatch's bucket grads are
-    # double-buffered across the step boundary (aggregated by the NEXT step)
-    pipe_carry = spec.overlap == "pipelined" and spec.overlap_staleness == 1
-    if pipe_carry:
-        comm_state_specs["overlap_pending"] = [P(all_axes) for _ in bplan.buckets]
-    if aggregate.plan_uses_powersgd(bplan):
-        comm_state_specs["psgd_q"] = [P(all_axes) for _ in bplan.buckets]
-    if comm.error_feedback:
-        comm_state_specs["ef"] = [P(all_axes) for _ in bplan.buckets]
-    if comm.momentum_correction:
-        comm_state_specs["u"] = [P(all_axes) for _ in bplan.buckets]
-    if comm.aggregator == "gossip" and comm.gossip_compress == "choco":
-        comm_state_specs["choco_xhat"] = jax.tree.map(lambda _: P(all_axes), list(bplan.buckets))
-        comm_state_specs["choco_nbr"] = jax.tree.map(lambda _: P(all_axes), list(bplan.buckets))
+    # one declaration of the comm state (aggregate.init_comm_state):
+    # scalars are replicated, every other leaf is per shard
+    comm_state_specs = jax.tree.map(
+        lambda x: P(all_axes) if x.ndim else P(),
+        jax.eval_shape(lambda: aggregate.init_comm_state(comm, bplan)))
     state_specs = {
         "params": param_specs,
         "opt": opt_state_specs,
@@ -543,28 +519,9 @@ def _compile_bundle(
             lambda x: comms.varying(x, all_axes) if hasattr(x, "shape") and x.ndim else x,
             opt.init(params),
         )
-        cstate: dict[str, Any] = {"step": jnp.zeros((), jnp.int32)}
-        if spec.churn:
-            cstate["alive_prev"] = comms.varying(jnp.ones((1,), f32), all_axes)
-            if comm.pod_local:
-                cstate["pod_alive_prev"] = comms.varying(jnp.ones((1,), f32), all_axes)
-        if corruption_kind != "none":
-            for k in ("qcount", "quarantine_total", "escalation_total"):
-                cstate[k] = comms.varying(jnp.zeros((1,), f32), all_axes)
-        if pipe_carry:
-            cstate["overlap_pending"] = [
-                comms.varying(jnp.zeros((b.size,), f32), all_axes) for b in bplan.buckets
-            ]
-        if aggregate.plan_uses_powersgd(bplan):
-            base = aggregate.init_comm_state(comm, bplan)["psgd_q"]
-            cstate["psgd_q"] = [comms.varying(q, all_axes) for q in base]
-        if comm.error_feedback:
-            cstate["ef"] = [comms.varying(jnp.zeros((b.size,), f32), all_axes) for b in bplan.buckets]
-        if comm.momentum_correction:
-            cstate["u"] = [comms.varying(jnp.zeros((b.size,), f32), all_axes) for b in bplan.buckets]
-        if comm.aggregator == "gossip" and comm.gossip_compress == "choco":
-            cstate["choco_xhat"] = [comms.varying(jnp.zeros((b.size,), f32), all_axes) for b in bplan.buckets]
-            cstate["choco_nbr"] = [comms.varying(jnp.zeros((b.size,), f32), all_axes) for b in bplan.buckets]
+        cstate = jax.tree.map(
+            lambda x: comms.varying(x, all_axes) if x.ndim else x,
+            aggregate.init_comm_state(comm, bplan))
         return {"params": params, "opt": opt_state, "comm": cstate,
                 "step": jnp.zeros((), jnp.int32)}
 
@@ -663,22 +620,10 @@ def _compile_bundle(
             # the mask via ``alive_info`` so its per-call draw is skipped.
             alive_seq = rejoin_seq = in_window = None
             if spec.churn and spec.overlap_staleness == 1:
-                maxes = mask_axes if mask_axes is not None else agg_axes
-                widx = jnp.zeros((), jnp.int32)
-                for axn in maxes:
-                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
-                mkey = jax.random.fold_in(key, widx)
-                drop = knobs["dropout"]
-                if getattr(drop, "ndim", 0) == 1:
-                    drop = jnp.take(drop, widx)
-                u = jax.random.uniform(jax.random.fold_in(mkey, 0x6368), ())
-                stepf = state["step"].astype(f32)
-                in_window = ((stepf >= knobs["churn_start"])
-                             & (stepf < knobs["churn_end"]))
-                alive = jnp.where(in_window & (u < drop), 0.0, 1.0)
-                rejoined = alive * (1.0 - cstate["alive_prev"].reshape(()))
-                cstate = dict(cstate)
-                cstate["alive_prev"] = alive.reshape(1)
+                alive, in_window, _ = aggregate.draw_alive(
+                    key, state["step"], knobs,
+                    mask_axes if mask_axes is not None else agg_axes)
+                rejoined = aggregate.rejoin(cstate, alive)
                 alive_seq = jnp.concatenate([
                     (alive * (1.0 - rejoined)).reshape(1),
                     jnp.broadcast_to(alive, (M - 1,)),
@@ -795,10 +740,8 @@ def _compile_bundle(
             # stale params never drag the average), and its compressor
             # state resets.
             cstate = dict(state["comm"])
-            stepf = state["step"].astype(f32)
-            in_window = ((stepf >= knobs["churn_start"])
-                         & (stepf < knobs["churn_end"]))
-            mkey = None
+            slot = "pod_alive_prev" if comm.pod_local else "alive_prev"
+            prev = cstate[slot].reshape(())
             if comm.pod_local:
                 # participation unit = the POD (every shard of a pod must
                 # agree on the pod's alive bit or within-pod consistency
@@ -810,26 +753,13 @@ def _compile_bundle(
                 shard_bit = cstate["alive_prev"].reshape(())
                 alive = jnp.where(comms.psum(shard_bit, agg_axes) > 0,
                                   1.0, 0.0)
-                prev = cstate["pod_alive_prev"].reshape(())
-                rejoined = alive * (1.0 - prev)
-                cstate["pod_alive_prev"] = alive.reshape(1)
             else:
                 # participation unit = the data shard (sync_axes == ax.data)
-                widx = jnp.zeros((), jnp.int32)
-                for axn in sync_axes:
-                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
-                mkey = jax.random.fold_in(
+                alive, in_window, mkey = aggregate.draw_alive(
                     jax.random.fold_in(jax.random.key(knobs["seed"]),
                                        state["step"]),
-                    widx)
-                drop = knobs["dropout"]
-                if getattr(drop, "ndim", 0) == 1:
-                    drop = jnp.take(drop, widx)
-                u = jax.random.uniform(jax.random.fold_in(mkey, 0x6368), ())
-                alive = jnp.where(in_window & (u < drop), 0.0, 1.0)
-                prev = cstate["alive_prev"].reshape(())
-                rejoined = alive * (1.0 - prev)
-                cstate["alive_prev"] = alive.reshape(1)
+                    state["step"], knobs, sync_axes)
+            rejoined = aggregate.rejoin(cstate, alive, slot)
             donor = (alive * prev if spec.rejoin_policy == "pull_avg"
                      else None)
             # gradient integrity on the sync wire: local/post_local cells
@@ -841,7 +771,7 @@ def _compile_bundle(
             # donor mask.  pod_local cells corrupt at the per-step
             # within-pod aggregation instead (aggregate_buckets), so the
             # DCN sync stays clean — one injection point per wire payload.
-            payload = valid = esc = None
+            payload = valid = None
             if corruption_kind != "none" and not comm.pod_local:
                 cflag = integrity.corruption_flag(
                     mkey, knobs["corruption"], in_window & (alive > 0))
@@ -868,28 +798,15 @@ def _compile_bundle(
                                          impl=comm.collective,
                                          alive=alive, donor=donor,
                                          payload=payload)
-            reset = rejoined
+            aggregate.reset_comp_state(cstate, rejoined)
             if valid is not None:
                 # bounded quarantine: the corrupted payload was discarded
                 # (this shard adopted the clean live-set average — its own
                 # params were never corrupted, the wire copy was), but
                 # consecutive corrupted rounds escalate to the rejoin
                 # protocol's compressor-state reset leg
-                qlim = knobs["quarantine_limit"]
-                q = cstate["qcount"].reshape(())
-                q_new = jnp.where(alive > 0,
-                                  jnp.where(valid > 0, 0.0, q + 1.0), q)
-                esc = jnp.where(q_new >= qlim, 1.0, 0.0)
-                cstate["qcount"] = jnp.where(esc > 0, 0.0, q_new).reshape(1)
-                cstate["quarantine_total"] = (cstate["quarantine_total"]
-                                              + (1.0 - valid).reshape(1))
-                cstate["escalation_total"] = (cstate["escalation_total"]
-                                              + esc.reshape(1))
-                reset = jnp.clip(rejoined + esc, 0.0, 1.0)
-            for k in ("ef", "u"):
-                if k in cstate:
-                    cstate[k] = [jnp.where(reset > 0, jnp.zeros_like(e), e)
-                                 for e in cstate[k]]
+                aggregate.escalate(cstate, alive, valid,
+                                   knobs["quarantine_limit"])
             return {**state, "params": params, "comm": cstate}
         params = sync.average_params(params, sync_axes, impl=comm.collective)
         return {**state, "params": params}
@@ -925,29 +842,15 @@ def _compile_bundle(
             # churn: each shard draws its participation bit for this mixing
             # round (same key discipline as aggregate_buckets); a dead shard
             # drops out of the exchange, neighbors renormalize onto self
+            key = jax.random.fold_in(jax.random.key(knobs["seed"]), state["step"])
             alive = rejoined = None
             if spec.churn:
-                widx = jnp.zeros((), jnp.int32)
-                for axn in ax.data:
-                    widx = widx * jax.lax.axis_size(axn) + jax.lax.axis_index(axn)
-                mkey = jax.random.fold_in(
-                    jax.random.fold_in(jax.random.key(knobs["seed"]), state["step"]),
-                    widx)
-                drop = knobs["dropout"]
-                if getattr(drop, "ndim", 0) == 1:
-                    drop = jnp.take(drop, widx)
-                u = jax.random.uniform(jax.random.fold_in(mkey, 0x6368), ())
-                stepf = state["step"].astype(f32)
-                in_window = ((stepf >= knobs["churn_start"])
-                             & (stepf < knobs["churn_end"]))
-                alive = jnp.where(in_window & (u < drop), 0.0, 1.0)
-                # rejoin detection: alive now, masked out last round
-                rejoined = alive * (1.0 - cstate["alive_prev"].reshape(()))
-                cstate["alive_prev"] = alive.reshape(1)
+                alive, _, _ = aggregate.draw_alive(key, state["step"], knobs,
+                                                   ax.data)
+                rejoined = aggregate.rejoin(cstate, alive)
             with comms.tag("gossip_mix"):
                 if comm.gossip_compress == "choco" and compressor is not None:
                     st = gossip.ChocoState(list(cstate["choco_xhat"]), list(cstate["choco_nbr"]))
-                    key = jax.random.fold_in(jax.random.key(knobs["seed"]), state["step"])
                     # churn: mirror snap + exact-delta resync (both rejoin
                     # policies — the mirror-drift invariant is mandatory)
                     bufs, st = gossip.choco_mix(

@@ -10,9 +10,9 @@ churn) now run end-to-end.
 
 Properties, per ISSUE 6:
 
-* an all-alive mask reproduces the churn-free program — bitwise for the
-  shared-denominator schemes (bsp/ssp/asp), within float tolerance for
-  local/gossip (XLA fuses their masked reductions differently);
+* the convergence engine always carries the participation mask, so a cell
+  whose churn window never opens reproduces the plain cell bitwise, every
+  scheme — one program, an all-ones mask;
 * a single surviving worker degenerates to solo SGD on that worker's
   objective (hand-rolled reference loop);
 * masked mixing renormalizes over the live set: rows keep summing to 1,
@@ -22,7 +22,8 @@ Properties, per ISSUE 6:
   and the run keeps converging;
 * engine and trainer agree on the churn cell contract: dropout-0 churn
   matches the plain cell, 30% dropout stays finite, and dropout VALUES
-  never split a compile/build class.
+  never split a compile/build class (the engine has one class for all
+  three; the trainer keeps its masked class apart).
 """
 
 import numpy as np
@@ -38,9 +39,9 @@ from repro.core.simulate import (
 )
 
 SCHEMES = ("bsp", "local", "ssp", "asp", "gossip")
-#: schemes whose masked aggregation is algebraically the churn-free mean when
-#: everyone is alive AND whose compiled programs reproduce it bitwise; the
-#: parameter-averaging / mixing schemes fuse differently and match to rtol
+#: schemes whose guarded (integrity) program at corruption rate 0 reproduces
+#: the unguarded one bitwise; the parameter-averaging / mixing schemes fuse
+#: their guarded reductions differently and match to rtol
 BITWISE = ("bsp", "ssp", "asp")
 
 
@@ -64,16 +65,16 @@ def _cell(sync, **kw):
 
 @pytest.mark.parametrize("sync", SCHEMES)
 def test_all_alive_mask_matches_churn_free(sync):
+    """A 90% dropout cell whose churn window starts at the run's length
+    (``churn_start == steps``) never opens, so its mask is all ones: the
+    window gating on the one masked program makes it bitwise the plain
+    cell, every scheme."""
     problem = quadratic_problem(dim=24, n_workers=4, noise=0.1, seed=3)
     plain = simulate_training_batch(_cell(sync), problem)[0]
-    churn0 = simulate_training_batch(
-        _cell(sync, churn=True, dropout_rate=0.0), problem)[0]
+    closed = simulate_training_batch(
+        _cell(sync, dropout_rate=0.9, churn_start=12), problem)[0]
     for k in ("loss", "consensus", "bits"):
-        if sync in BITWISE:
-            np.testing.assert_array_equal(churn0[k], plain[k], err_msg=k)
-        else:
-            np.testing.assert_allclose(churn0[k], plain[k], rtol=1e-5,
-                                       atol=1e-6, err_msg=k)
+        np.testing.assert_array_equal(closed[k], plain[k], err_msg=k)
 
 
 def test_single_alive_worker_matches_solo_sgd():
@@ -145,7 +146,7 @@ def test_dropout_values_share_one_engine_compile():
     problem = quadratic_problem(dim=16, n_workers=4, noise=0.05, seed=2)
     cells = [SimCfg(sync="bsp", n_workers=4, steps=20, lr=0.05,
                     compressor=_qsgd16(), error_feedback=True,
-                    churn=True, dropout_rate=r, seed=5)
+                    dropout_rate=r, seed=5)
              for r in (0.0, 0.1, 0.3)]
     st = engine_cache_stats()
     c0 = st.compiles
@@ -235,8 +236,9 @@ def test_ef_freezes_while_masked_out():
 def test_engine_and_trainer_agree_on_churn_cell():
     """The shared churn-cell contract, checked on BOTH substrates: a
     dropout-0 churn cell reproduces the plain cell, 30% dropout stays
-    finite, and the three cells span exactly two compile/build classes
-    (plain vs churn — never one per dropout value)."""
+    finite, and dropout values never split a class: the engine runs all
+    three cells in one class (it always carries the mask), the trainer in
+    at most two (plain vs churn)."""
     from repro.experiments import Scenario
     from repro.experiments.runner import run_scenarios, training_shape_key
     from repro.experiments.trainer_substrate import run_trainer_scenario
@@ -252,11 +254,11 @@ def test_engine_and_trainer_agree_on_churn_cell():
     cells = [cell(),
              cell(churn=True, dropout_rate=0.0),
              cell(churn=True, dropout_rate=0.3)]
-    assert len({training_shape_key(s) for s in cells}) == 2
+    assert len({training_shape_key(s) for s in cells}) == 1
 
     c0 = engine_cache_stats().compiles
     plain, churn0, churn30 = run_scenarios(cells, "training")
-    assert engine_cache_stats().compiles - c0 <= 2
+    assert engine_cache_stats().compiles - c0 <= 1
     np.testing.assert_array_equal(churn0.series["loss"], plain.series["loss"])
     assert np.isfinite(churn30.series["loss"]).all()
 
@@ -283,7 +285,7 @@ def test_rejoin_policy_dropout0_matches_churn_free(sync, policy):
     problem = quadratic_problem(dim=24, n_workers=4, noise=0.1, seed=3)
     plain = simulate_training_batch(_cell(sync), problem)[0]
     churn0 = simulate_training_batch(
-        _cell(sync, churn=True, dropout_rate=0.0, rejoin_policy=policy),
+        _cell(sync, dropout_rate=0.0, rejoin_policy=policy),
         problem)[0]
     for k in ("loss", "consensus", "bits"):
         np.testing.assert_allclose(churn0[k], plain[k], rtol=1e-5, atol=1e-6,
@@ -315,13 +317,13 @@ def test_pull_avg_rejoin_collapses_consensus_and_charges_download():
 
 
 def test_rejoin_policy_is_structural_dropout_is_traced():
-    """One engine compile per (churn, rejoin_policy) class: dropout values
-    never split a class, the two policies never share one."""
+    """One engine compile per rejoin_policy class: dropout values never
+    split a class, the two policies never share one."""
     problem = quadratic_problem(dim=16, n_workers=4, noise=0.05, seed=2)
 
     def cells(pol):
         return [SimCfg(sync="local", n_workers=4, steps=15, lr=0.05,
-                       local_steps=5, churn=True, dropout_rate=r,
+                       local_steps=5, dropout_rate=r,
                        rejoin_policy=pol, seed=5)
                 for r in (0.1, 0.3)]
 
@@ -589,7 +591,7 @@ def test_engine_corruption_finite_within_2x_of_clean_drop(sync, kind):
         _cell(sync, steps=24, corruption_rate=0.1, corruption_kind=kind),
         problem)[0]
     drop = simulate_training_batch(
-        _cell(sync, steps=24, churn=True, dropout_rate=0.1), problem)[0]
+        _cell(sync, steps=24, dropout_rate=0.1), problem)[0]
     assert np.isfinite(hot["loss"]).all(), kind
     assert np.isfinite(drop["loss"]).all()
     assert hot["loss"][-1] <= 2.0 * drop["loss"][-1] + 1e-6, \
@@ -601,14 +603,14 @@ def test_engine_corruption_finite_within_2x_of_clean_drop(sync, kind):
 
 @pytest.mark.parametrize("sync", SCHEMES)
 def test_engine_corruption0_matches_churn_free(sync):
-    """A corruption-0 cell (explicit kind, rate 0 — the guarded program) is
-    bitwise identical to the churn-free cell for the shared-denominator
+    """A corruption-0 cell (a named kind at rate 0 — the guarded program) is
+    bitwise identical to the plain cell for the shared-denominator
     schemes: every integrity select rides the post-compression jnp.where
     and is the identity when the corruption flag never fires."""
     problem = quadratic_problem(dim=24, n_workers=4, noise=0.1, seed=3)
     plain = simulate_training_batch(_cell(sync), problem)[0]
     hot0 = simulate_training_batch(
-        _cell(sync, churn=True, dropout_rate=0.0, corruption_rate=0.0,
+        _cell(sync, dropout_rate=0.0, corruption_rate=0.0,
               corruption_kind="bitflip"), problem)[0]
     for k in ("loss", "consensus", "bits"):
         if sync in BITWISE:

@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 from jax import make_mesh, shard_map
 from jax.sharding import AxisType
 from repro.core import collectives, comms, aggregate, gossip
-from repro.core.types import CommConfig
+from repro.core.types import CommConfig, CommKnobs
 from repro.core.compression import get_compressor
 
 mesh = make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
@@ -47,7 +47,9 @@ def agg_with(comm):
     def f(g):
         g = {k: v[0] for k, v in g.items()}
         state = aggregate.init_comm_state(comm, plan)
-        out, _ = aggregate.aggregate_gradients(comm, plan, g, state, jax.random.key(0), ("data",))
+        knobs = CommKnobs.from_comm(comm, plan.knob_values(), n_workers=8).as_tree()
+        out, _ = aggregate.aggregate_gradients(comm, plan, g, state, jax.random.key(0),
+                                               ("data",), knobs)
         return out
     return jax.jit(shard_map(f, mesh=mesh,
         in_specs=({k: P("data") for k in grads},), out_specs={"w": P(), "b": P()},

@@ -159,7 +159,7 @@ def test_int8_exchange_carries_words_without_byte_copies(v5e, n):
     from jax.sharding import PartitionSpec as P
 
     from repro.core import aggregate
-    from repro.core.types import CommConfig
+    from repro.core.types import CommConfig, CommKnobs
 
     mesh = Mesh(np.asarray(v5e.devices).reshape(W), ("data",))
     comm = CommConfig(compressor="qsgd_kernel", compressor_kwargs={"levels": 127},
@@ -169,8 +169,9 @@ def test_int8_exchange_carries_words_without_byte_copies(v5e, n):
 
     def exchange(g):
         state = aggregate.init_comm_state(comm, plan)
+        knobs = CommKnobs.from_comm(comm, plan.knob_values(), n_workers=W).as_tree()
         out, _ = aggregate.aggregate_gradients(comm, plan, {"g": g}, state,
-                                               jax.random.key(0), ("data",))
+                                               jax.random.key(0), ("data",), knobs)
         return out["g"]
 
     step = jax.jit(shard_map(exchange, mesh=mesh, in_specs=P("data"), out_specs=P(),

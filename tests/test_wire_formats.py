@@ -271,7 +271,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax import make_mesh, shard_map
 from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import aggregate, comms
-from repro.core.types import CommConfig
+from repro.core.types import CommConfig, CommKnobs
 from repro.kernels import ops
 from repro.kernels import ref as kref
 
@@ -311,8 +311,9 @@ def run(comm):
         state = aggregate.init_comm_state(comm, plan)
         if "ef" in state:
             state["ef"] = [e[0] for e in ef]
+        knobs = CommKnobs.from_comm(comm, plan.knob_values(), n_workers=W).as_tree()
         out, st = aggregate.aggregate_gradients(comm, plan, g, state,
-                                                jax.random.key(7), ("data",))
+                                                jax.random.key(7), ("data",), knobs)
         return out, [e[None] for e in st.get("ef", [])]
 
     ef0 = [jax.random.normal(jax.random.key(9 + i), (W, b.size)) * 0.05
